@@ -65,7 +65,7 @@ def test_lower_is_better_metrics_flag_rises():
 
 
 def test_backends_never_cross_band():
-    """CPU-fallback history must not band a TPU candidate (and vice
+    """CPU history must not band a TPU candidate (and vice
     versa) — a backend switch is not a regression."""
     hist = _history(backend="numpy")
     verdict = regress.evaluate(hist, _entry(pairs=10.0, backend="jax"))
@@ -110,7 +110,8 @@ def test_cli_exit_codes(tmp_path, capsys):
 
 
 def test_gate_passes_on_repo_history():
-    """The checked-in bench_history.jsonl must pass its own gate — the
-    verify skill runs exactly this command after the bench step."""
+    """The repo's bench_history.jsonl (absent until a chip bench run
+    appends one) must pass its own gate — the verify skill runs exactly
+    this command after the bench step."""
     path = os.path.join(REPO, "bench_history.jsonl")
     assert regress.main(["--history", path]) == 0

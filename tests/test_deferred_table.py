@@ -4,18 +4,18 @@ import numpy as np
 import jax.numpy as jnp
 
 from tpu_cooccurrence.ops.device_scorer import DeferredResultsTable
+from tpu_cooccurrence.state.results import pack_ids
 
 
 def _packed(rows_vals, k):
-    """Build a [2, S, K] packed block: vals descending, ids bitcast."""
+    """Build a [2, S, K] packed block: vals descending, ids packed."""
     s = len(rows_vals)
     vals = np.full((s, k), -np.inf, np.float32)
     ids = np.zeros((s, k), np.int32)
     for i, (val, idx) in enumerate(rows_vals):
         vals[i, : len(val)] = val
         ids[i, : len(idx)] = idx
-    return jnp.stack([jnp.asarray(vals),
-                      jnp.asarray(ids).view(jnp.float32)])
+    return jnp.stack([jnp.asarray(vals), pack_ids(jnp.asarray(ids))])
 
 
 def test_drain_empty_and_incremental():

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from tpu_cooccurrence.ops.pallas_score import (pallas_score_rect,
                                                rect_supported, rect_tile)
+from tpu_cooccurrence.state.results import unpack_ids
 from tpu_cooccurrence.sampling.reservoir import PairDeltaBatch
 from tpu_cooccurrence.state.sparse_scorer import (SparseDeviceScorer,
                                                   _score_rect)
@@ -47,7 +48,7 @@ def _random_slab(rng, n_rows, num_items, R, zero_frac=0.1,
 
 def _unpack(packed, s):
     host = np.asarray(packed)
-    return host[0, :s], host[1, :s].view(np.int32)
+    return host[0, :s], unpack_ids(host[1, :s])
 
 
 @pytest.mark.parametrize("seed,R,n_rows", [

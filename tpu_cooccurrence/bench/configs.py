@@ -24,6 +24,7 @@ import os
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
+import numpy as np
 
 from ..config import Backend, Config
 from ..io import synthetic
@@ -71,18 +72,30 @@ class BenchResult:
         }
 
 
-def _run(name: str, cfg: Config, users, items, ts,
-         standin_model: Optional[str]) -> BenchResult:
-    """``standin_model``: None = real (or non-stand-in) input; a string
-    names the synthetic model that stands in for a real dataset."""
-    job = CooccurrenceJob(cfg)
+@dataclasses.dataclass
+class Workload:
+    """One benchmark configuration's stream and job config, not yet run:
+    ``chip_smoke.py`` drives the same workload on the chip and on the
+    oracle backend."""
+    name: str
+    config: Config
+    users: np.ndarray
+    items: np.ndarray
+    ts: np.ndarray
+    #: None = real (or non-stand-in) input; a string names the
+    #: synthetic model that stands in for a real dataset.
+    standin_model: Optional[str] = None
+
+
+def _run(w: Workload) -> BenchResult:
+    job = CooccurrenceJob(w.config)
     start = time.monotonic()
-    job.add_batch(users, items, ts)
+    job.add_batch(w.users, w.items, w.ts)
     job.finish()
     seconds = time.monotonic() - start
-    return BenchResult(name, cfg.backend.value, len(users),
+    return BenchResult(w.name, w.config.backend.value, len(w.users),
                        job.counters.get(OBSERVED_COOCCURRENCES), seconds,
-                       standin_model is not None, standin_model)
+                       w.standin_model is not None, w.standin_model)
 
 
 def config1_tiny_text(backend: Backend = Backend.DEVICE) -> BenchResult:
@@ -91,7 +104,7 @@ def config1_tiny_text(backend: Backend = Backend.DEVICE) -> BenchResult:
     n_items = int(items.max()) + 1
     cfg = Config(window_size=1_000_000, skip_cuts=True, seed=1,
                  backend=backend, num_items=n_items)
-    return _run("tiny-text-batch", cfg, users, items, ts, None)
+    return _run(Workload("tiny-text-batch", cfg, users, items, ts))
 
 
 def _movielens_100k() -> Tuple:
@@ -111,7 +124,7 @@ def config2_ml100k(backend: Backend = Backend.DEVICE) -> BenchResult:
     users, items, ts, model = _movielens_100k()
     cfg = Config(window_size=4000, seed=2, item_cut=500, user_cut=500,
                  backend=backend, num_items=int(items.max()) + 1)
-    return _run("ml-100k-tumbling", cfg, users, items, ts, model)
+    return _run(Workload("ml-100k-tumbling", cfg, users, items, ts, model))
 
 
 def _movielens_25m(limit: Optional[int]) -> Tuple:
@@ -140,8 +153,8 @@ def _dense_cfg_extras(backend: Backend, items) -> Dict:
     }
 
 
-def config3_ml25m_sliding(backend: Backend = Backend.DEVICE,
-                          limit: Optional[int] = 500_000) -> BenchResult:
+def config3_workload(backend: Backend = Backend.DEVICE,
+                     limit: Optional[int] = 500_000) -> Workload:
     """59k-item vocab (the calibrated stand-in carries ML-25M's real
     59,047 movies): a dense int32 C (13.9 GB) misses one chip's HBM,
     but reference-style int16 counts (7.0 GB) fit — so the dense device
@@ -150,11 +163,16 @@ def config3_ml25m_sliding(backend: Backend = Backend.DEVICE,
     cfg = Config(window_size=4000, window_slide=1000, seed=3,
                  item_cut=500, user_cut=500, backend=backend,
                  **_dense_cfg_extras(backend, items))
-    return _run("ml-25m-sliding", cfg, users, items, ts, model)
+    return Workload("ml-25m-sliding", cfg, users, items, ts, model)
 
 
-def config4_zipfian_1m(backend: Backend = Backend.SPARSE,
-                            n_events: int = 1_000_000) -> BenchResult:
+def config3_ml25m_sliding(backend: Backend = Backend.DEVICE,
+                          limit: Optional[int] = 500_000) -> BenchResult:
+    return _run(config3_workload(backend, limit))
+
+
+def config4_workload(backend: Backend = Backend.SPARSE,
+                     n_events: int = 1_000_000) -> Workload:
     """1M-item Zipfian stream. Dense device state is infeasible at this
     vocabulary; the device-resident sparse slab backend carries it (the
     host-matrix hybrid remains as the fallback comparison point)."""
@@ -163,10 +181,15 @@ def config4_zipfian_1m(backend: Backend = Backend.SPARSE,
         events_per_ms=200)
     cfg = Config(window_size=100, seed=4, item_cut=500, user_cut=500,
                  backend=backend)
-    return _run("zipfian-1M-items", cfg, users, items, ts, None)
+    return Workload("zipfian-1M-items", cfg, users, items, ts)
 
 
-def _instacart() -> Tuple:
+def config4_zipfian_1m(backend: Backend = Backend.SPARSE,
+                       n_events: int = 1_000_000) -> BenchResult:
+    return _run(config4_workload(backend, n_events))
+
+
+def _instacart(n_baskets: Optional[int] = None) -> Tuple:
     orders = os.environ.get("INSTACART_ORDERS", "")
     order_products = os.environ.get("INSTACART_ORDER_PRODUCTS", "")
     if orders and os.path.exists(orders) and os.path.exists(order_products):
@@ -178,18 +201,24 @@ def _instacart() -> Tuple:
     # Banana-headed product spectrum). Scale via BENCH_BASKETS;
     # persistent histories make the pair volume grow quadratically in
     # per-user interactions.
-    n_baskets = int(os.environ.get("BENCH_BASKETS", 20_000))
+    if n_baskets is None:
+        n_baskets = int(os.environ.get("BENCH_BASKETS", 20_000))
     users, items, ts = synthetic.instacart_calibrated(n_baskets)
     return users, items, ts, "calibrated-v1"
 
 
-def config5_instacart(backend: Backend = Backend.DEVICE) -> BenchResult:
+def config5_workload(backend: Backend = Backend.DEVICE,
+                     n_baskets: Optional[int] = None) -> Workload:
     """~50k-item vocab: int16 counts (5 GB dense C) keep this on the dense
-    device backend (17x the hybrid's throughput here)."""
-    users, items, ts, model = _instacart()
+    device backend."""
+    users, items, ts, model = _instacart(n_baskets)
     cfg = Config(window_size=1000, seed=5, item_cut=500, user_cut=500,
                  backend=backend, **_dense_cfg_extras(backend, items))
-    return _run("instacart-incremental", cfg, users, items, ts, model)
+    return Workload("instacart-incremental", cfg, users, items, ts, model)
+
+
+def config5_instacart(backend: Backend = Backend.DEVICE) -> BenchResult:
+    return _run(config5_workload(backend))
 
 
 ALL_CONFIGS: List[Tuple[str, Callable[[], BenchResult]]] = [

@@ -14,8 +14,8 @@ shared host produces) and flag the candidate when it lands beyond
 BAD side (each tracked metric declares its good direction; a 2x
 pairs/s IMPROVEMENT is news, not a regression). The relative floor
 keeps a freakishly quiet history (MAD ~ 0) from flagging ordinary
-jitter. History entries compare within the same ``backend`` only — cpu
-fallback numbers must never band a TPU run.
+jitter. History entries compare within the same ``backend`` only — CPU
+numbers must never band a TPU run.
 
 Exit code: 1 when any tracked metric regresses, 0 otherwise —
 including when history is too thin to band (< ``min_history`` prior

@@ -59,9 +59,19 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         # committed epoch on any failure. Workers run the job path
         # below with the coordinator flags filled in; their stdouts are
         # spooled and forwarded in process order only on clean exit.
-        from .robustness.gang import GangSupervisor
+        from .robustness.gang import (GangSupervisor,
+                                      check_one_process_per_chip)
 
         import tempfile
+
+        try:
+            check_one_process_per_chip(max(
+                config.gang_workers,
+                config.autoscale_max_workers
+                if config.autoscale == "on" else 0))
+        except ValueError as exc:
+            LOG.error("configuration error: %s", exc)
+            return EX_CONFIG
 
         raw = list(argv) if argv is not None else sys.argv[1:]
         gang_dir = (os.path.join(config.checkpoint_dir, "gang")

@@ -50,6 +50,10 @@ class CooccurrenceJob:
     def __init__(self, config: Config, scorer=None) -> None:
         if config.window_millis <= 0:
             raise ValueError("window size must be positive")
+        # Before the scorer's first compile (xla_cache.py says where).
+        from .xla_cache import enable_compilation_cache
+
+        enable_compilation_cache()
         self.config = config
         self.counters = Counters()
         # Graceful-degradation plane (--degrade, robustness/degrade.py):
@@ -272,7 +276,7 @@ class CooccurrenceJob:
             help="scorer stage seconds per fired window")
         # Fused-vs-chained wall-time split (--fused-window): the same
         # stage seconds, bucketed by which dispatch path the window
-        # took, so the fused win (or CPU-fallback neutrality) is a
+        # took, so the fused win (or its CPU neutrality) is a
         # first-class distribution in bench JSON and /metrics.
         self._hist_score_fused = REGISTRY.histogram(
             "cooc_window_score_seconds_fused",
@@ -349,7 +353,7 @@ class CooccurrenceJob:
         if backend == Backend.HYBRID:
             # Retired round 3: on its flagship config (1M-item Zipfian) the
             # sparse backend measured 2.2x the hybrid's on-chip throughput
-            # (TPU_ROUND2.jsonl 2026-07-30: 71.9k vs 32.1k pairs/s) and
+            # (71.9k vs 32.1k pairs/s, 2026-07-30, before this round) and
             # covers the same beyond-dense-ceiling vocabularies. The flag
             # stays accepted: checkpoints were interchangeable by design
             # (state/sparse_scorer.py snapshot docstring), so a hybrid

@@ -289,8 +289,8 @@ def rect_tile(R: int) -> int:
 
     Wide tiles amortize the sequential top-K merge: the on-chip dense
     sweep measured 2048 → 179 ms vs 512 → 300 ms at [8192, 61440] int16
-    (TPU_ROUND2.jsonl pallas-bench), and the int32 rectangle blocks are
-    8 sublanes, so a [8, 2048] i32 tile is ~64 KB — far under VMEM. The
+    (before this round; awaits a cell), and the int32 rectangle blocks
+    are 8 sublanes, so a [8, 2048] i32 tile is ~64 KB — far under VMEM. The
     sparse-pallas bench row re-times each rectangle width on chip.
     """
     return min(2048, R)
@@ -326,7 +326,7 @@ def topk_parity(vals_a, idx_a, vals_b, idx_b, rtol=1e-5, atol=1e-5):
     bench checks: scores allclose, and every UNTIED position (score
     unique within its row under the same tolerance) carries the same id.
     Tied positions may legitimately order differently. Vectorized —
-    safe to run inside a scarce TPU grant window.
+    cheap enough to run inside a chip session.
 
     Returns ``(scores_allclose: bool, untied_id_mismatches: int)``.
     """
@@ -344,11 +344,11 @@ def topk_parity(vals_a, idx_a, vals_b, idx_b, rtol=1e-5, atol=1e-5):
 def resolve_sparse_pallas_flag(use_pallas: str) -> bool:
     """Resolve an ``auto|on|off`` --pallas request for a SPARSE scorer.
 
-    auto is OFF for now: slab counts are int32, where the measured dense
-    A/B favored XLA ~5x (TPU_ROUND2.jsonl pallas-bench, v5e); the
-    sparse-pallas tpu_round2 row re-decides this on chip, and this
-    default flips if the rectangle form cliffs like dense int16 did
-    (247x). 'on' forces the kernel for every rectangle
+    auto is OFF for now: slab counts are int32, where the dense A/B
+    measured before this round favored XLA ~5x (v5e; awaits a cell,
+    ROADMAP A4); the sparse-pallas measurement re-decides this on chip,
+    and this default flips if the rectangle form cliffs like dense int16
+    did (247x). 'on' forces the kernel for every rectangle
     :func:`rect_supported` can carry; narrow buckets stay XLA either
     way."""
     if use_pallas not in ("auto", "on", "off"):
@@ -430,13 +430,14 @@ def pallas_score_rect(cnt, dst, row_sums, meta, observed, *, top_k: int,
         ),
         interpret=interpret,
     )(k11, dsf, rsj, rsi, obs)
-    # Same wire format as _score_rect: ids as an int32 BITCAST (the
-    # float->int conversion happens here in XLA, where it is exact and
-    # immune to the Mosaic carried-scratch issue the value-space
-    # encoding works around inside the kernel).
+    # Same wire format as _score_rect (results.pack_ids; the float->int
+    # conversion happens here in XLA, where it is exact and immune to
+    # the Mosaic carried-scratch issue the value-space encoding works
+    # around inside the kernel).
+    from ..state.results import pack_ids
+
     ids = idxf[:S, :top_k].astype(jnp.int32)
-    return jnp.stack([vals[:S, :top_k],
-                      jax.lax.bitcast_convert_type(ids, jnp.float32)])
+    return jnp.stack([vals[:S, :top_k], pack_ids(ids)])
 
 
 def _expand_kernel(basket_ref, new_ref, len_ref, skip_ref, sign_ref,

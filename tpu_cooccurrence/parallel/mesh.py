@@ -41,16 +41,7 @@ def shard_map_maybe_relaxed(f, mesh, in_specs, out_specs, relaxed: bool):
     contains a pallas_call (its ShapeDtypeStruct outputs carry no vma
     annotation, which ``check_vma=True`` — the default — rejects).
     XLA-only programs keep the full check."""
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-    if not relaxed:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs)
-    try:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:  # pragma: no cover - older jax spelling
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+    from jax import shard_map
+
+    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                     check_vma=not relaxed)

@@ -33,15 +33,12 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..metrics import Counters, RESCORED_ITEMS, ROW_SUM_PROCESS_WINDOW
 from ..observability.registry import REGISTRY, log_buckets
-from ..state.results import TopKBatch
+from ..state.results import TopKBatch, pack_ids, unpack_ids
 from ..ops.aggregate import (aggregate_window_coo, distinct_sorted,
                              narrow_deltas_int32)
 from ..ops.llr import llr_stable
@@ -88,7 +85,7 @@ class ShardedScorer:
     AUTO_INITIAL_ROWS = 64
 
     #: Column-tile width for the fused kernel (same measured choice as
-    #: DeviceScorer.PALLAS_TILE — swept on-chip, TPU_ROUND2.jsonl).
+    #: DeviceScorer.PALLAS_TILE — swept on-chip before this round).
     PALLAS_TILE = 2048
 
     def __init__(self, num_items: int, top_k: int, num_shards: Optional[int] = None,
@@ -97,9 +94,6 @@ class ShardedScorer:
                  max_score_rows_per_call: int = 8192,
                  count_dtype: str = "int32",
                  use_pallas: str = "auto") -> None:
-        from ..xla_cache import enable_compilation_cache
-
-        enable_compilation_cache()
         if count_dtype not in ("int32", "int16"):
             raise ValueError(f"count_dtype must be int32|int16, got {count_dtype}")
         self.count_dtype = np.dtype(count_dtype)
@@ -107,8 +101,8 @@ class ShardedScorer:
         self.n_shards = self.mesh.devices.size
         # Fused-kernel routing: same auto rule (and top-k-overflow
         # warning) as the dense single-chip scorer — the kernel exactly
-        # when int16 counts meet a real TPU (XLA collapses 247x there,
-        # TPU_ROUND2.jsonl pallas-bench), per shard inside the shard_map
+        # when int16 counts meet a real TPU (XLA collapsed 247x there,
+        # measured before this round), per shard inside the shard_map
         # body. With pallas on, the vocab pads to a tile multiple so the
         # kernel's column grid divides evenly.
         self.use_pallas = resolve_pallas_flag(use_pallas, self.count_dtype,
@@ -200,8 +194,7 @@ class ShardedScorer:
             # topk_padded: a vocab smaller than K pads with -inf/0.
             vals, idx = topk_padded(scores, top_k)
             # Pack per shard into [1, 2, S, K] f32 => one fetchable buffer.
-            return jnp.stack(
-                [vals, jax.lax.bitcast_convert_type(idx, jnp.float32)])[None]
+            return jnp.stack([vals, pack_ids(idx)])[None]
 
         self._update = jax.jit(shard_map(
             _update, mesh=self.mesh,
@@ -339,11 +332,11 @@ class ShardedScorer:
                     continue
                 rows_l.append(rb[d, :n_valid])
                 vals_l.append(host[0, :n_valid])
-                # Pallas packs ids as float values (astype), XLA as an
-                # int32 bitcast (view) — see ops/pallas_score.py.
+                # Pallas packs ids as float values (astype), XLA with
+                # results.pack_ids — see ops/pallas_score.py.
                 idx_l.append(host[1, :n_valid].astype(np.int32)
                              if self.use_pallas
-                             else host[1, :n_valid].view(np.int32))
+                             else unpack_ids(host[1, :n_valid]))
         return TopKBatch.concatenate(rows_l, idx_l, vals_l, self.top_k)
 
     # -- checkpoint ------------------------------------------------------

@@ -59,10 +59,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..metrics import Counters, RESCORED_ITEMS, ROW_SUM_PROCESS_WINDOW
@@ -73,7 +70,7 @@ from ..ops.aggregate import (aggregate_window_coo, distinct_sorted,
 from ..ops.device_scorer import pad_pow2, pad_pow4
 from ..ops.donation import donate_argnums
 from ..sampling.reservoir import PairDeltaBatch
-from ..state.results import TopKBatch
+from ..state.results import TopKBatch, unpack_ids
 from ..state.sparse_scorer import (_SENT, SlabIndex, _apply_cells,
                                    _pow2ceil, _score_rect, bucket_r,
                                    fixed_block, ladder_bits,
@@ -107,9 +104,7 @@ class ShardedSparseScorer:
                  wire_format: str = "raw",
                  fused_window: str = "off") -> None:
         from ..state.wire import CELL_DTYPES, cell_promote_threshold
-        from ..xla_cache import enable_compilation_cache
 
-        enable_compilation_cache()
         if cell_dtype not in CELL_DTYPES:
             raise ValueError(
                 f"cell_dtype must be one of {sorted(CELL_DTYPES)}, got "
@@ -1408,7 +1403,7 @@ class ShardedSparseScorer:
                 host = np.asarray(shard.data)[0]  # [2, rp, K]
                 rows_l.append(per_shard[d].astype(np.int32))
                 vals_l.append(host[0, :n])
-                idx_l.append(host[1, :n].view(np.int32))
+                idx_l.append(unpack_ids(host[1, :n]))
             # Clear marks only after the host copies are in hand (a
             # transient fetch failure must leave the rows drainable).
             self._tbl_dirty[rows] = False
@@ -1428,7 +1423,7 @@ class ShardedSparseScorer:
                 host = np.asarray(shard.data)[0]  # [2, S_pad, K]
                 rows_l.append(rows_d)
                 vals_l.append(host[0, : len(rows_d)])
-                idx_l.append(host[1, : len(rows_d)].view(np.int32))
+                idx_l.append(unpack_ids(host[1, : len(rows_d)]))
         return TopKBatch.concatenate(rows_l, idx_l, vals_l, self.top_k)
 
     # -- checkpoint -------------------------------------------------------

@@ -438,6 +438,26 @@ class _Worker:
         self.journal_grew = False
 
 
+def check_one_process_per_chip(max_workers: int, env=None) -> None:
+    """Refuse a gang whose workers would share this host's chips.
+
+    The gang launches every worker on THIS host, and a JAX process that
+    is not pinned to the CPU claims every local accelerator chip: the
+    second worker would fail or hang on chips the first one holds. So a
+    multi-worker gang must run pinned to the CPU (``JAX_PLATFORMS=cpu``,
+    the test and rehearsal setting); on a chip host, one process drives
+    all local chips (``--num-shards N`` without ``--gang-workers``).
+    Raises ValueError (the CLI's exit 78) before any worker starts."""
+    env = os.environ if env is None else env
+    if max_workers > 1 and env.get("JAX_PLATFORMS", "").strip() != "cpu":
+        raise ValueError(
+            f"--gang-workers would start up to {max_workers} JAX "
+            f"processes on this host, and each would claim every local "
+            f"accelerator chip; one process drives all local chips "
+            f"(--backend sparse --num-shards N without --gang-workers), "
+            f"or set JAX_PLATFORMS=cpu for a CPU gang")
+
+
 class GangSupervisor:
     """Launch, monitor, gang-kill and gang-restart a multi-controller
     worker set (see the module docstring for the contract).
@@ -917,6 +937,9 @@ class ReplicaFleetSupervisor:
         except OSError:
             pass
         env = dict(os.environ)
+        # Replicas are host-only (numpy over the delta log): pinned to
+        # the CPU, no replica can ever claim the writer's chip.
+        env["JAX_PLATFORMS"] = "cpu"
         env[GANG_DIR_ENV] = self.gang_dir
         env[RUN_ID_ENV] = self.run_id
         env[ATTEMPT_ENV] = str(self._slot_attempts[pid])

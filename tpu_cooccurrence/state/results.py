@@ -22,6 +22,27 @@ from typing import Iterator, List, Mapping, Optional, Tuple
 import numpy as np
 
 
+#: Packed ``[2, S, K]`` float32 result blocks carry the ids in lane 1 as
+#: an int32 bit pattern, biased by 2^23 so that every id's pattern is a
+#: normal float (for every id below 2^31 - 2^24). TPUs flush denormal
+#: floats to zero even in pure data movement (stack, scatter, gather):
+#: unbiased, every id below 2^23 came back as id 0 on a v5e (PR 21).
+ID_BIAS = 1 << 23
+
+
+def pack_ids(ids):
+    """Traced: int32 ids -> float32 lanes of a packed result block."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.bitcast_convert_type(ids + ID_BIAS, jnp.float32)
+
+
+def unpack_ids(lanes: np.ndarray) -> np.ndarray:
+    """Host: float32 id lanes fetched from a packed block -> int32 ids."""
+    return lanes.view(np.int32) - ID_BIAS
+
+
 @dataclasses.dataclass
 class TopKBatch:
     """One window's top-K results in packed array form (dense-id space).
