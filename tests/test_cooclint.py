@@ -340,6 +340,19 @@ def test_metric_name_rule():
     assert analyze_source(good, rules=["metric-name"]) == []
 
 
+@pytest.mark.parametrize("pragma,flagged", [
+    ("", True),
+    ("<!-- # cooclint: disable-file=metric-name -->\n", False),
+    ("<!-- # cooclint: disable-file=fault-site -->\n", True)])
+def test_metric_name_doc_check_honours_a_file_pragma(pragma, flagged):
+    """A document that quotes retired metric names by design (a change
+    log) opts out of the metric-name check with a file pragma in an HTML
+    comment; a pragma for another rule leaves the check on."""
+    md = pragma + "Watch `cooc_bogus_thing` on /metrics.\n"
+    findings = analyze_source(md, path="CHANGES.md", rules=["metric-name"])
+    assert _rules(findings) == (["metric-name"] if flagged else [])
+
+
 def test_metric_name_rule_counter_literals():
     bad = ('class J:\n'
            '    def f(self):\n'

@@ -308,8 +308,9 @@ def test_fused_sharded_gauges_and_journal(tmp_path):
     compiles = [r["fused_compiles"] for r in recs if "fused_compiles" in r]
     assert compiles and compiles[-1] == REGISTRY.gauge(
         "cooc_fused_bucket_compilations_total").get()
-    assert (REGISTRY.histogram("cooc_window_score_seconds_fused").count
-            == fused_total)
+    # Each fused window's record carries its scorer seconds.
+    assert sum(1 for r in recs
+               if r["fused"] and r["score_seconds"] > 0) == fused_total
 
 
 def test_fused_sharded_packed_uplink_is_ledger_booked(tmp_path):

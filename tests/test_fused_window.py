@@ -305,9 +305,14 @@ def test_fused_registry_counters_and_journal(tmp_path):
     flags = [r["fused"] for r in recs]
     assert flags.count(1) == 4            # the four pair-carrying windows
     assert set(flags) <= {0, 1}
-    # The wall-time split histograms saw the same windows.
-    fused_hist = REGISTRY.histogram("cooc_window_score_seconds_fused")
-    assert fused_hist.count == 4
+    # Each fused window's record carries its scorer seconds and counts:
+    # one program launched (the first window also allocates the deferred
+    # results table), shaped for at least the rows it scored.
+    fused = [r for r in recs if r["fused"]]
+    assert [r["counts"]["launches"] for r in fused] == [2, 1, 1, 1]
+    for r in fused:
+        assert r["score_seconds"] > 0
+        assert r["counts"]["score_cells"] >= r["counts"]["live_cells"] > 0
 
 
 # -- config validation --------------------------------------------------

@@ -19,8 +19,8 @@ import pytest
 from tpu_cooccurrence.observability import journal as jn
 from tpu_cooccurrence.observability import trace
 from tpu_cooccurrence.observability.journal import (
-    REPLICA_SPAN_STAGES, SPAN_STAGES, VERSION, RunJournal, mint_run_id,
-    run_context, validate_record)
+    CORE_STAGES, REPLICA_SPAN_STAGES, SPAN_STAGES, VERSION, RunJournal,
+    mint_run_id, run_context, validate_record)
 
 # The journal key registry (see module docstring). Kept as literals on
 # purpose — the lint rule scans tests/ for the emitted key *strings*.
@@ -31,7 +31,7 @@ JOURNAL_SCHEMA_KEYS = [
     "wall_unix", "counters", "wire", "degradation_level",
     "degrade_events", "breaker_state", "fused", "fused_compiles",
     "fallback_reason", "snapshot_generation", "snapshot_rows", "epoch",
-    "run_id", "process_id", "attempt", "spans",
+    "run_id", "process_id", "attempt", "spans", "counts",
     "ingest_offsets", "ingest_lag",
     # event records (EVENT_SCHEMA)
     "event", "window_seq",
@@ -66,7 +66,7 @@ def _spans(sample_s, score_s):
     """Core spans partitioning sample+score exactly, the job contract."""
     admit = 0.25 * sample_s
     parts = [("ingest-admission", admit), ("sample", sample_s - admit),
-             ("uplink-encode", 0.3 * score_s),
+             ("index", 0.1 * score_s), ("uplink-encode", 0.2 * score_s),
              ("dispatch", 0.5 * score_s), ("rescore", 0.2 * score_s)]
     off, out = 0.0, []
     for stage, secs in parts:
@@ -137,9 +137,11 @@ def test_span_validation_rejects_malformed():
 
 
 def test_span_stage_tables():
-    assert SPAN_STAGES[:5] == ("ingest-admission", "sample",
-                               "uplink-encode", "dispatch", "rescore")
-    assert SPAN_STAGES[5:] == ("snapshot-publish", "checkpoint-commit")
+    assert CORE_STAGES == ("ingest-admission", "sample", "index",
+                           "uplink-encode", "dispatch", "rescore")
+    assert SPAN_STAGES == CORE_STAGES + ("snapshot-publish",
+                                         "checkpoint-commit")
+    assert trace.CORE_STAGES is CORE_STAGES
     assert REPLICA_SPAN_STAGES == ("delta-apply", "publish")
 
 
@@ -188,10 +190,10 @@ def test_job_records_spans_that_reconcile(tmp_path, depth):
         assert r["run_id"] == "tracerun12ab"
         assert r["process_id"] == 0 and r["attempt"] == 0
         stages = [s[0] for s in r["spans"]]
-        assert stages[:5] == list(SPAN_STAGES[:5])
-        # The core contract: the five core spans partition
+        assert stages[:len(CORE_STAGES)] == list(CORE_STAGES)
+        # The core contract: the core spans partition
         # sample_seconds + score_seconds (to field rounding).
-        core = sum(s[2] for s in r["spans"] if s[0] in SPAN_STAGES[:5])
+        core = sum(s[2] for s in r["spans"] if s[0] in CORE_STAGES)
         assert core == pytest.approx(
             r["sample_seconds"] + r["score_seconds"], abs=2e-6)
         # Offsets are contiguous: each span starts where the prior ended.
@@ -337,9 +339,9 @@ def test_chaos_gang_crash_restart_merges_cleanly(tmp_path):
             key = (ev["pid"], ev["args"]["window_seq"], ev["name"])
             assert key not in seen, f"duplicate span {key}"
             seen.add(key)
-    # p0 fired 1-6 (surviving attempts), p1 fired 1-6: 12 windows x 5
-    # core spans.
-    assert len(seen) == 12 * 5
+    # p0 fired 1-6 (surviving attempts), p1 fired 1-6: 12 windows x
+    # the core spans.
+    assert len(seen) == 12 * len(CORE_STAGES)
 
 
 def test_chaos_replica_resync_mid_tail():

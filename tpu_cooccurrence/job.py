@@ -33,7 +33,9 @@ from .io.parse import InteractionBatch
 from .sampling.item_cut import ItemInteractionCut
 from .sampling.reservoir import UserReservoirSampler
 from .sampling.sliding import SlidingBasketSampler
-from .observability import LEDGER, StepTimer, WindowStats, clock
+from .observability import (LEDGER, StageClock, StepTimer, WindowStats,
+                            clock)
+from .observability.journal import CORE_STAGES
 from .observability.registry import BYTES_BUCKETS, REGISTRY
 from .robustness import faults
 from .state.rescorer import HostRescorer, WindowTopK
@@ -227,6 +229,9 @@ class CooccurrenceJob:
         self.emissions = 0
         self.windows_fired = 0
         self.step_timer = StepTimer()
+        # The sampling thread's stage clock (sample, ingest-admission):
+        # reset per fired window, read into the window's sample_seconds.
+        self._sample_clock = StageClock()
         # Tracing plane (observability/journal.py): fleet correlation
         # identity, stamped on every journal record this job writes. A
         # supervising parent mints run_id once and threads it (plus the
@@ -274,18 +279,6 @@ class CooccurrenceJob:
         self._hist_score = REGISTRY.histogram(
             "cooc_window_score_seconds",
             help="scorer stage seconds per fired window")
-        # Fused-vs-chained wall-time split (--fused-window): the same
-        # stage seconds, bucketed by which dispatch path the window
-        # took, so the fused win (or its CPU neutrality) is a
-        # first-class distribution in bench JSON and /metrics.
-        self._hist_score_fused = REGISTRY.histogram(
-            "cooc_window_score_seconds_fused",
-            help="scorer stage seconds for windows on the fused "
-                 "one-dispatch path")
-        self._hist_score_chained = REGISTRY.histogram(
-            "cooc_window_score_seconds_chained",
-            help="scorer stage seconds for windows on the chained "
-                 "scatter+score path")
         self._hist_total = REGISTRY.histogram(
             "cooc_window_total_seconds",
             help="sample+score seconds per fired window")
@@ -635,29 +628,32 @@ class CooccurrenceJob:
                     if setk is not None:
                         setk(self.degrade.effective_top_k(
                             self.config.top_k))
-            with clock() as sample_clock:
-                # Inside the sample clock on purpose: a delay_ms
+            # The window's sampling side: ingest-admission (the item
+            # cut) and sample (everything else) on the job's own
+            # StageClock; sample_seconds is their sum.
+            clk = self._sample_clock
+            clk.reset()
+            with clk.stage("sample"):
+                # Inside the sample stage on purpose: a delay_ms
                 # injected here bills the window's wall time, so chaos
                 # tests can manufacture exactly the overloaded windows
                 # the degradation/autoscale planes key on. (Crash kinds
                 # are indifferent to the clock.)
                 if faults.PLAN is not None:
                     faults.PLAN.fire("window_fire", seq=self.windows_fired)
-                admit_seconds = 0.0
                 if self.sliding:
                     # The sliding sampler folds admission into its own
                     # fire; no separate admission cut to time.
                     pairs = self.sampler.fire(users, items)
-                else:
-                    # Item cut (or pass-through when --skip-cuts). Timed
-                    # separately: the journal's ingest-admission span is
-                    # the admission-cut share of sample_seconds.
-                    with clock() as admit_clock:
-                        if self.config.skip_cuts:
-                            sampled = np.ones(len(items), dtype=bool)
-                        else:
-                            sampled = self.item_cut.fire(items)
-                    admit_seconds = admit_clock.seconds
+            if not self.sliding:
+                # Item cut (or pass-through when --skip-cuts): the
+                # journal's ingest-admission span.
+                with clk.stage("ingest-admission"):
+                    if self.config.skip_cuts:
+                        sampled = np.ones(len(items), dtype=bool)
+                    else:
+                        sampled = self.item_cut.fire(items)
+                with clk.stage("sample"):
                     # User reservoir.
                     pairs, feedback_items = self.sampler.fire(users, items, sampled)
                     # Feedback decrements before the next window fire
@@ -665,18 +661,21 @@ class CooccurrenceJob:
                     if not self.config.skip_cuts and len(feedback_items):
                         self.item_cut.apply_feedback(
                             feedback_items, self.config.development_mode, self.counters)
-                if self.pipeline is not None:
+            if self.pipeline is not None:
+                with clk.stage("sample"):
                     # Pre-fold on the sampling thread for backends that
                     # accept aggregated deltas — the scorer worker's turn
                     # then starts at slot allocation / COO packing.
                     payload, slot, stall = self._stage(pairs)
+            sample_seconds = sum(clk.seconds.values())
+            admit_seconds = clk.seconds.get("ingest-admission", 0.0)
             if self.pipeline is not None:
                 from .pipeline import StagedWindow
 
                 self.pipeline.submit(StagedWindow(
                     ts=ts, payload=payload, events=len(items),
                     raw_pairs=len(pairs),
-                    sample_seconds=sample_clock.seconds, slot=slot,
+                    sample_seconds=sample_seconds, slot=slot,
                     seq=self.windows_fired, stall_seconds=stall,
                     admit_seconds=admit_seconds))
             else:
@@ -692,7 +691,7 @@ class CooccurrenceJob:
                     timestamp=ts, events=len(items), pairs=len(pairs),
                     rows_scored=getattr(self.scorer, "last_dispatched_rows",
                                         len(window_out)),
-                    sample_seconds=sample_clock.seconds,
+                    sample_seconds=sample_seconds,
                     score_seconds=score_clock.seconds),
                     seq=self.windows_fired,
                     admit_seconds=admit_seconds)
@@ -748,29 +747,30 @@ class CooccurrenceJob:
         """Carve one window's wall time into ordered journal span tuples
         ``[stage, start_offset_s, seconds]`` (journal.SPAN_STAGES).
 
-        The five core stages partition ``sample_seconds +
-        score_seconds`` exactly by construction: admission is the timed
-        cut share of sampling (clamped), uplink-encode / rescore come
-        from the scorer's StageClock (clamped into score_seconds), and
-        dispatch is the residual. Boundary stages stashed by the
-        PREVIOUS window's post-record work (_absorb publish, checkpoint
-        commit) ride this record as trailing spans.
+        The core stages (journal.CORE_STAGES) partition
+        ``sample_seconds + score_seconds`` exactly by construction:
+        admission is the timed cut share of sampling (clamped), index /
+        uplink-encode / rescore come from the scorer's StageClock
+        (clamped into score_seconds, in that order), and dispatch is
+        the residual. Boundary stages stashed by the PREVIOUS window's
+        post-record work (_absorb publish, checkpoint commit) ride this
+        record as trailing spans.
         """
         admit = max(0.0, min(admit_seconds, stats.sample_seconds))
         sc = getattr(self.scorer, "stage_clock", None)
         stage_s = sc.seconds if sc is not None else {}
-        enc = max(0.0, min(stage_s.get("uplink-encode", 0.0),
-                           stats.score_seconds))
-        resc = max(0.0, min(stage_s.get("rescore", 0.0),
-                            stats.score_seconds - enc))
-        disp = max(0.0, stats.score_seconds - enc - resc)
+        carved = {}
+        left = stats.score_seconds
+        for name in ("index", "uplink-encode", "rescore"):
+            carved[name] = max(0.0, min(stage_s.get(name, 0.0), left))
+            left -= carved[name]
+        carved["dispatch"] = max(0.0, left)
+        carved["ingest-admission"] = admit
+        carved["sample"] = stats.sample_seconds - admit
         off = 0.0
         spans = []
-        for name, secs in (("ingest-admission", admit),
-                           ("sample", stats.sample_seconds - admit),
-                           ("uplink-encode", enc),
-                           ("dispatch", disp),
-                           ("rescore", resc)):
+        for name in CORE_STAGES:
+            secs = carved[name]
             spans.append([name, round(off, 9), round(secs, 9)])
             off += secs
         pub, self._pending_publish_s = self._pending_publish_s, 0.0
@@ -806,6 +806,11 @@ class CooccurrenceJob:
         the sampling thread between fires; their bytes attribute to the
         next window's wire delta (totals stay exact).
         """
+        spans = self._build_spans(stats, admit_seconds)
+        stats.stages = {name: secs for name, _off, secs in spans
+                        if name in CORE_STAGES}
+        sc = getattr(self.scorer, "stage_clock", None)
+        stats.counts = dict(sc.counts) if sc is not None else {}
         self.step_timer.record(stats)
         wire = LEDGER.snapshot()
         wire_delta = {k: wire[k] - self._prev_wire.get(k, 0) for k in wire}
@@ -816,12 +821,9 @@ class CooccurrenceJob:
         self._hist_score.observe(stats.score_seconds)
         self._hist_total.observe(stats.seconds)
         self._hist_uplink.observe(wire_delta["h2d_bytes"])
-        # Dispatch-path split: only backends that expose the flag
-        # (DeviceScorer, incl. behind the breaker wrapper) participate.
+        # Dispatch path: only backends that expose the flag (incl.
+        # behind the breaker wrapper) report it.
         fused = getattr(self.scorer, "last_dispatch_fused", None)
-        if fused is not None:
-            (self._hist_score_fused if fused
-             else self._hist_score_chained).observe(stats.score_seconds)
         self._gauge_windows.set(seq)
         self._gauge_last_window.set(time.time())
         level = degrade_events = None
@@ -842,7 +844,6 @@ class CooccurrenceJob:
                 seq, stats.seconds,
                 self.degrade.overloaded_bit()
                 if self.degrade is not None else False)
-        spans = self._build_spans(stats, admit_seconds)
         # Ingest plane (partitioned source only): the wire position the
         # sampling thread snapshotted when this seq fired — per-partition
         # offsets + lag into the journal, the worst lag onto the gauge.
@@ -881,6 +882,8 @@ class CooccurrenceJob:
             }
             self._stamp(rec)
             rec["spans"] = spans
+            if stats.counts:
+                rec["counts"] = stats.counts
             if ingest is not None:
                 # The exactly-once ledger: the restored checkpoint's
                 # ingest_offsets section must match the last committed

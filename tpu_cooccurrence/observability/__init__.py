@@ -36,6 +36,11 @@ class WindowStats:
     rows_scored: int
     sample_seconds: float
     score_seconds: float
+    #: The window's core span seconds (journal.CORE_STAGES), the same
+    #: carve the journal's ``spans`` and ``/healthz`` carry.
+    stages: Dict[str, float] = dataclasses.field(default_factory=dict)
+    #: The scorer's per-window counts (:attr:`StageClock.counts`).
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def seconds(self) -> float:
@@ -74,14 +79,12 @@ class StepTimer:
         self.total_score_seconds += stats.score_seconds
 
     def summary(self) -> Dict[str, float]:
-        total = self.total_sample_seconds + self.total_score_seconds
         return {
             "windows": self.total_windows,
             "events": self.total_events,
             "pairs": self.total_pairs,
             "sample_seconds": round(self.total_sample_seconds, 4),
             "score_seconds": round(self.total_score_seconds, 4),
-            "pairs_per_sec": round(self.total_pairs / total, 1) if total else 0.0,
         }
 
     def slowest(self, n: int = 3) -> list:
@@ -262,28 +265,44 @@ class clock:  # noqa: N801 - tiny helper
 
 
 class StageClock:
-    """Per-window stage-seconds accumulator for the tracing plane.
+    """Per-window stage seconds and counts: the tracing plane's one span
+    primitive.
 
     The scorers :meth:`reset` it at ``process_window`` entry and wrap
-    their encode/upload and dispatch sections with :meth:`stage`; the
-    job reads :attr:`seconds` afterwards to carve the window's
-    ``score_seconds`` into journal span tuples. Re-entering the same
-    stage accumulates (the chained path uploads three operand groups
-    under one ``uplink-encode`` stage). Not thread-safe by design: one
-    scorer thread owns one clock.
+    their index, encode/upload and rescore sections with :meth:`stage`;
+    the job reads :attr:`seconds` afterwards to carve the window's
+    ``score_seconds`` into journal span tuples (the job's own clock
+    times ``sample`` and ``ingest-admission`` the same way). Each stage
+    is also a ``jax.profiler.TraceAnnotation`` named ``cooc/<stage>``,
+    so a profiler trace shows the program's stages on the device
+    trace's clock. Re-entering the same stage accumulates (the chained
+    path uploads three operand groups under one ``uplink-encode``
+    stage); stages do not nest. :meth:`add` keeps per-window integer
+    counts (``launches``, ``score_cells``, ``live_cells``) beside the
+    seconds. Not thread-safe by design: one thread owns one clock.
     """
 
     def __init__(self) -> None:
         self.seconds: Dict[str, float] = {}
+        self.counts: Dict[str, int] = {}
 
     def reset(self) -> None:
         self.seconds = {}
+        self.counts = {}
+
+    def add(self, name: str, n: int = 1) -> None:
+        self.counts[name] = self.counts.get(name, 0) + int(n)
 
     @contextlib.contextmanager
     def stage(self, name: str) -> Iterator[None]:
+        # Imported here, not at module top: journal.py and trace.py
+        # import this package and must stay jax-free.
+        from jax.profiler import TraceAnnotation
+
         t0 = time.perf_counter()
         try:
-            yield
+            with TraceAnnotation("cooc/" + name):
+                yield
         finally:
             self.seconds[name] = (self.seconds.get(name, 0.0)
                                   + time.perf_counter() - t0)

@@ -47,8 +47,9 @@ timeline and stitch pre-crash records to their post-restart successors.
 Window and replica records additionally carry ``spans``: ordered
 ``[stage, start_offset_s, seconds]`` tuples (:data:`SPAN_STAGES` /
 :data:`REPLICA_SPAN_STAGES`) formalizing the stage-seconds breakdown.
-The core window stages (``ingest-admission`` → ``sample`` →
-``uplink-encode`` → ``dispatch`` → ``rescore``) partition
+The core window stages (:data:`CORE_STAGES`: ``ingest-admission`` →
+``sample`` → ``index`` → ``uplink-encode`` → ``dispatch`` →
+``rescore``) partition
 ``sample_seconds + score_seconds`` exactly; the boundary stages
 (``snapshot-publish``, ``checkpoint-commit``) run after the record is
 flushed, so they are journaled on the first record *after* the boundary
@@ -81,12 +82,15 @@ RUN_ID_ENV = "TPU_COOC_RUN_ID"
 #: link to the prior attempt's instead of starting an unrelated stream.
 ATTEMPT_ENV = "TPU_COOC_ATTEMPT"
 
-#: Canonical window-record span stages, in lifecycle order. The first
-#: five partition ``sample_seconds + score_seconds`` exactly; the last
-#: two are boundary stages measured after the record flushes (journaled
-#: on the NEXT record, excluded from wall-seconds reconciliation).
-SPAN_STAGES = ("ingest-admission", "sample", "uplink-encode", "dispatch",
-               "rescore", "snapshot-publish", "checkpoint-commit")
+#: Core window-record span stages, in lifecycle order: they partition
+#: ``sample_seconds + score_seconds`` exactly.
+CORE_STAGES = ("ingest-admission", "sample", "index", "uplink-encode",
+               "dispatch", "rescore")
+
+#: Canonical window-record span stages: the core stages, then two
+#: boundary stages measured after the record flushes (journaled on the
+#: NEXT record, excluded from wall-seconds reconciliation).
+SPAN_STAGES = CORE_STAGES + ("snapshot-publish", "checkpoint-commit")
 
 #: Replica-record span stages: replay one delta generation, then swap
 #: the snapshot — the window's lifetime across the process boundary.
@@ -184,6 +188,9 @@ SCHEMA = {
     "attempt": (False, int),     # supervisor restart ordinal
     "spans": (False, list),      # ordered [stage, start_offset_s,
                                  # seconds] tuples (SPAN_STAGES)
+    "counts": (False, dict),     # the scorer's per-window counts
+                                 # (StageClock.counts: launches,
+                                 # score_cells, live_cells)
 }
 
 

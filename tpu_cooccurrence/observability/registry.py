@@ -72,12 +72,10 @@ CANONICAL_METRICS = frozenset({
     # pipelined execution (pipeline.py)
     "cooc_pipeline_queue_wait_seconds",
     "cooc_pipeline_ring_depth",
-    # fused one-dispatch window path (--fused-window; job.py splits the
-    # score-stage seconds, ops/device_scorer.py counts the dispatches)
+    # fused one-dispatch window path (--fused-window; the scorers count
+    # the dispatches of each path)
     "cooc_fused_dispatches_total",
     "cooc_chained_dispatches_total",
-    "cooc_window_score_seconds_fused",
-    "cooc_window_score_seconds_chained",
     # fused-sparse shape specialization (state/sparse_scorer.py): how
     # many distinct fused-program shapes (= XLA compiles) the pow2
     # (ops, touched-rows, registry-delta) bucketing produced

@@ -45,13 +45,9 @@ import os
 import sys
 from typing import Dict, List, Optional, Tuple
 
-from .journal import REPLICA_SPAN_STAGES, SPAN_STAGES, read_records
+from .journal import (CORE_STAGES, REPLICA_SPAN_STAGES, SPAN_STAGES,
+                      read_records)
 from .registry import SECONDS_BUCKETS, Histogram
-
-#: Core window stages whose span seconds must partition the record's
-#: ``sample_seconds + score_seconds`` (boundary stages are measured
-#: after the record flushes and excluded — journal.SPAN_STAGES).
-CORE_STAGES = SPAN_STAGES[:5]
 
 #: Relative tolerance for the core-span / wall-seconds reconciliation.
 RECONCILE_REL_TOL = 0.01
@@ -154,8 +150,8 @@ def waterfall(windows: List[dict],
 
 
 def reconcile(windows: List[dict]) -> dict:
-    """Check the span contract: per window, the five core stages must
-    sum to ``sample_seconds + score_seconds`` (rel tol
+    """Check the span contract: per window, the core stages
+    (``CORE_STAGES``) must sum to ``sample_seconds + score_seconds`` (rel tol
     ``RECONCILE_REL_TOL``; sub-millisecond windows skipped — journal
     field rounding dominates there)."""
     checked = violations = 0

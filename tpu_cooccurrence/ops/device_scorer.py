@@ -45,7 +45,7 @@ import jax.numpy as jnp
 
 from ..metrics import Counters, RESCORED_ITEMS, ROW_SUM_PROCESS_WINDOW
 from .. import tuning  # noqa: E402  (registry: stdlib-only)
-from ..observability import LEDGER
+from ..observability import LEDGER, StageClock
 from ..observability.registry import REGISTRY
 from ..robustness import faults
 from ..sampling.reservoir import BasketBatch, PairDeltaBatch
@@ -181,9 +181,11 @@ def _apply_coo(C, row_sums, src, dst, delta, num_items: int):
     # wraparound then matches the reference's documented silent-overflow
     # behavior, ItemRowAggregator.java:16). Row sums stay int32 always:
     # they grow far past 2^15.
-    C = C.at[src, dst].add(delta.astype(C.dtype))
-    rs_delta = jnp.zeros((num_items,), dtype=jnp.int32).at[src].add(delta)
-    return C, row_sums + rs_delta
+    with jax.named_scope("scatter"):
+        C = C.at[src, dst].add(delta.astype(C.dtype))
+        rs_delta = jnp.zeros((num_items,), dtype=jnp.int32).at[src].add(
+            delta)
+        return C, row_sums + rs_delta
 
 
 @functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1), static_argnames=("num_items",))
@@ -340,17 +342,19 @@ def _score_body(C, row_sums, rows, observed, top_k: int,
     # program (`_fused_window_emit`/`_defer`): one body, so the two
     # dispatch shapes cannot drift numerically — the fused path's
     # bit-identical-to-chained contract rides on this.
-    counts = C[rows]  # [S, I] int32
-    k11 = counts.astype(jnp.float32)
-    rs = row_sums.astype(jnp.float32)
-    rsi = rs[rows][:, None]
-    rsj = rs[None, :]
-    k12 = rsi - k11
-    k21 = rsj - k11
-    k22 = observed + k11 - k12 - k21
-    scores = llr_stable(k11, k12, k21, k22)
-    scores = jnp.where(counts != 0, scores, -jnp.inf)
-    vals, idx = topk_padded(scores, top_k)
+    with jax.named_scope("gather"):
+        counts = C[rows]  # [S, I] int32
+        rs = row_sums.astype(jnp.float32)
+        rsi = rs[rows][:, None]
+    with jax.named_scope("score"):
+        k11 = counts.astype(jnp.float32)
+        rsj = rs[None, :]
+        k12 = rsi - k11
+        k21 = rsj - k11
+        k22 = observed + k11 - k12 - k21
+        scores = llr_stable(k11, k12, k21, k22)
+        scores = jnp.where(counts != 0, scores, -jnp.inf)
+        vals, idx = topk_padded(scores, top_k)
     if packed:
         # One fused [2, S, K] float32 result => a single device->host fetch.
         return jnp.stack([vals, pack_ids(idx)])
@@ -400,9 +404,12 @@ def _fused_score_packed(C, row_sums, rows, observed, top_k: int,
 
     blk = row_block(C.dtype)
     sp = rows.shape[0]  # caller pads to a pow4 bucket (a blk multiple)
-    gathered = C[rows]
-    rsi = row_sums[rows].reshape(sp, 1)
+    with jax.named_scope("gather"):
+        gathered = C[rows]
+        rsi = row_sums[rows].reshape(sp, 1)
     rs2d = row_sums.reshape(1, C.shape[0])
+    # Unscoped: the kernel's custom call is the score stage and keeps
+    # the enclosing program's name (see pallas_score_topk).
     vals, idx = _pallas_topk_gathered(gathered, rs2d, rsi, observed,
                                       top_k=top_k, tile=tile, blk=blk,
                                       interpret=interpret)
@@ -486,6 +493,17 @@ def _gather_packed(tbl, rows):
     return tbl[:, rows]
 
 
+@functools.partial(jax.jit, static_argnames=("items_cap", "top_k"))
+def _empty_table(items_cap: int, top_k: int):
+    return jnp.full((2, items_cap, top_k), -jnp.inf, jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnames=("items_cap",))
+def _resized_table(tbl, items_cap: int):
+    m = min(items_cap, tbl.shape[1])
+    return _empty_table(items_cap, tbl.shape[2]).at[:, :m].set(tbl[:, :m])
+
+
 class DeferredResultsTable:
     """Device-resident latest-results table for deferred-results scorers.
 
@@ -509,22 +527,25 @@ class DeferredResultsTable:
         self.tbl = None  # lazy: allocated at the first scoring dispatch
         self.dirty = np.zeros(items_cap, dtype=bool)
 
-    def resize(self, items_cap: int) -> None:
-        """Track a vocab-capacity change, preserving entries and marks."""
+    def resize(self, items_cap: int) -> int:
+        """Track a vocab-capacity change, preserving entries and marks.
+        Returns the number of device programs launched (0 or 1)."""
         m = min(items_cap, len(self.dirty))
         dirty = np.zeros(items_cap, dtype=bool)
         dirty[:m] = self.dirty[:m]
         self.dirty = dirty
-        if self.tbl is not None and self.tbl.shape[1] != items_cap:
-            old = self.tbl
-            self.tbl = jnp.full((2, items_cap, self.top_k), -jnp.inf,
-                                jnp.float32).at[:, :m].set(old[:, :m])
+        if self.tbl is None or self.tbl.shape[1] == items_cap:
+            return 0
+        self.tbl = _resized_table(self.tbl, items_cap=items_cap)
+        return 1
 
-    def ensure(self) -> None:
-        """Allocate the device table (before a window's first scatter)."""
-        if self.tbl is None:
-            self.tbl = jnp.full((2, len(self.dirty), self.top_k),
-                                -jnp.inf, jnp.float32)
+    def ensure(self) -> int:
+        """Allocate the device table (before a window's first scatter).
+        Returns the number of device programs launched (0 or 1)."""
+        if self.tbl is not None:
+            return 0
+        self.tbl = _empty_table(items_cap=len(self.dirty), top_k=self.top_k)
+        return 1
 
     def scatter(self, packed, scatter_rows: np.ndarray) -> None:
         """Scatter one packed block; padded entries must carry a sentinel
@@ -604,9 +625,13 @@ class DeviceScorer:
         # kernel expands them on chip); the sparse fused path consumes
         # aggregated deltas instead and leaves this False.
         self.wants_baskets = self.use_fused
-        # Which path the LAST process_window dispatch took — the job's
-        # fused-vs-chained wall-time split and journal field read it.
+        # Which path the LAST process_window dispatch took — the
+        # journal's ``fused`` field and /healthz read it.
         self.last_dispatch_fused = False
+        # Tracing plane: per-window stage seconds (index / uplink-encode
+        # / rescore; the job carves the rest of score_seconds into
+        # dispatch) and counts (launches, score_cells, live_cells).
+        self.stage_clock = StageClock()
         self._fused_dispatches = REGISTRY.gauge(
             "cooc_fused_dispatches_total",
             help="windows dispatched through the fused one-dispatch "
@@ -670,11 +695,12 @@ class DeviceScorer:
         n = self.num_items
         while n <= max_id:
             n *= 2
+        self.stage_clock.add("launches")
         self.C, self.row_sums = _grow_dense(self.C, self.row_sums, n=n)
         self.num_items = self.num_items_logical = n
         self.max_score_rows = score_row_budget(n, self._max_score_rows_cap)
         if self._results is not None:
-            self._results.resize(n)
+            self.stage_clock.add("launches", self._results.resize(n))
 
     def process_window(self, ts: int, pairs) -> TopKBatch:
         self._breaker_seq += 1
@@ -684,6 +710,8 @@ class DeviceScorer:
             faults.PLAN.fire("scorer_breaker", seq=self._breaker_seq)
         self.last_dispatched_rows = 0
         self.last_dispatch_fused = False
+        clk = self.stage_clock
+        clk.reset()
         if isinstance(pairs, BasketBatch):
             if self.use_fused:
                 routed = self._try_fused(ts, pairs)
@@ -700,10 +728,13 @@ class DeviceScorer:
             # No new dispatch this window — drain any completed in-flight
             # results now instead of withholding them behind idle windows.
             return self.flush()
-        self._ensure_capacity(int(max(pairs.src.max(), pairs.dst.max())))
-        src, dst, agg_delta = aggregate_window_coo(
-            pairs.src, pairs.dst, pairs.delta)
-        agg_delta = narrow_deltas_int32(agg_delta)
+        with clk.stage("index"):
+            self._ensure_capacity(int(max(pairs.src.max(),
+                                          pairs.dst.max())))
+            src, dst, agg_delta = aggregate_window_coo(
+                pairs.src, pairs.dst, pairs.delta)
+            agg_delta = narrow_deltas_int32(agg_delta)
+            rows = distinct_sorted(src)
 
         # Bounded COO buckets: chunk to max_pairs_per_step, pad each chunk to
         # a power of two (recompile guard, SURVEY §7 "dynamic shapes").
@@ -716,84 +747,98 @@ class DeviceScorer:
         # uint16 wire format halves transfer bytes whenever the vocab and
         # the window's cell deltas allow it (bytes on the link are
         # wall-clock).
-        use_u16 = (self.num_items <= (1 << 16)
-                   and len(agg_delta) > 0
-                   and int(agg_delta.min()) >= -(1 << 15)
-                   and int(agg_delta.max()) < (1 << 15))
-        for lo in range(0, len(src), self.max_pairs_per_step):
-            n = min(len(src) - lo, self.max_pairs_per_step)
-            pad = pad_pow2(n, minimum=1 << 14)
-            if use_u16:
-                coo = np.zeros((3, pad), dtype=np.uint16)
-                coo[2, :n] = agg_delta[lo: lo + n].astype(
-                    np.int16).view(np.uint16)
-                update = _update_coo_u16
-            else:
-                coo = np.zeros((3, pad), dtype=np.int32)
-                coo[2, :n] = agg_delta[lo: lo + n]
-                update = _update_coo
-            coo[0, :n] = src[lo: lo + n]
-            coo[1, :n] = dst[lo: lo + n]
-            check_coo_chunk(coo, n)
-            parts = split_upload_auto(coo)
-            if parts is not None:
-                for p in parts:
-                    LEDGER.up("coo-chunk", p)
-                update_chunked = (_update_coo_u16_chunked if use_u16
-                                  else _update_coo_chunked)
-                self.C, self.row_sums = update_chunked(
-                    self.C, self.row_sums, parts,
-                    num_items=self.num_items)
-            else:
-                LEDGER.up("coo", coo)
-                self.C, self.row_sums = update(
-                    self.C, self.row_sums, coo, num_items=self.num_items)
+        with clk.stage("uplink-encode"):
+            use_u16 = (self.num_items <= (1 << 16)
+                       and len(agg_delta) > 0
+                       and int(agg_delta.min()) >= -(1 << 15)
+                       and int(agg_delta.max()) < (1 << 15))
+            for lo in range(0, len(src), self.max_pairs_per_step):
+                n = min(len(src) - lo, self.max_pairs_per_step)
+                pad = pad_pow2(n, minimum=1 << 14)
+                if use_u16:
+                    coo = np.zeros((3, pad), dtype=np.uint16)
+                    coo[2, :n] = agg_delta[lo: lo + n].astype(
+                        np.int16).view(np.uint16)
+                    update = _update_coo_u16
+                else:
+                    coo = np.zeros((3, pad), dtype=np.int32)
+                    coo[2, :n] = agg_delta[lo: lo + n]
+                    update = _update_coo
+                coo[0, :n] = src[lo: lo + n]
+                coo[1, :n] = dst[lo: lo + n]
+                check_coo_chunk(coo, n)
+                parts = split_upload_auto(coo)
+                clk.add("launches")
+                if parts is not None:
+                    for p in parts:
+                        LEDGER.up("coo-chunk", p)
+                    update_chunked = (_update_coo_u16_chunked if use_u16
+                                      else _update_coo_chunked)
+                    self.C, self.row_sums = update_chunked(
+                        self.C, self.row_sums, parts,
+                        num_items=self.num_items)
+                else:
+                    LEDGER.up("coo", coo)
+                    self.C, self.row_sums = update(
+                        self.C, self.row_sums, coo, num_items=self.num_items)
 
         window_sum = int(pairs.delta.sum())
         self.observed += window_sum
         self.counters.add(ROW_SUM_PROCESS_WINDOW, window_sum)
 
-        rows = distinct_sorted(src)
         self.counters.add(RESCORED_ITEMS, len(rows))
         self.last_dispatched_rows = len(rows)
         self._chained_dispatches.add(1)
         if self.defer_results:
-            self._results.ensure()
+            clk.add("launches", self._results.ensure())
         chunks: List[Tuple[np.ndarray, int, object]] = []
-        for lo in range(0, len(rows), self.max_score_rows):
-            chunk = rows[lo: lo + self.max_score_rows]
-            s = len(chunk)
-            pad_s = min(pad_pow4(s, minimum=64), self.max_score_rows)
-            rows_padded = np.zeros(pad_s, dtype=np.int32)
-            rows_padded[:s] = chunk
-            LEDGER.up("score-rows", rows_padded)
-            if self.use_pallas:
-                from .pallas_score import pallas_score_topk
+        with clk.stage("rescore"):
+            for lo in range(0, len(rows), self.max_score_rows):
+                chunk = rows[lo: lo + self.max_score_rows]
+                s = len(chunk)
+                pad_s = min(pad_pow4(s, minimum=64), self.max_score_rows)
+                self._count_scored(s, pad_s)
+                rows_padded = np.zeros(pad_s, dtype=np.int32)
+                rows_padded[:s] = chunk
+                LEDGER.up("score-rows", rows_padded)
+                if self.use_pallas:
+                    from .pallas_score import pallas_score_topk
 
-                packed = pallas_score_topk(
-                    self.C, self.row_sums, jnp.asarray(rows_padded),
-                    np.float32(self.observed), top_k=self.top_k,
-                    tile=self.PALLAS_TILE, interpret=self._pallas_interpret,
-                    packed=True)
-            else:
-                packed = _score(self.C, self.row_sums, rows_padded,
-                                np.float32(self.observed), top_k=self.top_k,
-                                packed=True)
-            if self.defer_results:
-                # Padded entries gather row 0 but must NOT scatter there.
-                scatter_rows = np.full(pad_s, _SENT_ROW, dtype=np.int32)
-                scatter_rows[:s] = chunk
-                self._results.scatter(packed, scatter_rows)
-                continue
-            if hasattr(packed, "copy_to_host_async"):
-                packed.copy_to_host_async()
-            chunks.append((chunk, s, packed))
+                    packed = pallas_score_topk(
+                        self.C, self.row_sums, jnp.asarray(rows_padded),
+                        np.float32(self.observed), top_k=self.top_k,
+                        tile=self.PALLAS_TILE,
+                        interpret=self._pallas_interpret, packed=True)
+                else:
+                    packed = _score(self.C, self.row_sums, rows_padded,
+                                    np.float32(self.observed),
+                                    top_k=self.top_k, packed=True)
+                if self.defer_results:
+                    # Padded entries gather row 0 but must NOT scatter
+                    # there.
+                    scatter_rows = np.full(pad_s, _SENT_ROW, dtype=np.int32)
+                    scatter_rows[:s] = chunk
+                    clk.add("launches")
+                    self._results.scatter(packed, scatter_rows)
+                    continue
+                if hasattr(packed, "copy_to_host_async"):
+                    packed.copy_to_host_async()
+                chunks.append((chunk, s, packed))
         if self.defer_results:
             self._results.mark(rows)
             return TopKBatch.empty(self.top_k)
         prev, self._pending = self._pending, chunks
         return (self._materialize(prev) if prev is not None
                 else TopKBatch.empty(self.top_k))
+
+    def _count_scored(self, rows: int, padded_rows: int) -> None:
+        """One scoring program over ``padded_rows`` rows of the catalog
+        width, ``rows`` of them live: the window's launch and cell
+        counts."""
+        clk = self.stage_clock
+        clk.add("launches")
+        clk.add("score_cells", padded_rows * self.num_items)
+        clk.add("live_cells", rows * self.num_items)
 
     def _try_fused(self, ts: int, b: BasketBatch) -> Optional[TopKBatch]:
         """Run one window through the fused one-dispatch program, or
@@ -816,40 +861,43 @@ class DeviceScorer:
 
             if self.top_k > _K_PAD or self.num_items > (1 << 24):
                 return None
-        valid = b._valid()
-        active = per_op > 0
-        self._ensure_capacity(int(max(b.new_items[active].max(),
-                                      b.baskets[valid].max())))
-        n_ops = b.n_ops
-        n_cap = pad_pow2(n_ops, minimum=64)
-        l_cap = pad_pow2(max(int(b.baskets.shape[1]), 1), minimum=128)
-        if 2 * n_cap * l_cap > self.max_pairs_per_step:
-            # The expanded lanes would exceed the chained path's COO
-            # chunk budget (HBM working-set bound): oversized windows
-            # stay chained, where chunking already handles them.
-            return None
-        # Rescore set: every item touched by an emitted pair — the
-        # union of active star items and valid basket cells, exactly
-        # the chained path's distinct_sorted(src) set (np.unique sorts).
-        rows = np.unique(np.concatenate([
-            b.new_items[active].astype(np.int64),
-            b.baskets[valid].astype(np.int64)])).astype(np.int32)
-        if len(rows) > self.max_score_rows:
-            return None
+        clk = self.stage_clock
+        with clk.stage("index"):
+            valid = b._valid()
+            active = per_op > 0
+            self._ensure_capacity(int(max(b.new_items[active].max(),
+                                          b.baskets[valid].max())))
+            n_ops = b.n_ops
+            n_cap = pad_pow2(n_ops, minimum=64)
+            l_cap = pad_pow2(max(int(b.baskets.shape[1]), 1), minimum=128)
+            if 2 * n_cap * l_cap > self.max_pairs_per_step:
+                # The expanded lanes would exceed the chained path's COO
+                # chunk budget (HBM working-set bound): oversized windows
+                # stay chained, where chunking already handles them.
+                return None
+            # Rescore set: every item touched by an emitted pair — the
+            # union of active star items and valid basket cells, exactly
+            # the chained path's distinct_sorted(src) set (np.unique sorts).
+            rows = np.unique(np.concatenate([
+                b.new_items[active].astype(np.int64),
+                b.baskets[valid].astype(np.int64)])).astype(np.int32)
+            if len(rows) > self.max_score_rows:
+                return None
 
-        # Single packed uplink: basket rectangle + 4 meta columns. Pad
-        # ops carry (len 0, sign 0) — zero expanded lanes. Basket cells
-        # beyond each op's len ride up unspecified and are masked
-        # in-kernel, same contract as the sampler's storage.
-        blockbuf = np.zeros((n_cap, l_cap + 4), dtype=np.int32)
-        w = b.baskets.shape[1]
-        if w:
-            blockbuf[:n_ops, :w] = b.baskets
-        blockbuf[:, l_cap + 2] = -1
-        blockbuf[:n_ops, l_cap] = b.new_items
-        blockbuf[:n_ops, l_cap + 1] = b.lens
-        blockbuf[:n_ops, l_cap + 2] = b.skips
-        blockbuf[:n_ops, l_cap + 3] = b.signs
+        with clk.stage("uplink-encode"):
+            # Single packed uplink: basket rectangle + 4 meta columns. Pad
+            # ops carry (len 0, sign 0) — zero expanded lanes. Basket cells
+            # beyond each op's len ride up unspecified and are masked
+            # in-kernel, same contract as the sampler's storage.
+            blockbuf = np.zeros((n_cap, l_cap + 4), dtype=np.int32)
+            w = b.baskets.shape[1]
+            if w:
+                blockbuf[:n_ops, :w] = b.baskets
+            blockbuf[:, l_cap + 2] = -1
+            blockbuf[:n_ops, l_cap] = b.new_items
+            blockbuf[:n_ops, l_cap + 1] = b.lens
+            blockbuf[:n_ops, l_cap + 2] = b.skips
+            blockbuf[:n_ops, l_cap + 3] = b.signs
 
         # Exact host-side observed tracking, identical to the chained
         # path's pairs.delta.sum(): each op contributes 2 * sign * pairs.
@@ -863,11 +911,12 @@ class DeviceScorer:
 
         s = len(rows)
         pad_s = min(pad_pow4(s, minimum=64), self.max_score_rows)
+        self._count_scored(s, pad_s)
         rows_padded = np.zeros(pad_s, dtype=np.int32)
         rows_padded[:s] = rows
         observed = np.float32(self.observed)
         if self.defer_results:
-            self._results.ensure()
+            self.stage_clock.add("launches", self._results.ensure())
             # Padded entries gather row 0 but must NOT scatter there.
             scatter_rows = np.full(pad_s, _SENT_ROW, dtype=np.int32)
             scatter_rows[:s] = rows

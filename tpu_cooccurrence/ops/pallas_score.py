@@ -565,8 +565,13 @@ def pallas_score_topk(C, row_sums, rows, observed, *, top_k: int,
     if pad_s:
         rows = jnp.concatenate([rows, jnp.zeros(pad_s, dtype=rows.dtype)])
     sp = S + pad_s
-    gathered = C[rows]                                   # [Sp, I] count dtype
-    rsi = row_sums[rows].reshape(sp, 1)
+    # Device-side stage name of the row gather (op metadata in a
+    # profiler trace). The kernel stays outside any scope: a Pallas
+    # custom call takes the innermost scope's name, and the trace's
+    # readers match it as ``pallas_score_topk``.
+    with jax.named_scope("gather"):
+        gathered = C[rows]                               # [Sp, I] count dtype
+        rsi = row_sums[rows].reshape(sp, 1)
     rs2d = row_sums.reshape(1, num_items)
     vals, idx = _pallas_topk_gathered(gathered, rs2d, rsi, observed,
                                       top_k=top_k, tile=tile, blk=blk,

@@ -218,8 +218,9 @@ class ShardedSparseScorer:
             fused_window)
         self.last_dispatch_fused = False
         self.last_fallback_reason: Optional[str] = None
-        # Tracing plane: per-window stage-seconds (uplink-encode /
-        # rescore) the job carves into journal span tuples.
+        # Tracing plane: per-window stage seconds (index /
+        # uplink-encode / rescore) the job carves into journal span
+        # tuples.
         self.stage_clock = StageClock()
         self._fused_shapes = set()
         # The rescale/restore seam and cold start: bucket plans must
@@ -533,6 +534,7 @@ class ShardedSparseScorer:
                 return (zs.at[:, : rs_loc.shape[1]].set(rs_loc),
                         zl.at[:, : rl_loc.shape[1]].set(rl_loc))
 
+            self.stage_clock.add("launches")
             self.reg_start, self.reg_len = jax.jit(shard_map(
                 _gr, mesh=self.mesh,
                 in_specs=(P(ITEM_AXIS), P(ITEM_AXIS)),
@@ -551,6 +553,7 @@ class ShardedSparseScorer:
                 z = jnp.full((1, 2, lc, self.top_k), -jnp.inf, jnp.float32)
                 return z.at[:, :, : tbl_loc.shape[2]].set(tbl_loc)
 
+            self.stage_clock.add("launches")
             self._tbl = jax.jit(shard_map(
                 _gt, mesh=self.mesh, in_specs=P(ITEM_AXIS),
                 out_specs=P(ITEM_AXIS)), donate_argnums=donate_argnums(0))(old)
@@ -561,6 +564,7 @@ class ShardedSparseScorer:
         new_cap = self.capacity
         while new_cap < need_end:
             new_cap *= 2
+        self.stage_clock.add("launches")
         self.cnt, self.dst = self._grow_fn(new_cap)(self.cnt, self.dst)
         self.capacity = new_cap
 
@@ -570,6 +574,7 @@ class ShardedSparseScorer:
         new_cap = self.capacity_w
         while new_cap < need_end:
             new_cap *= 2
+        self.stage_clock.add("launches")
         self.cnt_w, self.dst_w = self._grow_fn(new_cap)(
             self.cnt_w, self.dst_w)
         self.capacity_w = new_cap
@@ -590,45 +595,49 @@ class ShardedSparseScorer:
                 # Nothing in flight; results wait for the final flush.
                 return TopKBatch.empty(self.top_k)
             return self.flush()
-        if any(ix.needs_compaction(self.compact_min_heap)
-               for ix in self.indexes):
-            self._compact_all()
-        if (self.indexes_w is not None
-                and any(ix.needs_compaction(self.compact_min_heap)
-                        for ix in self.indexes_w)):
-            self._compact_all(wide=True)
-        delta64 = pairs.delta.astype(np.int64)
-        self._ensure_items(int(max(pairs.src.max(), pairs.dst.max())))
-        src_d, dst_d, d_val, _ = aggregate_window_coo(
-            pairs.src, pairs.dst, delta64, return_key=True)
-        d_val32 = narrow_deltas_int32(d_val)
+        clk = self.stage_clock
+        # The window's host bookkeeping before any upload: the index
+        # stage (as the single-device scorer's).
+        with clk.stage("index"):
+            if any(ix.needs_compaction(self.compact_min_heap)
+                   for ix in self.indexes):
+                self._compact_all()
+            if (self.indexes_w is not None
+                    and any(ix.needs_compaction(self.compact_min_heap)
+                            for ix in self.indexes_w)):
+                self._compact_all(wide=True)
+            delta64 = pairs.delta.astype(np.int64)
+            self._ensure_items(int(max(pairs.src.max(), pairs.dst.max())))
+            src_d, dst_d, d_val, _ = aggregate_window_coo(
+                pairs.src, pairs.dst, delta64, return_key=True)
+            d_val32 = narrow_deltas_int32(d_val)
 
-        # Global row sums (watermark ordering first), host-exact.
-        rows = distinct_sorted(src_d)
-        row_ends = np.searchsorted(src_d, rows, side="right")
-        cum = np.concatenate([[0], np.cumsum(d_val)])
-        rs_delta = cum[row_ends] - cum[np.searchsorted(src_d, rows)]
-        self.row_sums_host[rows] += rs_delta
-        if self.row_sums_host[rows].max(initial=0) >= 2**31:
-            raise ValueError("row sum exceeds int32 range")
-        window_sum = int(delta64.sum())
-        self.observed += window_sum
-        self.counters.add(ROW_SUM_PROCESS_WINDOW, window_sum)
-        # Incremental-checkpoint dirty feed (state/delta.py): global
-        # rows touched this window. No-op unless
-        # --checkpoint-incremental armed the store's log.
-        self.store.note_touched(rows)
-        row_owner = (rows % D).astype(np.int64)
-        owner_counts = np.bincount(row_owner, minlength=D)
+            # Global row sums (watermark ordering first), host-exact.
+            rows = distinct_sorted(src_d)
+            row_ends = np.searchsorted(src_d, rows, side="right")
+            cum = np.concatenate([[0], np.cumsum(d_val)])
+            rs_delta = cum[row_ends] - cum[np.searchsorted(src_d, rows)]
+            self.row_sums_host[rows] += rs_delta
+            if self.row_sums_host[rows].max(initial=0) >= 2**31:
+                raise ValueError("row sum exceeds int32 range")
+            window_sum = int(delta64.sum())
+            self.observed += window_sum
+            self.counters.add(ROW_SUM_PROCESS_WINDOW, window_sum)
+            # Incremental-checkpoint dirty feed (state/delta.py): global
+            # rows touched this window. No-op unless
+            # --checkpoint-incremental armed the store's log.
+            self.store.note_touched(rows)
+            row_owner = (rows % D).astype(np.int64)
+            owner_counts = np.bincount(row_owner, minlength=D)
 
-        # Narrow-cell promotion, then the per-slab split: a cell routes
-        # by its row's residency, decided BEFORE this window's deltas
-        # apply (same ordering as the single-device scorer).
-        if self.indexes_w is not None:
-            self._promote_rows(rows)
-            cell_wide = self.wide_rows[src_d]
-        else:
-            cell_wide = None
+            # Narrow-cell promotion, then the per-slab split: a cell routes
+            # by its row's residency, decided BEFORE this window's deltas
+            # apply (same ordering as the single-device scorer).
+            if self.indexes_w is not None:
+                self._promote_rows(rows)
+                cell_wide = self.wide_rows[src_d]
+            else:
+                cell_wide = None
 
         # Fused routing gate: steady-state all-narrow windows take the
         # one-launch-per-worker program; everything else routes chained
@@ -658,18 +667,28 @@ class ShardedSparseScorer:
             return TopKBatch.empty(self.top_k)
 
         self._record_dispatch_gauges(fused=False)
-        with self.stage_clock.stage("uplink-encode"):
-            if cell_wide is not None and cell_wide.any():
+        split = cell_wide is not None and cell_wide.any()
+        # Slot allocation is index work; an allocation from a fused
+        # attempt that bailed must not be applied twice.
+        with clk.stage("index"):
+            if split:
+                prealloc = self._apply_shards(
+                    src_d[~cell_wide], dst_d[~cell_wide],
+                    d_val32[~cell_wide])
+                prealloc_w = self._apply_shards(
+                    src_d[cell_wide], dst_d[cell_wide], d_val32[cell_wide],
+                    wide=True)
+            elif prealloc is None:
+                prealloc = self._apply_shards(src_d, dst_d, d_val32)
+        with clk.stage("uplink-encode"):
+            if split:
                 # Wide rows ride the same update program on the wide slab
                 # pair; row sums travel once, with the narrow call.
-                self._window_update(src_d[~cell_wide], dst_d[~cell_wide],
-                                    d_val32[~cell_wide], rows, rs_delta)
-                self._window_update(src_d[cell_wide], dst_d[cell_wide],
-                                    d_val32[cell_wide], rows[:0],
-                                    rs_delta[:0], wide=True)
+                self._window_update(rows, rs_delta, prealloc)
+                self._window_update(rows[:0], rs_delta[:0], prealloc_w,
+                                    wide=True)
             else:
-                self._window_update(src_d, dst_d, d_val32, rows, rs_delta,
-                                    prealloc=prealloc)
+                self._window_update(rows, rs_delta, prealloc)
 
         if self.development_mode:
             self._check_row_sums(rows)
@@ -677,15 +696,13 @@ class ShardedSparseScorer:
         self.counters.add(RESCORED_ITEMS, len(rows))
         self.last_dispatched_rows = len(rows)
         _record_shard_metrics(len(rows), owner_counts)
-        with self.stage_clock.stage("rescore"):
-            if self.indexes_w is not None and self.wide_rows[rows].any():
-                wmask = self.wide_rows[rows]
-                chunks = self._dispatch_scoring(rows[~wmask],
-                                                row_owner[~wmask])
-                chunks += self._dispatch_scoring(rows[wmask],
-                                                 row_owner[wmask], wide=True)
-            else:
-                chunks = self._dispatch_scoring(rows, row_owner)
+        if self.indexes_w is not None and self.wide_rows[rows].any():
+            wmask = self.wide_rows[rows]
+            chunks = self._dispatch_scoring(rows[~wmask], row_owner[~wmask])
+            chunks += self._dispatch_scoring(rows[wmask], row_owner[wmask],
+                                             wide=True)
+        else:
+            chunks = self._dispatch_scoring(rows, row_owner)
         self._record_state_gauges()
         prev, self._pending = self._pending, chunks
         return (self._materialize(prev) if prev is not None
@@ -719,17 +736,14 @@ class ShardedSparseScorer:
             mv_blocks.append((plan.mv, plan.mv_len))
         return plans, sec_new, sec_delta, mv_blocks
 
-    def _window_update(self, src_d: np.ndarray, dst_d: np.ndarray,
-                       d_val32: np.ndarray, rows: np.ndarray,
-                       rs_delta: np.ndarray, wide: bool = False,
-                       prealloc=None) -> None:
-        """The chained update step for one slab pair: moves (if any),
-        then one [D, 2, N_pad] cell-section upload + owner-partitioned
-        row-sum parts (psum'd to every replica)."""
+    def _window_update(self, rows: np.ndarray, rs_delta: np.ndarray,
+                       prealloc, wide: bool = False) -> None:
+        """The chained update step for one slab pair, under the
+        allocation ``_apply_shards`` made (``prealloc``): moves (if
+        any), then one [D, 2, N_pad] cell-section upload +
+        owner-partitioned row-sum parts (psum'd to every replica)."""
         D = self.n_shards
         indexes = self.indexes_w if wide else self.indexes
-        if prealloc is None:
-            prealloc = self._apply_shards(src_d, dst_d, d_val32, wide=wide)
         _plans, sec_new, sec_delta, mv_blocks = prealloc
         if wide:
             self._ensure_heap_w(max(ix.heap_end for ix in indexes))
@@ -750,6 +764,7 @@ class ShardedSparseScorer:
                 if mv is not None:
                     mv_all[d, :, : mv.shape[1]] = mv
             LEDGER.up("update-moves-sharded" + lbl, mv_all)
+            self.stage_clock.add("launches")
             cnt_ref, dst_ref = self._moves_fn(mv_len)(
                 cnt_ref, dst_ref,
                 self._put_global(mv_all, self.mesh, P(ITEM_AXIS)))
@@ -782,6 +797,7 @@ class ShardedSparseScorer:
         # sharded update step never recorded its uploads, leaving
         # fused-vs-sharded wire comparisons blind on one side.
         LEDGER.up("update-sharded" + lbl, upd, bounds, rs_part)
+        self.stage_clock.add("launches")
         out = self._update(
             cnt_ref, dst_ref, self.row_sums,
             self._put_global(upd, self.mesh, P(ITEM_AXIS)),
@@ -834,6 +850,7 @@ class ShardedSparseScorer:
             src[d, : len(s)] = s
             dsts[d, : len(t)] = t
         LEDGER.up("promote-cells-sharded", src, dsts)
+        self.stage_clock.add("launches")
         self.cnt_w, self.dst_w = self._promote_fn(m_pad)(
             self.cnt, self.dst, self.cnt_w, self.dst_w,
             self._put_global(src, self.mesh, P(ITEM_AXIS)),
@@ -942,108 +959,74 @@ class ShardedSparseScorer:
         from ..ops.device_scorer import split_upload_auto
 
         D = self.n_shards
-        prealloc = self._apply_shards(src_d, dst_d, d_val32)
-        _plans, sec_new, sec_delta, mv_blocks = prealloc
-        if any(mv is not None for mv, _ in mv_blocks):
-            self._fallback_chained("relocation")
-            return False, prealloc
-        self._ensure_heap(max(ix.heap_end for ix in self.indexes))
+        clk = self.stage_clock
+        with clk.stage("index"):
+            prealloc = self._apply_shards(src_d, dst_d, d_val32)
+            _plans, sec_new, sec_delta, mv_blocks = prealloc
+            if any(mv is not None for mv, _ in mv_blocks):
+                self._fallback_chained("relocation")
+                return False, prealloc
+            self._ensure_heap(max(ix.heap_end for ix in self.indexes))
 
-        # Per-shard 3-section update: new | delta | owned row sums. The
-        # third section replaces the chained path's separate rs_part
-        # upload — the fused body scatters it into the psum partial.
-        owner_counts = np.bincount(row_owner, minlength=D)
-        n_per = [len(sec_new[d][0]) + len(sec_delta[d][0])
-                 + int(owner_counts[d]) for d in range(D)]
-        n_pad = pad_pow4(max(n_per + [1]), minimum=1 << 12)
-        upd = np.full((D, 2, n_pad), _SENT, dtype=np.int32)
-        upd[:, 1, :] = 0
-        bounds = np.zeros((D, 2), dtype=np.int32)
-        for d in range(D):
-            (ns, nd), (ds_, dv) = sec_new[d], sec_delta[d]
-            b0 = len(ns)
-            b1 = b0 + len(ds_)
-            upd[d, 0, :b0] = ns
-            upd[d, 1, :b0] = nd
-            upd[d, 0, b0:b1] = ds_
-            upd[d, 1, b0:b1] = dv
-            sel = row_owner == d
-            k = int(sel.sum())
-            upd[d, 0, b1: b1 + k] = rows[sel]
-            upd[d, 1, b1: b1 + k] = rs_delta[sel].astype(np.int32)
-            bounds[d] = (b0, b1)
-        if split_upload_auto(upd[0]) is not None:
-            self._fallback_chained("upload-split")
-            return False, prealloc
+        # The update upload: per-shard 3-section update (new | delta |
+        # owned row sums; the third section replaces the chained path's
+        # separate rs_part upload — the fused body scatters it into the
+        # psum partial), then the registry mirror's delta sync and the
+        # wire encoding.
+        with clk.stage("uplink-encode"):
+            owner_counts = np.bincount(row_owner, minlength=D)
+            n_per = [len(sec_new[d][0]) + len(sec_delta[d][0])
+                     + int(owner_counts[d]) for d in range(D)]
+            n_pad = pad_pow4(max(n_per + [1]), minimum=1 << 12)
+            upd = np.full((D, 2, n_pad), _SENT, dtype=np.int32)
+            upd[:, 1, :] = 0
+            bounds = np.zeros((D, 2), dtype=np.int32)
+            for d in range(D):
+                (ns, nd), (ds_, dv) = sec_new[d], sec_delta[d]
+                b0 = len(ns)
+                b1 = b0 + len(ds_)
+                upd[d, 0, :b0] = ns
+                upd[d, 1, :b0] = nd
+                upd[d, 0, b0:b1] = ds_
+                upd[d, 1, b0:b1] = dv
+                sel = row_owner == d
+                k = int(sel.sum())
+                upd[d, 0, b1: b1 + k] = rows[sel]
+                upd[d, 1, b1: b1 + k] = rs_delta[sel].astype(np.int32)
+                bounds[d] = (b0, b1)
+            if split_upload_auto(upd[0]) is not None:
+                self._fallback_chained("upload-split")
+                return False, prealloc
 
-        # Registry mirror delta sync, per shard in LOCAL row ids: rows
-        # whose host (start, len) changed since the mirror last synced.
-        # A restore/rescale marked everything dirty — resync every
-        # occupied row. Sentinel-padded to the widest shard's count.
-        dirty_l: List[np.ndarray] = []
-        n_reg = 0
-        for d in range(D):
-            dirty, all_dirty = self.indexes[d].rows.drain_dirty()
-            if all_dirty:
-                dirty = self.indexes[d].rows.occupied().astype(np.int64)
-            dirty_l.append(dirty)
-            n_reg = max(n_reg, len(dirty))
-        reg_pad = pad_pow2(max(n_reg, 1), minimum=256)
-        reg_upd = np.full((D, 3, reg_pad), _SENT, dtype=np.int32)
-        for d, dirty in enumerate(dirty_l):
-            k = len(dirty)
-            if k:
-                r_start, r_len, _c = self.indexes[d].rows.get(dirty)
-                reg_upd[d, 0, :k] = dirty
-                reg_upd[d, 1, :k] = r_start
-                reg_upd[d, 2, :k] = r_len
+            # Registry mirror delta sync, per shard in LOCAL row ids:
+            # rows whose host (start, len) changed since the mirror last
+            # synced. A restore/rescale marked everything dirty —
+            # resync every occupied row. Sentinel-padded to the widest
+            # shard's count.
+            dirty_l: List[np.ndarray] = []
+            n_reg = 0
+            for d in range(D):
+                dirty, all_dirty = self.indexes[d].rows.drain_dirty()
+                if all_dirty:
+                    dirty = self.indexes[d].rows.occupied().astype(np.int64)
+                dirty_l.append(dirty)
+                n_reg = max(n_reg, len(dirty))
+            reg_pad = pad_pow2(max(n_reg, 1), minimum=256)
+            reg_upd = np.full((D, 3, reg_pad), _SENT, dtype=np.int32)
+            for d, dirty in enumerate(dirty_l):
+                k = len(dirty)
+                if k:
+                    r_start, r_len, _c = self.indexes[d].rows.get(dirty)
+                    reg_upd[d, 0, :k] = dirty
+                    reg_upd[d, 1, :k] = r_start
+                    reg_upd[d, 2, :k] = r_len
+            if self.wire_packed:
+                from ..state.wire import encode_update
 
-        # Monotone shard-uniform scoring plan (the fixed-shape rule via
-        # _bump_plan): every (bucket, chunk-rank) ever occupied on any
-        # shard dispatches — absent ones as all-padding rectangles — so
-        # the static plan only grows and compile count stays bounded.
-        local = (rows // D).astype(np.int64)
-        lens = np.empty(len(rows), dtype=np.int32)
-        for d in range(D):
-            sel = row_owner == d
-            _s, lens[sel], _c = self.indexes[d].rows.get(local[sel])
-        min_r = max(16, self.top_k)
-        bucket, order = score_buckets(lens, min_r, self.score_ladder)
-        self._bump_plan(self._plan_buckets, bucket, order, row_owner,
-                        min_r)
-        b_sorted = bucket[order]
-        plan_t = []
-        segs: List[np.ndarray] = []
-        off = 0
-        for bb in sorted(self._plan_buckets):
-            R = bucket_r(bb, min_r, self.score_ladder)
-            S = fixed_block(R, self.FIXED_BUDGET, self.FIXED_ROW_CAP)
-            lo = int(np.searchsorted(b_sorted, bb))
-            hi = int(np.searchsorted(b_sorted, bb, side="right"))
-            members = order[lo:hi]
-            per_shard = [members[row_owner[members] == d]
-                         for d in range(D)]
-            for c in range(self._plan_buckets[bb]):
-                seg = np.full((D, S), _SENT, dtype=np.int32)
-                for d in range(D):
-                    p = per_shard[d][c * S: (c + 1) * S]
-                    seg[d, : len(p)] = rows[p]
-                segs.append(seg)
-                plan_t.append((R, S, off, self._rect_pallas(R)))
-                off += S
-        rows_all = np.concatenate(segs, axis=1)
-        plan_t = tuple(plan_t)
-
-        self._ensure_tbl()
-        observed = np.float32(self.observed)
-        pg = self._put_global
-        if self.wire_packed:
-            from ..state.wire import encode_update
-
-            # Ownership-partitioned packed uplink: each shard's sections
-            # encode independently; word streams pad to the widest
-            # shard's pow2 bucket (+1 guard word for the decode gather).
-            with self.stage_clock.stage("uplink-encode"):
+                # Ownership-partitioned packed uplink: each shard's
+                # sections encode independently; word streams pad to the
+                # widest shard's pow2 bucket (+1 guard word for the
+                # decode gather).
                 enc = [encode_update(upd[d], bounds[d], n_per[d])
                        for d in range(D)]
                 wi_w = pad_pow2(max(len(e[0]) for e in enc) + 1,
@@ -1057,6 +1040,52 @@ class ShardedSparseScorer:
                     wi[d, : len(ei)] = ei
                     wv[d, : len(ev)] = ev
                     hdr[d] = eh
+
+        # Monotone shard-uniform scoring plan (the fixed-shape rule via
+        # _bump_plan): every (bucket, chunk-rank) ever occupied on any
+        # shard dispatches — absent ones as all-padding rectangles — so
+        # the static plan only grows and compile count stays bounded.
+        # The bump is index work and the rectangles are scoring work, as
+        # on the chained path (_dispatch_scoring).
+        with clk.stage("index"):
+            local = (rows // D).astype(np.int64)
+            lens = np.empty(len(rows), dtype=np.int32)
+            for d in range(D):
+                sel = row_owner == d
+                _s, lens[sel], _c = self.indexes[d].rows.get(local[sel])
+            min_r = max(16, self.top_k)
+            bucket, order = score_buckets(lens, min_r, self.score_ladder)
+            self._bump_plan(self._plan_buckets, bucket, order, row_owner,
+                            min_r)
+        with clk.stage("rescore"):
+            b_sorted = bucket[order]
+            plan_t = []
+            segs: List[np.ndarray] = []
+            off = 0
+            for bb in sorted(self._plan_buckets):
+                R = bucket_r(bb, min_r, self.score_ladder)
+                S = fixed_block(R, self.FIXED_BUDGET, self.FIXED_ROW_CAP)
+                lo = int(np.searchsorted(b_sorted, bb))
+                hi = int(np.searchsorted(b_sorted, bb, side="right"))
+                members = order[lo:hi]
+                per_shard = [members[row_owner[members] == d]
+                             for d in range(D)]
+                for c in range(self._plan_buckets[bb]):
+                    seg = np.full((D, S), _SENT, dtype=np.int32)
+                    for d in range(D):
+                        p = per_shard[d][c * S: (c + 1) * S]
+                        seg[d, : len(p)] = rows[p]
+                    segs.append(seg)
+                    plan_t.append((R, S, off, self._rect_pallas(R)))
+                    off += S
+            rows_all = np.concatenate(segs, axis=1)
+            plan_t = tuple(plan_t)
+        self._count_scored(plan_t, lens)
+
+        self._ensure_tbl()
+        observed = np.float32(self.observed)
+        pg = self._put_global
+        if self.wire_packed:
             LEDGER.up_encoded("fused-window-packed",
                               upd.nbytes + bounds.nbytes, wi, wv, hdr)
             LEDGER.up("fused-window-meta", reg_upd, rows_all)
@@ -1222,105 +1251,129 @@ class ShardedSparseScorer:
                             else (self.cnt, self.dst))
         if len(rows) == 0 and not plan_buckets:
             return []
-        local = (rows // D).astype(np.int64)
-        starts = np.empty(len(rows), dtype=np.int32)
-        lens = np.empty(len(rows), dtype=np.int32)
-        for d in range(D):
-            sel = row_owner == d
-            # One registry pass per shard (the _RowField views are the
-            # compat shim; this is the per-window hot path).
-            starts[sel], lens[sel], _ = indexes[d].rows.get(local[sel])
-        min_r = max(16, self.top_k)
-        bucket, order = score_buckets(lens, min_r, self.score_ladder)
-        b_sorted = bucket[order]
-        chunks: List[Tuple] = []
-        rects: List[Tuple[int, int, List[np.ndarray]]] = []  # (R, S, parts)
-        if self.fixed_shapes:
-            # Monotone plan over every (bucket, chunk-rank) ever occupied
-            # on ANY shard (the shard_map program is shared, so the plan
-            # must be shard-uniform); absent ones ride as all-padding.
-            # Shared with the fused window so the plans cannot drift.
-            self._bump_plan(plan_buckets, bucket, order, row_owner, min_r)
-        pos = 0
-        while pos < len(order):
-            b = int(b_sorted[pos])
-            end = int(np.searchsorted(b_sorted, b, side="right"))
-            R = bucket_r(b, min_r, self.score_ladder)
+        clk = self.stage_clock
+        with clk.stage("index"):
+            local = (rows // D).astype(np.int64)
+            starts = np.empty(len(rows), dtype=np.int32)
+            lens = np.empty(len(rows), dtype=np.int32)
+            for d in range(D):
+                sel = row_owner == d
+                # One registry pass per shard (the _RowField views are
+                # the compat shim; this is the per-window hot path).
+                starts[sel], lens[sel], _ = indexes[d].rows.get(local[sel])
+            min_r = max(16, self.top_k)
+            bucket, order = score_buckets(lens, min_r, self.score_ladder)
             if self.fixed_shapes:
-                s_block = fixed_block(R, self.FIXED_BUDGET,
-                                      self.FIXED_ROW_CAP)
-            else:
-                s_block = max(self.SCORE_BUDGET // R, 16)
-            members = order[pos:end]
-            counts = np.bincount(row_owner[members], minlength=D)
-            # Per-shard chunking: split the bucket so no shard exceeds
-            # s_block rows per dispatch.
-            n_dispatch = max(1, -(-int(counts.max()) // s_block))
-            per_shard = [members[row_owner[members] == d] for d in range(D)]
-            for i in range(n_dispatch):
-                parts = [p[i * s_block: (i + 1) * s_block]
-                         for p in per_shard]
+                # Monotone plan over every (bucket, chunk-rank) ever
+                # occupied on ANY shard (the shard_map program is shared,
+                # so the plan must be shard-uniform); absent ones ride as
+                # all-padding. Shared with the fused window so the plans
+                # cannot drift.
+                self._bump_plan(plan_buckets, bucket, order, row_owner,
+                                min_r)
+        with clk.stage("rescore"):
+            b_sorted = bucket[order]
+            chunks: List[Tuple] = []
+            # (R, S, parts)
+            rects: List[Tuple[int, int, List[np.ndarray]]] = []
+            pos = 0
+            while pos < len(order):
+                b = int(b_sorted[pos])
+                end = int(np.searchsorted(b_sorted, b, side="right"))
+                R = bucket_r(b, min_r, self.score_ladder)
                 if self.fixed_shapes:
-                    rects.append((R, s_block, parts))
-                    continue
-                s_max = max((len(p) for p in parts), default=0)
-                s_pad = min(pad_pow4(max(s_max, 1), minimum=16), s_block)
-                meta = np.zeros((D, 3, s_pad), dtype=np.int32)
-                for d, p in enumerate(parts):
-                    meta[d, 0, : len(p)] = rows[p]
-                    meta[d, 1, : len(p)] = starts[p]
-                    meta[d, 2, : len(p)] = lens[p]
-                meta_g = self._put_global(meta, self.mesh, P(ITEM_AXIS))
-                if self.defer_results:
-                    self._ensure_tbl()
-                    self._tbl = self._score_into_fn(R)(
-                        self._tbl, cnt_ref, dst_ref, self.row_sums,
-                        meta_g, np.float32(self.observed))
-                    continue
-                packed = self._score_fn(R)(
-                    cnt_ref, dst_ref, self.row_sums, meta_g,
+                    s_block = fixed_block(R, self.FIXED_BUDGET,
+                                          self.FIXED_ROW_CAP)
+                else:
+                    s_block = max(self.SCORE_BUDGET // R, 16)
+                members = order[pos:end]
+                counts = np.bincount(row_owner[members], minlength=D)
+                # Per-shard chunking: split the bucket so no shard
+                # exceeds s_block rows per dispatch.
+                n_dispatch = max(1, -(-int(counts.max()) // s_block))
+                per_shard = [members[row_owner[members] == d]
+                             for d in range(D)]
+                for i in range(n_dispatch):
+                    parts = [p[i * s_block: (i + 1) * s_block]
+                             for p in per_shard]
+                    if self.fixed_shapes:
+                        rects.append((R, s_block, parts))
+                        continue
+                    s_max = max((len(p) for p in parts), default=0)
+                    s_pad = min(pad_pow4(max(s_max, 1), minimum=16),
+                                s_block)
+                    meta = np.zeros((D, 3, s_pad), dtype=np.int32)
+                    for d, p in enumerate(parts):
+                        meta[d, 0, : len(p)] = rows[p]
+                        meta[d, 1, : len(p)] = starts[p]
+                        meta[d, 2, : len(p)] = lens[p]
+                    meta_g = self._put_global(meta, self.mesh, P(ITEM_AXIS))
+                    self._count_scored(((R, s_pad),),
+                                       lens[np.concatenate(parts)])
+                    if self.defer_results:
+                        self._ensure_tbl()
+                        self._tbl = self._score_into_fn(R)(
+                            self._tbl, cnt_ref, dst_ref, self.row_sums,
+                            meta_g, np.float32(self.observed))
+                        continue
+                    packed = self._score_fn(R)(
+                        cnt_ref, dst_ref, self.row_sums, meta_g,
+                        np.float32(self.observed))
+                    if hasattr(packed, "copy_to_host_async"):
+                        packed.copy_to_host_async()
+                    chunks.append(([rows[p] for p in parts], packed))
+                pos = end
+            if self.fixed_shapes:
+                # Top up to the high-water plan (absent (bucket,
+                # chunk-rank) entries dispatch as all-padding).
+                have = {}
+                for R, _S, _p in rects:
+                    have[R] = have.get(R, 0) + 1
+                for bb, n_chunks in plan_buckets.items():
+                    R = bucket_r(bb, min_r, self.score_ladder)
+                    S = fixed_block(R, self.FIXED_BUDGET,
+                                    self.FIXED_ROW_CAP)
+                    for _ in range(n_chunks - have.get(R, 0)):
+                        rects.append((R, S, [order[:0]] * D))
+            if rects:
+                # One packed [D, 3, sum(S)] upload + ONE fused dispatch
+                # for the whole window (fixed mode is defer-only,
+                # enforced at construction); canonical R order keeps the
+                # plan identical regardless of which buckets were empty
+                # this window.
+                self._count_scored(rects, lens)
+                rects.sort(key=lambda t: t[0])
+                total = sum(S for _R, S, _p in rects)
+                meta_all = np.zeros((D, 3, total), dtype=np.int32)
+                plan = []
+                off = 0
+                for R, S, parts in rects:
+                    for d, p in enumerate(parts):
+                        n = len(p)
+                        meta_all[d, 0, off: off + n] = rows[p]
+                        meta_all[d, 1, off: off + n] = starts[p]
+                        meta_all[d, 2, off: off + n] = lens[p]
+                    plan.append((R, S, off))
+                    off += S
+                self._ensure_tbl()
+                self._tbl = self._score_window_into_fn(tuple(plan))(
+                    self._tbl, cnt_ref, dst_ref, self.row_sums,
+                    self._put_global(meta_all, self.mesh, P(ITEM_AXIS)),
                     np.float32(self.observed))
-                if hasattr(packed, "copy_to_host_async"):
-                    packed.copy_to_host_async()
-                chunks.append(([rows[p] for p in parts], packed))
-            pos = end
-        if self.fixed_shapes:
-            # Top up to the high-water plan (absent (bucket, chunk-rank)
-            # entries dispatch as all-padding).
-            have = {}
-            for R, _S, _p in rects:
-                have[R] = have.get(R, 0) + 1
-            for bb, n_chunks in plan_buckets.items():
-                R = bucket_r(bb, min_r, self.score_ladder)
-                S = fixed_block(R, self.FIXED_BUDGET, self.FIXED_ROW_CAP)
-                for _ in range(n_chunks - have.get(R, 0)):
-                    rects.append((R, S, [order[:0]] * D))
-        if rects:
-            # One packed [D, 3, sum(S)] upload + ONE fused dispatch for
-            # the whole window (fixed mode is defer-only, enforced at
-            # construction); canonical R order keeps the plan identical
-            # regardless of which buckets were empty this window.
-            rects.sort(key=lambda t: t[0])
-            total = sum(S for _R, S, _p in rects)
-            meta_all = np.zeros((D, 3, total), dtype=np.int32)
-            plan = []
-            off = 0
-            for R, S, parts in rects:
-                for d, p in enumerate(parts):
-                    n = len(p)
-                    meta_all[d, 0, off: off + n] = rows[p]
-                    meta_all[d, 1, off: off + n] = starts[p]
-                    meta_all[d, 2, off: off + n] = lens[p]
-                plan.append((R, S, off))
-                off += S
-            self._ensure_tbl()
-            self._tbl = self._score_window_into_fn(tuple(plan))(
-                self._tbl, cnt_ref, dst_ref, self.row_sums,
-                self._put_global(meta_all, self.mesh, P(ITEM_AXIS)),
-                np.float32(self.observed))
         if self.defer_results:
             self._tbl_dirty[rows] = True
         return chunks
+
+    def _count_scored(self, rects, lens: np.ndarray) -> None:
+        """One scoring program over ``rects`` (``(R, S, ...)``
+        rectangles, each scored on every shard, a fixed plan's
+        all-padding ones included) for rows of lengths ``lens``: the
+        window's launch and cell counts."""
+        clk = self.stage_clock
+        clk.add("launches")
+        clk.add("score_cells",
+                self.n_shards * sum(r[0] * r[1] for r in rects))
+        clk.add("live_cells", int(lens.sum()))
 
     def _compact_all(self, wide: bool = False) -> None:
         indexes = self.indexes_w if wide else self.indexes
@@ -1332,6 +1385,7 @@ class ShardedSparseScorer:
         for d, g in enumerate(gmaps):
             gm[d, : len(g)] = g
         gm_g = self._put_global(gm, self.mesh, P(ITEM_AXIS))
+        self.stage_clock.add("launches")
         if wide:
             self.cnt_w, self.dst_w = self._compact_gather_fn(g_pad)(
                 self.cnt_w, self.dst_w, gm_g)

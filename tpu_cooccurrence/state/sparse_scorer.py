@@ -83,13 +83,14 @@ def _moves_body(cnt, dst, mv, L: int):
     allocated past the heap end or in compacted space.
     """
     old_start, new_start, ln = mv[0], mv[1], mv[2]
-    col = jnp.arange(L, dtype=jnp.int32)[None, :]
-    valid = col < ln[:, None]
-    src_idx = jnp.where(valid, old_start[:, None] + col, 0)
-    out_idx = jnp.where(valid, new_start[:, None] + col, _SENT)
-    cnt = cnt.at[out_idx.ravel()].set(cnt[src_idx].ravel(), mode="drop")
-    dst = dst.at[out_idx.ravel()].set(dst[src_idx].ravel(), mode="drop")
-    return cnt, dst
+    with jax.named_scope("slab-update"):
+        col = jnp.arange(L, dtype=jnp.int32)[None, :]
+        valid = col < ln[:, None]
+        src_idx = jnp.where(valid, old_start[:, None] + col, 0)
+        out_idx = jnp.where(valid, new_start[:, None] + col, _SENT)
+        cnt = cnt.at[out_idx.ravel()].set(cnt[src_idx].ravel(), mode="drop")
+        dst = dst.at[out_idx.ravel()].set(dst[src_idx].ravel(), mode="drop")
+        return cnt, dst
 
 
 def _update_body(cnt, dst, row_sums, upd, bounds):
@@ -107,13 +108,16 @@ def _update_body(cnt, dst, row_sums, upd, bounds):
                 ``row_sums``
 
     Section order matters: new-cell zeroing must precede the delta add.
+    The device-side stage name (op metadata in a profiler trace) is
+    ``slab-update``.
     """
-    cnt, dst = _apply_cells(cnt, dst, upd, bounds)
-    pos = jnp.arange(upd.shape[1], dtype=jnp.int32)
-    rs_idx = jnp.where(pos >= bounds[1], upd[0], _SENT)
-    row_sums = row_sums.at[rs_idx].add(
-        jnp.where(pos >= bounds[1], upd[1], 0), mode="drop")
-    return cnt, dst, row_sums
+    with jax.named_scope("slab-update"):
+        cnt, dst = _apply_cells(cnt, dst, upd, bounds)
+        pos = jnp.arange(upd.shape[1], dtype=jnp.int32)
+        rs_idx = jnp.where(pos >= bounds[1], upd[0], _SENT)
+        row_sums = row_sums.at[rs_idx].add(
+            jnp.where(pos >= bounds[1], upd[1], 0), mode="drop")
+        return cnt, dst, row_sums
 
 
 _apply_update = functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1, 2))(
@@ -243,16 +247,18 @@ def _score_rect(cnt, dst, row_sums, meta, observed, top_k: int, R: int):
     carry len == 0 and score all -inf. ``meta[0]`` row ids index
     ``row_sums`` (global id space); starts index the local slab.
     """
-    k11i, valid, ds, rsj, rsi = gather_rect(cnt, dst, row_sums, meta, R)
-    k11 = k11i.astype(jnp.float32)
-    k12 = rsi - k11
-    k21 = rsj - k11
-    k22 = observed + k11 - k12 - k21
-    scores = llr_stable(k11, k12, k21, k22)
-    scores = jnp.where(valid, scores, -jnp.inf)
-    vals, kidx = jax.lax.top_k(scores, top_k)
-    ids = jnp.take_along_axis(ds, kidx, axis=1)
-    return jnp.stack([vals, pack_ids(ids)])
+    with jax.named_scope("score"):
+        k11i, valid, ds, rsj, rsi = gather_rect(cnt, dst, row_sums, meta,
+                                                R)
+        k11 = k11i.astype(jnp.float32)
+        k12 = rsi - k11
+        k21 = rsj - k11
+        k22 = observed + k11 - k12 - k21
+        scores = llr_stable(k11, k12, k21, k22)
+        scores = jnp.where(valid, scores, -jnp.inf)
+        vals, kidx = jax.lax.top_k(scores, top_k)
+        ids = jnp.take_along_axis(ds, kidx, axis=1)
+        return jnp.stack([vals, pack_ids(ids)])
 
 
 _score_slab = functools.partial(jax.jit, static_argnames=("top_k", "R"))(
@@ -277,7 +283,9 @@ def _rect_into_table(tbl, cnt, dst, row_sums, meta, observed,
     body shared by the per-bucket and fused-window dispatch forms).
     ``pallas`` routes the rectangle through the fused LLR+top-K kernel
     (``ops/pallas_score.pallas_score_rect``, same packed wire format);
-    the scatter is identical either way."""
+    the scatter is identical either way. Device-side stage names:
+    ``score`` (the XLA scorer; a Pallas kernel keeps its own name, as a
+    custom call takes the innermost scope's) and ``table``."""
     if pallas:
         from ..ops.pallas_score import pallas_score_rect
 
@@ -285,8 +293,9 @@ def _rect_into_table(tbl, cnt, dst, row_sums, meta, observed,
                                    top_k=top_k, R=R, interpret=interpret)
     else:
         packed = _score_rect(cnt, dst, row_sums, meta, observed, top_k, R)
-    rowids = jnp.where(meta[2] > 0, meta[0], _SENT)
-    return tbl.at[:, rowids].set(packed, mode="drop")
+    with jax.named_scope("table"):
+        rowids = jnp.where(meta[2] > 0, meta[0], _SENT)
+        return tbl.at[:, rowids].set(packed, mode="drop")
 
 
 @functools.partial(jax.jit, donate_argnums=donate_argnums(0),
@@ -362,8 +371,9 @@ def _fused_sparse_body(cnt, dst, row_sums, tbl, reg_start, reg_len, upd,
     drift numerically because there is no second implementation.
     """
     cnt, dst, row_sums = _update_body(cnt, dst, row_sums, upd, bounds)
-    reg_start = reg_start.at[reg_upd[0]].set(reg_upd[1], mode="drop")
-    reg_len = reg_len.at[reg_upd[0]].set(reg_upd[2], mode="drop")
+    with jax.named_scope("slab-update"):
+        reg_start = reg_start.at[reg_upd[0]].set(reg_upd[1], mode="drop")
+        reg_len = reg_len.at[reg_upd[0]].set(reg_upd[2], mode="drop")
     for R, S, off, use_pl in plan:
         rowids = jax.lax.slice(rows_all, (off,), (off + S,))
         meta = jnp.stack([rowids, reg_start[rowids], reg_len[rowids]])
@@ -1582,12 +1592,13 @@ class SparseDeviceScorer:
         # The sparse fused path consumes aggregated deltas (the host
         # fold owns slot allocation); it never wants basket uplinks.
         self.wants_baskets = False
-        # Which path the LAST process_window dispatch took — the job's
-        # fused-vs-chained wall-time split and journal field read it.
+        # Which path the LAST process_window dispatch took — the
+        # journal's ``fused`` field and /healthz read it.
         self.last_dispatch_fused = False
-        # Tracing plane: per-window stage-seconds (uplink-encode /
-        # rescore) the job carves into journal span tuples; the
-        # unattributed remainder of score_seconds becomes "dispatch".
+        # Tracing plane: per-window stage seconds (index / uplink-encode
+        # / rescore) the job carves into journal span tuples — the
+        # unattributed remainder of score_seconds becomes "dispatch" —
+        # and counts (launches, score_cells, live_cells).
         self.stage_clock = StageClock()
         self._fused_dispatches = REGISTRY.gauge(
             "cooc_fused_dispatches_total",
@@ -1647,6 +1658,8 @@ class SparseDeviceScorer:
         grown = np.zeros(new_cap, dtype=np.int64)
         grown[: len(self.row_sums_host)] = self.row_sums_host
         self.row_sums_host = grown
+        clk = self.stage_clock
+        clk.add("launches")
         self.row_sums = _grow(self.row_sums, n=new_cap)
         if self.index_w is not None:
             wide = np.zeros(new_cap, dtype=bool)
@@ -1655,11 +1668,13 @@ class SparseDeviceScorer:
         if self.use_fused:
             # Zero-extension preserves the synced (start, len) entries;
             # new rows read len 0 until their first registry sync.
+            clk.add("launches")
             self.reg_start = _grow(self.reg_start, n=new_cap)
+            clk.add("launches")
             self.reg_len = _grow(self.reg_len, n=new_cap)
         self.items_cap = new_cap
         if self._results is not None:
-            self._results.resize(new_cap)
+            clk.add("launches", self._results.resize(new_cap))
 
     def _ensure_heap(self, need_end: int) -> None:
         if need_end <= self.capacity:
@@ -1667,7 +1682,9 @@ class SparseDeviceScorer:
         new_cap = self.capacity
         while new_cap < need_end:
             new_cap *= 2
+        self.stage_clock.add("launches")
         self.cnt = _grow(self.cnt, n=new_cap)
+        self.stage_clock.add("launches")
         self.dst = _grow(self.dst, n=new_cap)
         self.capacity = new_cap
 
@@ -1677,7 +1694,9 @@ class SparseDeviceScorer:
         new_cap = self.capacity_w
         while new_cap < need_end:
             new_cap *= 2
+        self.stage_clock.add("launches")
         self.cnt_w = _grow(self.cnt_w, n=new_cap)
+        self.stage_clock.add("launches")
         self.dst_w = _grow(self.dst_w, n=new_cap)
         self.capacity_w = new_cap
 
@@ -1701,84 +1720,98 @@ class SparseDeviceScorer:
                 return TopKBatch.empty(self.top_k)
             # No new dispatch — drain any completed in-flight results now.
             return self.flush()
-        # Tiered-state spill step (state/store.py; no-op for the direct
-        # store): advance the window clock and move rows that went cold
-        # to the host arena, BEFORE any index op — the freed regions
-        # become garbage the compaction below can reclaim this window.
-        self.store.tick()
-        # Reclaim freed slab regions once they dominate the heap. Runs
-        # between windows only: mid-window the move/update instructions
-        # already carry concrete slab addresses.
-        if self.index.needs_compaction(self.compact_min_heap):
-            gmap = self.index.compact()
-            gmap_pad = np.zeros(min(pad_pow2(len(gmap), minimum=1 << 10),
-                                    self.capacity), dtype=np.int32)
-            gmap_pad[: len(gmap)] = gmap
-            LEDGER.up("compact-gather", gmap_pad)
-            self.cnt, self.dst = _compact_gather(self.cnt, self.dst,
-                                                 gmap_pad, cap=self.capacity)
-        if (self.index_w is not None
-                and self.index_w.needs_compaction(self.compact_min_heap)):
-            gmap = self.index_w.compact()
-            gmap_pad = np.zeros(min(pad_pow2(len(gmap), minimum=1 << 10),
-                                    self.capacity_w), dtype=np.int32)
-            gmap_pad[: len(gmap)] = gmap
-            LEDGER.up("compact-gather-wide", gmap_pad)
-            self.cnt_w, self.dst_w = _compact_gather(
-                self.cnt_w, self.dst_w, gmap_pad, cap=self.capacity_w)
-        self._ensure_items(int(max(pairs.src.max(), pairs.dst.max())))
-        if isinstance(pairs, AggregatedPairs):
-            src_d, d_val, d_key = pairs.src, pairs.delta, pairs.key
-        else:
-            src_d, _, d_val, d_key = aggregate_window_coo(
-                pairs.src, pairs.dst, pairs.delta.astype(np.int64),
-                return_key=True)
-        d_val32 = narrow_deltas_int32(d_val)
+        clk = self.stage_clock
+        # The window's host bookkeeping before any upload: the index
+        # stage.
+        with clk.stage("index"):
+            # Tiered-state spill step (state/store.py; no-op for the
+            # direct store): advance the window clock and move rows that
+            # went cold to the host arena, BEFORE any index op — the
+            # freed regions become garbage the compaction below can
+            # reclaim this window.
+            self.store.tick()
+            # Reclaim freed slab regions once they dominate the heap.
+            # Runs between windows only: mid-window the move/update
+            # instructions already carry concrete slab addresses.
+            if self.index.needs_compaction(self.compact_min_heap):
+                gmap = self.index.compact()
+                gmap_pad = np.zeros(min(pad_pow2(len(gmap),
+                                                 minimum=1 << 10),
+                                        self.capacity), dtype=np.int32)
+                gmap_pad[: len(gmap)] = gmap
+                LEDGER.up("compact-gather", gmap_pad)
+                clk.add("launches")
+                self.cnt, self.dst = _compact_gather(
+                    self.cnt, self.dst, gmap_pad, cap=self.capacity)
+            if (self.index_w is not None
+                    and self.index_w.needs_compaction(self.compact_min_heap)):
+                gmap = self.index_w.compact()
+                gmap_pad = np.zeros(min(pad_pow2(len(gmap),
+                                                 minimum=1 << 10),
+                                        self.capacity_w), dtype=np.int32)
+                gmap_pad[: len(gmap)] = gmap
+                LEDGER.up("compact-gather-wide", gmap_pad)
+                clk.add("launches")
+                self.cnt_w, self.dst_w = _compact_gather(
+                    self.cnt_w, self.dst_w, gmap_pad, cap=self.capacity_w)
+            self._ensure_items(int(max(pairs.src.max(), pairs.dst.max())))
+            if isinstance(pairs, AggregatedPairs):
+                src_d, d_val, d_key = pairs.src, pairs.delta, pairs.key
+            else:
+                src_d, _, d_val, d_key = aggregate_window_coo(
+                    pairs.src, pairs.dst, pairs.delta.astype(np.int64),
+                    return_key=True)
+            d_val32 = narrow_deltas_int32(d_val)
 
-        # Row sums first (watermark ordering, reference
-        # ItemRowRescorerTwoInputStreamOperator.java:116-142). The host
-        # mirror is exact (int64); the device copy feeds the k21 gathers.
-        rows = distinct_sorted(src_d)
-        row_ends = np.searchsorted(src_d, rows, side="right")
-        cum = np.concatenate([[0], np.cumsum(d_val)])
-        rs_delta = cum[row_ends] - cum[np.searchsorted(src_d, rows)]
-        self.row_sums_host[rows] += rs_delta
-        if self.row_sums_host[rows].max(initial=0) >= 2**31:
-            raise ValueError("row sum exceeds int32 range")
-        # Fold-invariant: the per-cell aggregated deltas sum to exactly the
-        # raw per-pair deltas (both int64), so either input form works.
-        window_sum = int(d_val.sum())
-        self.observed += window_sum
-        self.counters.add(ROW_SUM_PROCESS_WINDOW, window_sum)
+            # Row sums first (watermark ordering, reference
+            # ItemRowRescorerTwoInputStreamOperator.java:116-142). The
+            # host mirror is exact (int64); the device copy feeds the
+            # k21 gathers.
+            rows = distinct_sorted(src_d)
+            row_ends = np.searchsorted(src_d, rows, side="right")
+            cum = np.concatenate([[0], np.cumsum(d_val)])
+            rs_delta = cum[row_ends] - cum[np.searchsorted(src_d, rows)]
+            self.row_sums_host[rows] += rs_delta
+            if self.row_sums_host[rows].max(initial=0) >= 2**31:
+                raise ValueError("row sum exceeds int32 range")
+            # Fold-invariant: the per-cell aggregated deltas sum to
+            # exactly the raw per-pair deltas (both int64), so either
+            # input form works.
+            window_sum = int(d_val.sum())
+            self.observed += window_sum
+            self.counters.add(ROW_SUM_PROCESS_WINDOW, window_sum)
 
-        # Spill-tier re-promotion FIRST (before the narrow->wide check
-        # and before any delta applies): touched rows resident in the
-        # host arena re-enter the slab index with their within-row order
-        # preserved; their cell values ride this window's update upload
-        # as extra new-cell + delta entries — no extra dispatch.
-        promo_n, promo_w = self.store.promote_touched(rows)
-        # Incremental-checkpoint dirty feed (state/delta.py): the SAME
-        # touched-rows set the recency clock stamps — one dirty source,
-        # two consumers. No-op unless --checkpoint-incremental armed it.
-        self.store.note_touched(rows)
-        # Narrow-cell promotion, then the per-slab split: a cell routes by
-        # its row's residency, decided BEFORE this window's deltas apply.
-        if self.index_w is not None:
-            self._promote_rows(rows)
-            cell_wide = self.wide_rows[src_d]
-        else:
-            cell_wide = None
+            # Spill-tier re-promotion FIRST (before the narrow->wide
+            # check and before any delta applies): touched rows resident
+            # in the host arena re-enter the slab index with their
+            # within-row order preserved; their cell values ride this
+            # window's update upload as extra new-cell + delta entries —
+            # no extra dispatch.
+            promo_n, promo_w = self.store.promote_touched(rows)
+            # Incremental-checkpoint dirty feed (state/delta.py): the
+            # SAME touched-rows set the recency clock stamps — one dirty
+            # source, two consumers. No-op unless
+            # --checkpoint-incremental armed it.
+            self.store.note_touched(rows)
+            # Narrow-cell promotion, then the per-slab split: a cell
+            # routes by its row's residency, decided BEFORE this
+            # window's deltas apply.
+            if self.index_w is not None:
+                self._promote_rows(rows)
+                cell_wide = self.wide_rows[src_d]
+            else:
+                cell_wide = None
         # Fused routing gate: steady-state all-narrow windows with no
         # spill re-promotion take the one-dispatch program; promotion /
         # wide-touching / re-promotion windows (and, inside
         # _fused_window, relocation windows and explicit upload-split
         # requests) route chained — per window, bit-identically.
-        pre_plan = None
+        plan = None
         fused_done = False
         if (self.use_fused and promo_n is None and promo_w is None
                 and (cell_wide is None or not cell_wide.any())):
-            fused_done, pre_plan = self._fused_window(d_key, d_val32,
-                                                      rows, rs_delta)
+            fused_done, plan = self._fused_window(d_key, d_val32,
+                                                  rows, rs_delta)
         if fused_done:
             if self.development_mode:
                 self._check_row_sums(rows)
@@ -1792,17 +1825,29 @@ class SparseDeviceScorer:
             return TopKBatch.empty(self.top_k)
 
         self._chained_dispatches.add(1)
-        with self.stage_clock.stage("uplink-encode"):
-            if cell_wide is not None and (cell_wide.any()
-                                          or promo_w is not None):
-                self._window_update(d_key[~cell_wide], d_val32[~cell_wide],
-                                    rows, rs_delta, wide=False, promo=promo_n)
-                self._window_update(d_key[cell_wide], d_val32[cell_wide],
-                                    rows[:0], rs_delta[:0], wide=True,
+        split = cell_wide is not None and (cell_wide.any()
+                                           or promo_w is not None)
+        # Slot allocation is index work; a plan from a fused attempt
+        # that bailed AFTER allocation (relocation window / explicit
+        # upload-split request) must not be applied twice.
+        with clk.stage("index"):
+            if split:
+                key_n, key_w = d_key[~cell_wide], d_key[cell_wide]
+                plan_n = self.index.apply(key_n)
+                plan_w = self.index_w.apply(key_w)
+            elif plan is None:
+                plan = self.index.apply(d_key)
+        with clk.stage("uplink-encode"):
+            if split:
+                self._window_update(key_n, d_val32[~cell_wide], rows,
+                                    rs_delta, plan_n, wide=False,
+                                    promo=promo_n)
+                self._window_update(key_w, d_val32[cell_wide], rows[:0],
+                                    rs_delta[:0], plan_w, wide=True,
                                     promo=promo_w)
             else:
-                self._window_update(d_key, d_val32, rows, rs_delta,
-                                    wide=False, promo=promo_n, plan=pre_plan)
+                self._window_update(d_key, d_val32, rows, rs_delta, plan,
+                                    wide=False, promo=promo_n)
 
         if self.development_mode:
             self._check_row_sums(rows)
@@ -1810,13 +1855,12 @@ class SparseDeviceScorer:
         # Score every updated row, length-bucketed (padding is device-only).
         self.counters.add(RESCORED_ITEMS, len(rows))
         self.last_dispatched_rows = len(rows)
-        with self.stage_clock.stage("rescore"):
-            if self.index_w is not None and self.wide_rows[rows].any():
-                wmask = self.wide_rows[rows]
-                chunks = self._dispatch_scoring(rows[~wmask], wide=False)
-                chunks += self._dispatch_scoring(rows[wmask], wide=True)
-            else:
-                chunks = self._dispatch_scoring(rows)
+        if self.index_w is not None and self.wide_rows[rows].any():
+            wmask = self.wide_rows[rows]
+            chunks = self._dispatch_scoring(rows[~wmask], wide=False)
+            chunks += self._dispatch_scoring(rows[wmask], wide=True)
+        else:
+            chunks = self._dispatch_scoring(rows)
         self._record_state_gauges()
 
         prev, self._pending = self._pending, chunks
@@ -1849,16 +1893,18 @@ class SparseDeviceScorer:
         dsts = np.full(m_pad, _SENT, dtype=np.int32)
         dsts[:m] = plan_w.slots
         LEDGER.up("promote-cells", src, dsts)
+        self.stage_clock.add("launches")
         self.cnt_w, self.dst_w = _promote_cells(
             self.cnt, self.dst, self.cnt_w, self.dst_w, src, dsts)
 
     def _window_update(self, d_key: np.ndarray, d_val32: np.ndarray,
                        rows: np.ndarray, rs_delta: np.ndarray,
-                       wide: bool = False, promo=None,
-                       plan: Optional[AllocPlan] = None) -> None:
-        """Allocate slots and dispatch one slab's window update. The
-        narrow dispatch also carries the shared row-sum section (row
-        sums are slab-independent); the wide dispatch's is empty.
+                       plan: AllocPlan, wide: bool = False,
+                       promo=None) -> None:
+        """Pack and dispatch one slab's window update under ``plan``,
+        the slots ``index.apply`` allocated for ``d_key``. The narrow
+        dispatch also carries the shared row-sum section (row sums are
+        slab-independent); the wide dispatch's is empty.
 
         ``promo`` — tiered-store re-promotion extras ``(cell_keys,
         dst_vals, cnt_vals)``: each promoted cell rides the SAME upload
@@ -1870,12 +1916,6 @@ class SparseDeviceScorer:
         and a promoted slot also receiving a window delta is fine: the
         delta section's scatter-adds commute."""
         index = self.index_w if wide else self.index
-        if plan is None:
-            # A non-None plan comes from a fused-window attempt that
-            # bailed AFTER allocation (relocation window / explicit
-            # upload-split request): apply already ran, re-running it
-            # would double-insert.
-            plan = index.apply(d_key)
         if wide:
             self._ensure_heap_w(index.heap_end)
             cnt_t, dst_t = self.cnt_w, self.dst_w
@@ -1888,6 +1928,7 @@ class SparseDeviceScorer:
                                            rows, rs_delta, promo)
         n_pad = upd.shape[1]
         lbl = "update-wide" if wide else "update"
+        self.stage_clock.add("launches")
 
         # An explicit upload-split request (TPU_COOC_UPLOAD_CHUNKS /
         # _CHUNK_KB) pins the raw chunked path — the two wire levers are
@@ -2027,74 +2068,85 @@ class SparseDeviceScorer:
         fused program). The caller gates promotion / wide-row / spill
         re-promotion windows before allocation.
         """
-        plan = self.index.apply(d_key)
+        clk = self.stage_clock
+        with clk.stage("index"):
+            plan = self.index.apply(d_key)
+            if plan.mv is None:
+                self._ensure_heap(self.index.heap_end)
         if plan.mv is not None:
             return False, plan
-        self._ensure_heap(self.index.heap_end)
 
-        with self.stage_clock.stage("uplink-encode"):
+        # The update upload: cells, deltas and row sums, then the
+        # registry mirror's delta sync and the wire encoding.
+        with clk.stage("uplink-encode"):
             upd, bounds, n = self._pack_update(self.index, plan, d_key,
                                                d_val32, rows, rs_delta, None)
-        n_pad = upd.shape[1]
-        if split_upload_auto(upd) is not None:
-            return False, plan
-        self.live_cells += plan.n_new
+            n_pad = upd.shape[1]
+            if split_upload_auto(upd) is not None:
+                return False, plan
+            # Registry delta sync: rows whose host (start, len) changed
+            # since the device mirror last synced — this window's
+            # new-cell rows plus anything a chained window / compaction
+            # / spill touched in between. Sentinel-padded,
+            # scatter-dropped.
+            dirty, all_dirty = self.index.rows.drain_dirty()
+            if all_dirty:
+                dirty = self.index.rows.occupied().astype(np.int64)
+            n_reg = len(dirty)
+            reg_pad = pad_pow2(n_reg, minimum=256)
+            reg_upd = np.full((3, reg_pad), _SENT, dtype=np.int32)
+            if n_reg:
+                r_start, r_len, _c = self.index.rows.get(dirty)
+                reg_upd[0, :n_reg] = dirty
+                reg_upd[1, :n_reg] = r_start
+                reg_upd[2, :n_reg] = r_len
+            if self.wire_packed:
+                from .wire import encode_update
 
-        # Registry delta sync: rows whose host (start, len) changed
-        # since the device mirror last synced — this window's new-cell
-        # rows plus anything a chained window / compaction / spill
-        # touched in between. Sentinel-padded, scatter-dropped.
-        dirty, all_dirty = self.index.rows.drain_dirty()
-        if all_dirty:
-            dirty = self.index.rows.occupied().astype(np.int64)
-        n_reg = len(dirty)
-        reg_pad = pad_pow2(n_reg, minimum=256)
-        reg_upd = np.full((3, reg_pad), _SENT, dtype=np.int32)
-        if n_reg:
-            r_start, r_len, _c = self.index.rows.get(dirty)
-            reg_upd[0, :n_reg] = dirty
-            reg_upd[1, :n_reg] = r_start
-            reg_upd[2, :n_reg] = r_len
+                words_i, words_v, header = encode_update(upd, bounds, n)
+                wi = _pad_words(words_i)
+                wv = _pad_words(words_v)
+        self.live_cells += plan.n_new
 
         # Monotone scoring plan (the fixed-shape mode's rule, shared
         # _plan_buckets): every (bucket, chunk-rank) ever occupied
         # dispatches — absent ones as all-padding rectangles — so the
         # static plan only grows and compile count stays bounded by the
         # final plan's rectangle count. Per-row independence of
-        # _score_rect makes chunking/padding parity-neutral.
-        _s, lens_h, _c = self.index.rows.get(rows)
-        min_r = max(16, self.top_k)
-        bucket, order = score_buckets(lens_h, min_r, self.score_ladder)
-        self._bump_fixed_plan(self._plan_buckets, bucket, min_r)
-        b_sorted = bucket[order]
-        plan_t = []
-        segs = []
-        off = 0
-        for b in sorted(self._plan_buckets):
-            R = bucket_r(b, min_r, self.score_ladder)
-            S = fixed_block(R, self.FIXED_BUDGET, self.FIXED_ROW_CAP)
-            lo = int(np.searchsorted(b_sorted, b))
-            hi = int(np.searchsorted(b_sorted, b, side="right"))
-            rows_b = rows[order[lo:hi]]
-            for c in range(self._plan_buckets[b]):
-                chunk = rows_b[c * S: (c + 1) * S]
-                seg = np.full(S, _SENT, dtype=np.int32)
-                seg[: len(chunk)] = chunk
-                segs.append(seg)
-                plan_t.append((R, S, off, self._rect_pallas(R)))
-                off += S
-        rows_all = np.concatenate(segs)
-        plan_t = tuple(plan_t)
+        # _score_rect makes chunking/padding parity-neutral. The bump is
+        # index work and the rectangles are scoring work, as on the
+        # chained path (_dispatch_scoring).
+        with clk.stage("index"):
+            _s, lens_h, _c = self.index.rows.get(rows)
+            min_r = max(16, self.top_k)
+            bucket, order = score_buckets(lens_h, min_r, self.score_ladder)
+            self._bump_fixed_plan(self._plan_buckets, bucket, min_r)
 
-        self._results.ensure()
+        with clk.stage("rescore"):
+            b_sorted = bucket[order]
+            plan_t = []
+            segs = []
+            off = 0
+            for b in sorted(self._plan_buckets):
+                R = bucket_r(b, min_r, self.score_ladder)
+                S = fixed_block(R, self.FIXED_BUDGET, self.FIXED_ROW_CAP)
+                lo = int(np.searchsorted(b_sorted, b))
+                hi = int(np.searchsorted(b_sorted, b, side="right"))
+                rows_b = rows[order[lo:hi]]
+                for c in range(self._plan_buckets[b]):
+                    chunk = rows_b[c * S: (c + 1) * S]
+                    seg = np.full(S, _SENT, dtype=np.int32)
+                    seg[: len(chunk)] = chunk
+                    segs.append(seg)
+                    plan_t.append((R, S, off, self._rect_pallas(R)))
+                    off += S
+            rows_all = np.concatenate(segs)
+            plan_t = tuple(plan_t)
+        self._count_scored(plan_t, lens_h)
+
+        clk.add("launches", self._results.ensure())
         observed = np.float32(self.observed)
         if self.wire_packed:
-            from .wire import encode_update
-
-            with self.stage_clock.stage("uplink-encode"):
-                words_i, words_v, header = encode_update(upd, bounds, n)
-                wi = _pad_words(words_i)
-                wv = _pad_words(words_v)
             LEDGER.up_encoded("fused-window-packed",
                               upd.nbytes + bounds.nbytes, wi, wv, header)
             LEDGER.up("fused-window-meta", reg_upd, rows_all)
@@ -2153,108 +2205,128 @@ class SparseDeviceScorer:
             plan_buckets = self._plan_buckets
         if len(rows) == 0 and not plan_buckets:
             return []
-        # One registry pass (the _RowField views are the compat shim for
-        # external callers; this is the per-window hot path).
-        starts, lens, _caps = index.rows.get(rows)
-        min_r = max(16, self.top_k)  # lax.top_k needs k <= R
-        bucket, order = score_buckets(lens, min_r, self.score_ladder)
-        b_sorted = bucket[order]
-        if self.defer_results:
-            self._results.ensure()
-        chunks: List[Tuple[np.ndarray, int, object]] = []
-        rects: List[Tuple[int, int, np.ndarray]] = []  # fixed: (R, S, chunk)
-        if self.fixed_shapes:
-            # Monotone plan: dispatch every (bucket, chunk-rank) ever
-            # occupied (absent ones as all-padding rectangles), so the
-            # fused program's static plan only grows — no churn from
-            # per-window bucket subsets OR from a bucket occasionally
-            # overflowing its per-dispatch row cap.
-            self._bump_fixed_plan(plan_buckets, bucket, min_r)
-        pos = 0
-        while pos < len(order):
-            b = int(b_sorted[pos])
-            end = int(np.searchsorted(b_sorted, b, side="right"))
-            R = bucket_r(b, min_r, self.score_ladder)
+        clk = self.stage_clock
+        with clk.stage("index"):
+            # One registry pass (the _RowField views are the compat shim
+            # for external callers; this is the per-window hot path).
+            starts, lens, _caps = index.rows.get(rows)
+            min_r = max(16, self.top_k)  # lax.top_k needs k <= R
+            bucket, order = score_buckets(lens, min_r, self.score_ladder)
             if self.fixed_shapes:
-                s_block = fixed_block(R, self.FIXED_BUDGET,
-                                      self.FIXED_ROW_CAP)
-            else:
-                s_block = max(self.SCORE_BUDGET // R, 16)
-            for lo in range(pos, end, s_block):
-                chunk = order[lo: min(lo + s_block, end)]
-                s = len(chunk)
-                if self.fixed_shapes:
-                    # Fixed mode: always the full per-bucket rectangle,
-                    # collected into ONE window dispatch below.
-                    rects.append((R, s_block, chunk))
-                    continue
-                # pow-4 row padding: each (R, s_pad) combination is one
-                # trace + compile per process; a coarse ladder keeps the
-                # program count (and per-process retrace time) small.
-                s_pad = min(pad_pow4(s, minimum=16), s_block)
-                meta = np.zeros((3, s_pad), dtype=np.int32)
-                meta[0, :s] = rows[chunk]
-                meta[1, :s] = starts[chunk]
-                meta[2, :s] = lens[chunk]
-                LEDGER.up("bucket-meta", meta)
-                if self.defer_results:
-                    # Fused: the scatter rides the scoring dispatch (the
-                    # table is donated in and reassigned).
-                    self._results.tbl = _score_into_table(
-                        self._results.tbl, cnt, dst,
-                        self.row_sums, meta, np.float32(self.observed),
-                        top_k=self.top_k, R=R,
-                        pallas=self._rect_pallas(R),
-                        interpret=self._pallas_interpret)
-                    continue
-                score = (_score_slab_pallas if self._rect_pallas(R)
-                         else _score_slab)
-                kw = ({"interpret": self._pallas_interpret}
-                      if self._rect_pallas(R) else {})
-                packed = score(cnt, dst, self.row_sums,
-                               meta, np.float32(self.observed),
-                               top_k=self.top_k, R=R, **kw)
-                if hasattr(packed, "copy_to_host_async"):
-                    packed.copy_to_host_async()
-                chunks.append((rows[chunk], s, packed))
-            pos = end
-        if self.fixed_shapes:
-            # Top up to the high-water plan: every (bucket, chunk-rank)
-            # ever seen dispatches, absent ones as all-padding.
-            have = {}
-            for R, _S, _c in rects:
-                have[R] = have.get(R, 0) + 1
-            for b, n_chunks in plan_buckets.items():
+                # Monotone plan: dispatch every (bucket, chunk-rank) ever
+                # occupied (absent ones as all-padding rectangles), so
+                # the fused program's static plan only grows — no churn
+                # from per-window bucket subsets OR from a bucket
+                # occasionally overflowing its per-dispatch row cap.
+                self._bump_fixed_plan(plan_buckets, bucket, min_r)
+        with clk.stage("rescore"):
+            b_sorted = bucket[order]
+            if self.defer_results:
+                clk.add("launches", self._results.ensure())
+            chunks: List[Tuple[np.ndarray, int, object]] = []
+            # Fixed mode: (R, S, chunk) rectangles of one window dispatch.
+            rects: List[Tuple[int, int, np.ndarray]] = []
+            pos = 0
+            while pos < len(order):
+                b = int(b_sorted[pos])
+                end = int(np.searchsorted(b_sorted, b, side="right"))
                 R = bucket_r(b, min_r, self.score_ladder)
-                S = fixed_block(R, self.FIXED_BUDGET, self.FIXED_ROW_CAP)
-                for _ in range(n_chunks - have.get(R, 0)):
-                    rects.append((R, S, order[:0]))
-        if rects:
-            # One packed [3, sum(S)] meta upload + one dispatch for the
-            # whole window (fixed mode is defer-only, enforced at
-            # construction). Canonical R order keeps the plan identical
-            # regardless of which buckets were empty this window.
-            rects.sort(key=lambda t: t[0])
-            total = sum(S for _R, S, _c in rects)
-            meta_all = np.zeros((3, total), dtype=np.int32)
-            plan = []
-            off = 0
-            for R, S, chunk in rects:
-                s = len(chunk)
-                meta_all[0, off: off + s] = rows[chunk]
-                meta_all[1, off: off + s] = starts[chunk]
-                meta_all[2, off: off + s] = lens[chunk]
-                plan.append((R, S, off, self._rect_pallas(R)))
-                off += S
-            LEDGER.up("window-meta", meta_all)
-            self._results.tbl = _score_window_into_table(
-                self._results.tbl, cnt, dst, self.row_sums,
-                meta_all, np.float32(self.observed),
-                top_k=self.top_k, plan=tuple(plan),
-                interpret=self._pallas_interpret)
+                if self.fixed_shapes:
+                    s_block = fixed_block(R, self.FIXED_BUDGET,
+                                          self.FIXED_ROW_CAP)
+                else:
+                    s_block = max(self.SCORE_BUDGET // R, 16)
+                for lo in range(pos, end, s_block):
+                    chunk = order[lo: min(lo + s_block, end)]
+                    s = len(chunk)
+                    if self.fixed_shapes:
+                        # Fixed mode: always the full per-bucket
+                        # rectangle, collected into ONE window dispatch
+                        # below.
+                        rects.append((R, s_block, chunk))
+                        continue
+                    # pow-4 row padding: each (R, s_pad) combination is
+                    # one trace + compile per process; a coarse ladder
+                    # keeps the program count (and per-process retrace
+                    # time) small.
+                    s_pad = min(pad_pow4(s, minimum=16), s_block)
+                    self._count_scored(((R, s_pad),), lens[chunk])
+                    meta = np.zeros((3, s_pad), dtype=np.int32)
+                    meta[0, :s] = rows[chunk]
+                    meta[1, :s] = starts[chunk]
+                    meta[2, :s] = lens[chunk]
+                    LEDGER.up("bucket-meta", meta)
+                    if self.defer_results:
+                        # Fused: the scatter rides the scoring dispatch
+                        # (the table is donated in and reassigned).
+                        self._results.tbl = _score_into_table(
+                            self._results.tbl, cnt, dst,
+                            self.row_sums, meta, np.float32(self.observed),
+                            top_k=self.top_k, R=R,
+                            pallas=self._rect_pallas(R),
+                            interpret=self._pallas_interpret)
+                        continue
+                    score = (_score_slab_pallas if self._rect_pallas(R)
+                             else _score_slab)
+                    kw = ({"interpret": self._pallas_interpret}
+                          if self._rect_pallas(R) else {})
+                    packed = score(cnt, dst, self.row_sums,
+                                   meta, np.float32(self.observed),
+                                   top_k=self.top_k, R=R, **kw)
+                    if hasattr(packed, "copy_to_host_async"):
+                        packed.copy_to_host_async()
+                    chunks.append((rows[chunk], s, packed))
+                pos = end
+            if self.fixed_shapes:
+                # Top up to the high-water plan: every (bucket,
+                # chunk-rank) ever seen dispatches, absent ones as
+                # all-padding.
+                have = {}
+                for R, _S, _c in rects:
+                    have[R] = have.get(R, 0) + 1
+                for b, n_chunks in plan_buckets.items():
+                    R = bucket_r(b, min_r, self.score_ladder)
+                    S = fixed_block(R, self.FIXED_BUDGET,
+                                    self.FIXED_ROW_CAP)
+                    for _ in range(n_chunks - have.get(R, 0)):
+                        rects.append((R, S, order[:0]))
+            if rects:
+                # One packed [3, sum(S)] meta upload + one dispatch for
+                # the whole window (fixed mode is defer-only, enforced at
+                # construction). Canonical R order keeps the plan
+                # identical regardless of which buckets were empty this
+                # window.
+                self._count_scored(rects, lens)
+                rects.sort(key=lambda t: t[0])
+                total = sum(S for _R, S, _c in rects)
+                meta_all = np.zeros((3, total), dtype=np.int32)
+                plan = []
+                off = 0
+                for R, S, chunk in rects:
+                    s = len(chunk)
+                    meta_all[0, off: off + s] = rows[chunk]
+                    meta_all[1, off: off + s] = starts[chunk]
+                    meta_all[2, off: off + s] = lens[chunk]
+                    plan.append((R, S, off, self._rect_pallas(R)))
+                    off += S
+                LEDGER.up("window-meta", meta_all)
+                self._results.tbl = _score_window_into_table(
+                    self._results.tbl, cnt, dst, self.row_sums,
+                    meta_all, np.float32(self.observed),
+                    top_k=self.top_k, plan=tuple(plan),
+                    interpret=self._pallas_interpret)
         if self.defer_results:
             self._results.mark(rows)
         return chunks
+
+    def _count_scored(self, rects, lens: np.ndarray) -> None:
+        """One scoring program over ``rects`` (``(R, S, ...)``
+        rectangles, a fixed plan's all-padding ones included) for rows
+        of lengths ``lens``: the window's launch and cell counts."""
+        clk = self.stage_clock
+        clk.add("launches")
+        clk.add("score_cells", sum(r[0] * r[1] for r in rects))
+        clk.add("live_cells", int(lens.sum()))
 
     def _check_row_sums(self, rows: np.ndarray) -> None:
         """Dev-mode invariant: slab row contents sum to the tracked row sum

@@ -422,6 +422,7 @@ class TieredSlabStore(StateStore):
                 keys_o = keys[order]
                 slots_o = np.ascontiguousarray(slots[order])
                 LEDGER.up("spill-slots", slots_o)
+                sc.stage_clock.add("launches")
                 fetched = np.asarray(cnt_dev[jnp.asarray(slots_o)])
                 LEDGER.down("spill-cells", fetched)
                 vals = fetched.astype(np.int32)
