@@ -9,7 +9,9 @@ import pytest
 import jax.numpy as jnp
 
 from tpu_cooccurrence.ops.device_scorer import _score
-from tpu_cooccurrence.ops.pallas_score import pallas_score_topk
+from tpu_cooccurrence.ops.pallas_score import (pallas_score_topk,
+                                               pallas_score_topk_local,
+                                               topk_parity)
 
 
 @pytest.mark.parametrize("seed,num_items,s,top_k", [
@@ -200,3 +202,104 @@ def test_pallas_auto_rule():
         assert (DeviceScorer(64, 5, use_pallas="auto",
                              count_dtype=dt).use_pallas
                 is pallas_auto(np.dtype(dt), jax.default_backend(), 5))
+
+
+# -- the kernel's own row fetch from C --------------------------------------
+
+def _counts(rng, num_items, dtype, nnz=6000):
+    C = np.zeros((num_items, num_items), dtype=dtype)
+    np.add.at(C, (rng.integers(0, num_items, nnz),
+                  rng.integers(0, num_items, nnz)), 1)
+    return C, C.sum(axis=1, dtype=np.int64).astype(np.int32)
+
+
+#: Row sets that exercise the fetch: each row's 8-row group is DMA'd,
+#: rows sharing a group share one DMA, a short set pads with row 0.
+FETCH_ROWS = {
+    # unsorted, repeated, 19 rows (not a block multiple: pads with row 0)
+    "unsorted-repeats": [255, 3, 3, 2, 9, 0, 7, 6, 254, 100, 101, 102, 5,
+                         5, 250, 1, 8, 17, 33],
+    # a whole group, odd and even rows, one group split across blocks
+    "shared-groups": [8, 9, 10, 11, 12, 13, 14, 15, 41, 42, 43, 44, 45,
+                      46, 47, 48, 49, 50, 51, 52, 53, 54, 55, 56],
+    # the catalog's last rows, one at a time across groups
+    "last-rows": [511, 504, 496, 503, 510, 509, 1, 488, 480, 479, 505],
+}
+
+
+@pytest.mark.parametrize("dtype", [np.int16, np.int32])
+@pytest.mark.parametrize("case", sorted(FETCH_ROWS))
+def test_dense_kernel_fetch_matches_xla(dtype, case):
+    """Rows fetched by the kernel from C in HBM score like the XLA
+    scorer's, over four column tiles, and a row's result does not depend
+    on which rows share its block or group (bitwise under a shuffle)."""
+    num_items = 512
+    C, row_sums = _counts(np.random.default_rng(11), num_items, dtype)
+    observed = np.float32(row_sums.sum())
+    rows = np.asarray(FETCH_ROWS[case], dtype=np.int32)
+    top_k = 8
+    args = (jnp.asarray(C), jnp.asarray(row_sums))
+    ref_vals, ref_idx = _score(*args, jnp.asarray(rows), observed,
+                               top_k=top_k)
+    got_vals, got_idx = pallas_score_topk(*args, jnp.asarray(rows), observed,
+                                          top_k=top_k, tile=128,
+                                          interpret=True)
+    ok, mism = topk_parity(got_vals, got_idx, ref_vals, ref_idx)
+    assert ok and mism == 0
+
+    perm = np.random.default_rng(12).permutation(len(rows))
+    p_vals, p_idx = pallas_score_topk(*args, jnp.asarray(rows[perm]),
+                                      observed, top_k=top_k, tile=128,
+                                      interpret=True)
+    np.testing.assert_array_equal(np.asarray(p_vals),
+                                  np.asarray(got_vals)[perm])
+    np.testing.assert_array_equal(np.asarray(p_idx),
+                                  np.asarray(got_idx)[perm])
+
+
+@pytest.mark.parametrize("dtype", [np.int16, np.int32])
+def test_dense_kernel_local_block_offset(dtype):
+    """The sharded form reads a shard's row block of C (global rows
+    [lo, lo + 128)) and scores exactly what the whole-C form scores."""
+    num_items, lo, per_shard = 512, 256, 128
+    C, row_sums = _counts(np.random.default_rng(13), num_items, dtype)
+    observed = np.float32(row_sums.sum())
+    rows = np.asarray([383, 256, 300, 301, 302, 256, 311, 376, 377, 290,
+                       264, 265, 333], dtype=np.int32)
+    packed = pallas_score_topk_local(
+        jnp.asarray(C[lo:lo + per_shard]), jnp.asarray(row_sums),
+        jnp.asarray(rows), lo, observed, top_k=6, tile=128, interpret=True)
+    whole = pallas_score_topk(jnp.asarray(C), jnp.asarray(row_sums),
+                              jnp.asarray(rows), observed, top_k=6,
+                              tile=128, interpret=True, packed=True)
+    np.testing.assert_array_equal(np.asarray(packed), np.asarray(whole))
+
+
+@pytest.mark.parametrize("dtype", ["int16", "int32"])
+def test_dense_scorer_counts_fetch_cells(dtype):
+    """``fetch_cells`` is the groups the kernel DMAs x 8 x the width: in
+    each row block, a row starts a DMA unless the row before it is in
+    the same 8-row group (padding rows are row 0)."""
+    from tpu_cooccurrence.ops.device_scorer import DeviceScorer, pad_pow4
+    from tpu_cooccurrence.ops.pallas_score import BLOCK_ROWS, _fetch_plan
+    from tpu_cooccurrence.sampling.reservoir import PairDeltaBatch
+
+    src = np.asarray([9, 10, 11, 12, 40, 41, 200, 201, 207, 208, 300, 301,
+                      302, 303, 304, 305, 306, 307, 308, 500, 505, 90],
+                     dtype=np.int64)
+    dst = (src * 7 + 3) % 512
+    sc = DeviceScorer(512, top_k=5, use_pallas="on", count_dtype=dtype)
+    sc.process_window(0, PairDeltaBatch(src, dst,
+                                        np.ones(len(src), np.int32)))
+    rows = np.unique(src)
+    padded = np.zeros(pad_pow4(len(rows), minimum=64), dtype=np.int64)
+    padded[:len(rows)] = rows
+    groups = sum(1 for k in range(len(padded))
+                 if k % BLOCK_ROWS == 0 or padded[k] // 8 != padded[k - 1] // 8)
+    counts = sc.stage_clock.counts
+    assert counts["fetch_cells"] == groups * 8 * sc.num_items
+    # ...and that is the plan the kernel fetches by.
+    _slots, _firsts, n_groups = _fetch_plan(jnp.asarray(padded, jnp.int32))
+    assert int(n_groups.sum()) == groups
+    assert counts["live_cells"] == len(rows) * sc.num_items
+    sc.flush()

@@ -12,6 +12,7 @@ file is the one that loads it.
 """
 
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -71,16 +72,14 @@ def _assert_kernel(compiled):
 # -- the three Pallas kernels at real widths --------------------------------
 
 def _dense_kernel(one_chip):
-    from tpu_cooccurrence.ops.pallas_score import (_pallas_topk_gathered,
-                                                   row_block)
+    from tpu_cooccurrence.ops.pallas_score import dense_topk
 
     rows, items = 8192, 61_440
-    blk = row_block(jnp.int16)
-    fn = jax.jit(lambda g, rs2d, rsi, obs: _pallas_topk_gathered(
-        g, rs2d, rsi, obs, top_k=10, tile=2048, blk=blk, interpret=False))
-    return fn.lower(_sds((rows, items), jnp.int16, one_chip),
-                    _sds((1, items), jnp.int32, one_chip),
-                    _sds((rows, 1), jnp.int32, one_chip),
+    fn = jax.jit(lambda c, rs, r, obs: dense_topk(
+        c, r, rs, obs, top_k=10, tile=2048, interpret=False))
+    return fn.lower(_sds((items, items), jnp.int16, one_chip),
+                    _sds((items,), jnp.int32, one_chip),
+                    _sds((rows,), jnp.int32, one_chip),
                     _sds((), jnp.float32, one_chip)).compile()
 
 
@@ -144,12 +143,11 @@ def _dense_update(one_chip):
                     num_items=items).compile()
 
 
-def _dense_score(one_chip):
+def _dense_score(one_chip, items=61_440):
     """...and score half: the int16 Pallas scorer over the rows budget."""
     from tpu_cooccurrence.ops import device_scorer as ds
     from tpu_cooccurrence.ops.pallas_score import pallas_score_topk
 
-    items = 61_440
     rows = ds.score_row_budget(items, 8192)
     return pallas_score_topk.lower(
         _sds((items, items), jnp.int16, one_chip),
@@ -224,9 +222,20 @@ def _sparse_fused(one_chip, monkeypatch):
                       **dict(kwargs, interpret=False)).compile()
 
 
+def _assert_reads_c_in_place(compiled):
+    """The kernel fetches its rows from C itself: the program makes no
+    int16 value (no copy, slice or gather of C) and holds no [S, I]
+    buffer of gathered rows."""
+    made = [line.strip()[:120] for line in compiled.as_text().splitlines()
+            if re.search(r"= s16\[", line) and "parameter(" not in line]
+    assert not made, made
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
 @pytest.mark.parametrize("program", [
     "dense-update-61440-int16", "dense-score-61440-int16",
-    "dense-fused-config5", "sparse-fused-config4"])
+    "dense-score-59392-int16-ml25m", "dense-fused-config5",
+    "sparse-fused-config4"])
 def test_main_path_program_compiles(one_chip, monkeypatch, program):
     if program == "dense-update-61440-int16":
         compiled = _dense_update(one_chip)
@@ -234,9 +243,12 @@ def test_main_path_program_compiles(one_chip, monkeypatch, program):
         # Donation keeps ONE 7.55 GB C resident: without the alias the
         # output C alone would double it past the chip's HBM.
         assert m.alias_size_in_bytes >= 61_440 ** 2 * 2
-    elif program == "dense-score-61440-int16":
-        compiled = _dense_score(one_chip)
+    elif program.startswith("dense-score"):
+        # 59,392: the ML-25M benchmark cell's catalog, padded to the tile
+        # (4,096 rows a call, tile 2,048).
+        compiled = _dense_score(one_chip, int(program.split("-")[2]))
         _assert_kernel(compiled)
+        _assert_reads_c_in_place(compiled)
     elif program == "dense-fused-config5":
         compiled = _dense_fused(one_chip)
         _assert_kernel(compiled)

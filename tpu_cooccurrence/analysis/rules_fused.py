@@ -12,8 +12,8 @@ miscompile class (see the float32-id workaround in
 ``_score_topk_kernel``) that nothing ever compares against a reference
 implementation, documented nowhere an operator looks.
 
-Coverage is one call hop wide: a private kernel core (e.g.
-``_pallas_topk_gathered``) counts as parity-tested when a module-level
+Coverage is one call hop wide: a kernel core (e.g. ``dense_topk``)
+counts as parity-tested when a module-level
 wrapper that calls it is referenced from ``tests/`` — the wrappers are
 the public surface the tests drive. AST-checked (nothing imported) and
 baseline-free by construction, mirroring the ``degrade-registry`` rule.

@@ -373,10 +373,10 @@ def _fused_apply_baskets(C, row_sums, block, num_items: int,
     basket rectangle plus the (new, len, skip, sign) meta columns. The
     expansion runs in the Pallas kernel
     (``pallas_score.pallas_expand_baskets``); the scatter-add stays an
-    XLA op inside the same program — Mosaic cannot scatter to arbitrary
-    HBM rows, the same boundary that keeps the dense score kernel's
-    ``C[rows]`` gather in XLA. Invalid/padded lanes carry (0, 0, 0):
-    the scatter no-op triple, so no masking is needed here.
+    XLA op inside the same program — Mosaic cannot scatter-add to
+    arbitrary HBM rows (the score kernel only reads them, a DMA of each
+    row's 8-row group). Invalid/padded lanes carry (0, 0, 0): the
+    scatter no-op triple, so no masking is needed here.
     """
     from .pallas_score import pallas_expand_baskets
 
@@ -396,23 +396,17 @@ def _fused_score_packed(C, row_sums, rows, observed, top_k: int,
                         use_pallas: bool, tile: int, interpret: bool):
     """Score half of the fused program: the SAME math as the chained
     path — ``_score_body`` when the Pallas score kernel is off, the
-    shared ``_pallas_topk_gathered`` core when it is on — so fused and
+    shared ``pallas_score.dense_topk`` core when it is on — so fused and
     chained results are bitwise equal, not just close."""
     if not use_pallas:
         return _score_body(C, row_sums, rows, observed, top_k, packed=True)
-    from .pallas_score import _pallas_topk_gathered, row_block
+    from .pallas_score import dense_topk
 
-    blk = row_block(C.dtype)
-    sp = rows.shape[0]  # caller pads to a pow4 bucket (a blk multiple)
-    with jax.named_scope("gather"):
-        gathered = C[rows]
-        rsi = row_sums[rows].reshape(sp, 1)
-    rs2d = row_sums.reshape(1, C.shape[0])
-    # Unscoped: the kernel's custom call is the score stage and keeps
-    # the enclosing program's name (see pallas_score_topk).
-    vals, idx = _pallas_topk_gathered(gathered, rs2d, rsi, observed,
-                                      top_k=top_k, tile=tile, blk=blk,
-                                      interpret=interpret)
+    # The caller pads rows to a pow4 bucket (a row-block multiple). The
+    # kernel's custom call is unscoped: it keeps the enclosing program's
+    # name (see pallas_score_topk).
+    vals, idx = dense_topk(C, rows, row_sums, observed, top_k=top_k,
+                           tile=tile, interpret=interpret)
     # Value-space id packing, exactly like pallas_score_topk(packed=True).
     return jnp.stack([vals[:, :top_k], idx[:, :top_k]])
 
@@ -630,7 +624,8 @@ class DeviceScorer:
         self.last_dispatch_fused = False
         # Tracing plane: per-window stage seconds (index / uplink-encode
         # / rescore; the job carves the rest of score_seconds into
-        # dispatch) and counts (launches, score_cells, live_cells).
+        # dispatch) and counts (launches, score_cells, live_cells, and
+        # with the Pallas kernel fetch_cells).
         self.stage_clock = StageClock()
         self._fused_dispatches = REGISTRY.gauge(
             "cooc_fused_dispatches_total",
@@ -797,9 +792,9 @@ class DeviceScorer:
                 chunk = rows[lo: lo + self.max_score_rows]
                 s = len(chunk)
                 pad_s = min(pad_pow4(s, minimum=64), self.max_score_rows)
-                self._count_scored(s, pad_s)
                 rows_padded = np.zeros(pad_s, dtype=np.int32)
                 rows_padded[:s] = chunk
+                self._count_scored(s, rows_padded)
                 LEDGER.up("score-rows", rows_padded)
                 if self.use_pallas:
                     from .pallas_score import pallas_score_topk
@@ -831,14 +826,20 @@ class DeviceScorer:
         return (self._materialize(prev) if prev is not None
                 else TopKBatch.empty(self.top_k))
 
-    def _count_scored(self, rows: int, padded_rows: int) -> None:
-        """One scoring program over ``padded_rows`` rows of the catalog
-        width, ``rows`` of them live: the window's launch and cell
-        counts."""
+    def _count_scored(self, rows: int, rows_padded: np.ndarray) -> None:
+        """One scoring program over ``rows_padded`` rows of the catalog
+        width, the first ``rows`` of them live: the window's launch and
+        cell counts, and with the Pallas kernel the cells it fetches from
+        ``C`` (whole 8-row groups; over ``live_cells``, the fetch's read
+        amplification)."""
         clk = self.stage_clock
         clk.add("launches")
-        clk.add("score_cells", padded_rows * self.num_items)
+        clk.add("score_cells", len(rows_padded) * self.num_items)
         clk.add("live_cells", rows * self.num_items)
+        if self.use_pallas:
+            from .pallas_score import fetch_cells
+
+            clk.add("fetch_cells", fetch_cells(rows_padded, self.num_items))
 
     def _try_fused(self, ts: int, b: BasketBatch) -> Optional[TopKBatch]:
         """Run one window through the fused one-dispatch program, or
@@ -911,9 +912,9 @@ class DeviceScorer:
 
         s = len(rows)
         pad_s = min(pad_pow4(s, minimum=64), self.max_score_rows)
-        self._count_scored(s, pad_s)
         rows_padded = np.zeros(pad_s, dtype=np.int32)
         rows_padded[:s] = rows
+        self._count_scored(s, rows_padded)
         observed = np.float32(self.observed)
         if self.defer_results:
             self.stage_clock.add("launches", self._results.ensure())

@@ -4,25 +4,31 @@ The XLA path (``ops/device_scorer._score``) materializes a ``[S, I]`` float32
 score matrix in HBM and then runs ``lax.top_k`` over it — two full passes of
 HBM traffic over data that is consumed once. This kernel fuses the whole of
 hot loop 4 (SURVEY §3.4: contingency build + LLR + top-K selection): for
-each block of scored rows it streams column tiles of the gathered count
-rows through VMEM, computes the stable-form LLR on the VPU, and folds each
-tile into a running top-K scratch without ever writing scores back to HBM.
+each block of scored rows it streams column tiles of those rows of ``C``
+through VMEM, computes the stable-form LLR on the VPU, and folds each tile
+into a running top-K scratch without ever writing scores back to HBM.
 
-The row gather ``C[rows]`` happens in XLA before the kernel and does
-materialize an ``[S, I]`` int32 buffer in HBM (TPU block layout requires
-sublane-aligned blocks, so arbitrary single-row blocks can't be indexed
-from inside the kernel). What the fusion removes versus the XLA path is
-the float32 score matrix write plus ``top_k``'s separate full re-read of
-it; the caller additionally bounds ``S`` so the gathered buffer stays
-within a fixed HBM budget (``DeviceScorer.max_score_rows``).
+The kernel reads its rows from ``C`` in place (``dense_topk``). ``C``
+stays in HBM (``memory_space=pl.ANY``). Mosaic slices HBM along whole
+``(8, 128)`` tiles only (int16 as well: it packs row pairs into 32-bit
+words), so a row is fetched with the 8-row group that holds it: one DMA
+of ``[8, TILE]`` per group, shared by consecutive rows of a block that
+fall in the same group. XLA plans the groups of each block from the row
+ids (``_fetch_plan``, a few ops over ``S`` ints), and the plan rides in
+scalar memory (``PrefetchScalarGridSpec``). Each grid step starts the
+next step's DMAs before it scores its own tile, so the fetch overlaps
+the LLR, and loads each row from its group (int16 through a 32-bit view
+and a half-word shift) into the ``[R, TILE]`` count block. Nothing of
+``C`` is copied or gathered outside the kernel.
 
-Grid: ``(S // R, I // TILE)`` with ``R = row_block(count_dtype)`` rows per
-block — the count dtype's sublane tile (8 for int32, 16 for int16, whose
-halved bytes are exactly the regime where fusing away the f32 score
-matrix matters most). The running top-K lives in VMEM scratch that
-persists across the column-tile dimension (sequential grid execution,
-innermost-last order), initialized at ``j == 0`` and written to the
-output block at the last tile.
+Grid: ``(S // R, I // TILE)`` with ``R = BLOCK_ROWS`` rows per block.
+The counts reach the LLR as float32, so ``R`` is free of the count
+dtype's tiling: 64 rows a step amortize the per-step cost of the grid
+and of issuing the fetches (measured on a v5e against 16, 32 and 128).
+The running top-K lives in VMEM scratch that persists across the
+column-tile dimension (sequential grid execution, innermost-last
+order), initialized at ``j == 0`` and written to the output block at
+the last tile.
 
 Tie-breaking matches ``lax.top_k`` (lowest column index among equal scores):
 within a tile the extraction picks the minimum position, and the running
@@ -35,6 +41,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -42,32 +50,93 @@ from .. import tuning
 from .llr import llr_stable
 
 _K_PAD = 128     # output lane width; logical top_k occupies the first K lanes
+#: Rows per fetch from C: C's HBM tiling is 8 rows deep for int32 and
+#: int16 alike (int16 packs row pairs into 32-bit words), and Mosaic
+#: slices HBM along whole tiles only, so a row comes with its group.
+_GROUP = 8
+#: Rows per grid step of the dense kernel (see the module docstring).
+BLOCK_ROWS = 64
 
 
-def row_block(count_dtype) -> int:
-    """Rows per grid step: the sublane tile of the count dtype.
+def fetch_cells(rows, width: int) -> int:
+    """Cells of ``C`` the dense kernel DMAs for one call over the padded
+    ``rows`` (host ints) at catalog ``width``: in each row block, a row
+    fetches its ``_GROUP``-row group unless the row before it lies in the
+    same group."""
+    g = np.asarray(rows).reshape(-1, BLOCK_ROWS) // _GROUP
+    groups = g.shape[0] + int((g[:, 1:] != g[:, :-1]).sum())
+    return groups * _GROUP * width
 
-    int32 tiles are (8, 128); int16 packs two values per sublane word, so
-    its native tile is (16, 128) — 16-row blocks keep the gathered count
-    rectangle layout-aligned and feed the VPU full registers.
-    """
-    return 16 if jnp.dtype(count_dtype).itemsize == 2 else 8
+
+def _load_row(group, sub):
+    """Row ``sub`` of one fetched ``[_GROUP, TILE]`` group ref, as a
+    ``[1, TILE]`` float32 (exact: counts are int16/int32)."""
+    if group.dtype == jnp.int32:
+        return group[pl.ds(sub, 1), :].astype(jnp.float32)
+    # int16: rows 2m and 2m + 1 share 32-bit word m (low half first), and
+    # Mosaic loads a single row of a 32-bit ref only. (Selecting the row
+    # with a mask over all 8 took 2.5% longer on a v5e.)
+    word = group.bitcast(jnp.int32)[pl.ds(lax.shift_right_logical(sub, 1),
+                                          1), :]
+    shift = lax.shift_left(lax.bitwise_xor(lax.bitwise_and(sub, 1), 1), 4)
+    return lax.shift_right_arithmetic(lax.shift_left(word, shift),
+                                      16).astype(jnp.float32)
 
 
-def _score_topk_kernel(g_ref, rsj_ref, rsi_ref, obs_ref,
-                       vals_ref, idx_ref, run_vals, run_idx, *, top_k, tile,
-                       block):
-    j = pl.program_id(1)
-    n_j = pl.num_programs(1)
-    R = block
+def _score_topk_kernel(slots_ref, firsts_ref, n_ref, c_hbm, rsj_ref,
+                       rsi_ref, obs_ref, vals_ref, idx_ref, groups, sems,
+                       counts_ref, run_vals, run_idx, *, top_k, tile):
+    i, j = pl.program_id(0), pl.program_id(1)
+    n_i, n_j = pl.num_programs(0), pl.num_programs(1)
+    R = BLOCK_ROWS
+    step = i * n_j + j
+    buf = step % 2
+
+    def copy(bi, bj, b, g):
+        """The DMA of row block ``bi``'s ``g``-th group, column tile
+        ``bj``, into buffer ``b``."""
+        first = pl.multiple_of(firsts_ref[bi * R + g], _GROUP)
+        return pltpu.make_async_copy(
+            c_hbm.at[pl.ds(first, _GROUP),
+                     pl.ds(pl.multiple_of(bj * tile, tile), tile)],
+            groups.at[b, g], sems.at[b, g])
+
+    def start(bi, bj, b):
+        @pl.loop(0, n_ref[bi])
+        def _(g):
+            copy(bi, bj, b, g).start()
+
+    # Double buffering over the sequential grid: step t's groups were
+    # requested at step t - 1, and step t + 1's go out before this
+    # step's compute.
+    @pl.when(step == 0)
+    def _first():
+        start(i, j, buf)
+
+    @pl.when(step + 1 < n_i * n_j)
+    def _next():
+        last = j == n_j - 1
+        start(jnp.where(last, i + 1, i), jnp.where(last, 0, j + 1), 1 - buf)
 
     @pl.when(j == 0)
     def _init():
         run_vals[...] = jnp.full((R, _K_PAD), -jnp.inf, dtype=jnp.float32)
         run_idx[...] = jnp.zeros((R, _K_PAD), dtype=jnp.float32)
 
-    counts = g_ref[...]                                     # [R, TILE] counts
-    k11 = counts.astype(jnp.float32)
+    @pl.loop(0, n_ref[i])
+    def _(g):
+        copy(i, j, buf, g).wait()
+
+    # Unrolled, so the scalar work uses lax ops: each jnp operator traces
+    # a jitted wrapper of its own.
+    base = i * R
+    for k in range(R):
+        slot_sub = slots_ref[lax.add(base, k)]
+        counts_ref[k:k + 1, :] = _load_row(
+            groups.at[buf, lax.shift_right_logical(slot_sub, 3)],
+            lax.bitwise_and(slot_sub, _GROUP - 1))
+
+    k11 = counts_ref[...]                                   # [R, TILE] f32
     rsj = rsj_ref[0, :].astype(jnp.float32)[None, :]        # [1, TILE]
     rsi = rsi_ref[...].astype(jnp.float32)                  # [R, 1]
     observed = obs_ref[0, 0].astype(jnp.float32)
@@ -76,7 +145,7 @@ def _score_topk_kernel(g_ref, rsj_ref, rsi_ref, obs_ref,
     k21 = rsj - k11
     k22 = observed + k11 - k12 - k21
     scores = llr_stable(k11, k12, k21, k22)
-    scores = jnp.where(counts != 0, scores, -jnp.inf)       # [R, TILE]
+    scores = jnp.where(k11 != 0, scores, -jnp.inf)          # [R, TILE]
 
     # Threshold skip: the merge below costs more VPU work than the LLR
     # itself (top_k sequential extractions over the candidate width). A
@@ -129,44 +198,105 @@ def _score_topk_kernel(g_ref, rsj_ref, rsi_ref, obs_ref,
         idx_ref[...] = run_idx[...]
 
 
-def _pallas_topk_gathered(gathered, rs2d, rsi, observed, *, top_k: int,
-                          tile: int, blk: int, interpret: bool):
-    """The dense kernel's pallas_call on pre-gathered inputs.
-
-    gathered [Sp, I] int32|int16 (Sp % blk == 0, I % tile == 0),
-    rs2d [1, I] int32, rsi [Sp, 1] int32, observed scalar f32.
-    Returns (vals [Sp, _K_PAD] f32, idx [Sp, _K_PAD] f32 — ids as exact
-    float values). Shared by the single-chip wrapper (which gathers
-    ``C[rows]``) and the sharded backend (which gathers from its local
-    row block but passes the replicated global row sums).
+def _fetch_plan(local):
+    """The DMAs of each row block, from its ``[Sp]`` local row ids
+    (clamped into ``C``, as an XLA gather clamps its indices: a DMA is not
+    checked): a row shares the group of the row before it when both lie
+    in one ``_GROUP``-row group, else starts the block's next group.
+    Returns ``slots`` [Sp] (group number * 8 + row within the group),
+    ``firsts`` [Sp] (per block, each group's first row of ``C``) and
+    ``n_groups`` [Sp // BLOCK_ROWS]. :func:`fetch_cells` counts the same.
     """
-    sp, num_items = gathered.shape
+    first = jnp.bitwise_and(local, -_GROUP).reshape(-1, BLOCK_ROWS)
+    nb = first.shape[0]
+    starts = jnp.concatenate([jnp.ones((nb, 1), bool),
+                              first[:, 1:] != first[:, :-1]], axis=1)
+    group = jnp.cumsum(starts, axis=1, dtype=jnp.int32) - 1
+    firsts = jnp.zeros_like(first).at[
+        jnp.arange(nb)[:, None], group].set(first)
+    slots = group.reshape(-1) * _GROUP + jnp.bitwise_and(local, _GROUP - 1)
+    return slots, firsts.reshape(-1), group[:, -1] + 1
+
+
+def dense_topk(C, rows, row_sums, observed, *, top_k: int, tile: int,
+               interpret: bool, lo=0):
+    """THE dense scoring core: fused LLR + top-K of ``C``'s rows
+    ``rows - lo``, fetched by the kernel from ``C`` in HBM.
+
+    C        [N, I] int32|int16 — dense counts, or a shard's row block
+             of them starting at global row ``lo`` (N % 8 == 0,
+             I % tile == 0)
+    row_sums [I] int32 — global row sums
+    rows     [Sp] int32 — global row ids, Sp % BLOCK_ROWS == 0
+    Returns (vals [Sp, _K_PAD] f32, idx [Sp, _K_PAD] f32 — ids as exact
+    float values). The row ids ride in scalar memory; each grid step
+    DMAs the 8-row groups its block needs for the next column tile while
+    it scores this one.
+    """
+    n_rows, num_items = C.shape
+    if C.dtype not in (jnp.int32, jnp.int16):
+        raise ValueError(
+            f"pallas scorer supports int32|int16 counts, got {C.dtype}")
+    if num_items % tile != 0:
+        raise ValueError(
+            f"num_items {num_items} must be a multiple of tile {tile}")
+    if n_rows % _GROUP:
+        raise ValueError(
+            f"C's {n_rows} rows must be a multiple of {_GROUP}: the kernel "
+            f"fetches whole {_GROUP}-row groups")
+    if num_items > 1 << 24:
+        raise ValueError(
+            f"num_items {num_items} exceeds 2^24: column ids are tracked as "
+            f"exact float32 inside the kernel (int32 scratch miscompiles on "
+            f"Mosaic); use the XLA scorer (pallas='off') beyond that")
+    if top_k > _K_PAD:
+        raise ValueError(
+            f"top_k {top_k} exceeds the kernel's lane width {_K_PAD}; "
+            f"use the XLA scorer (pallas='off') for larger K")
+    blk = BLOCK_ROWS
+    sp = rows.shape[0]
+    # Device-side stage name of the row-sum lookup and the fetch plan (op
+    # metadata in a profiler trace). The kernel stays outside any scope:
+    # a Pallas custom call takes the innermost scope's name, and the
+    # trace's readers match it as ``pallas_score_topk``.
+    with jax.named_scope("gather"):
+        rsi = row_sums[rows].reshape(sp, 1)
+        slots, firsts, n_groups = _fetch_plan(
+            jnp.clip(rows - lo, 0, n_rows - 1))
     obs = jnp.full((1, 1), observed, dtype=jnp.float32)
-    kernel = functools.partial(_score_topk_kernel, top_k=top_k, tile=tile,
-                               block=blk)
+    kernel = functools.partial(_score_topk_kernel, top_k=top_k, tile=tile)
     return pl.pallas_call(
         kernel,
-        grid=(sp // blk, num_items // tile),
-        in_specs=[
-            pl.BlockSpec((blk, tile), lambda i, j: (i, j)),
-            pl.BlockSpec((1, tile), lambda i, j: (0, j)),
-            pl.BlockSpec((blk, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((blk, _K_PAD), lambda i, j: (i, 0)),
-            pl.BlockSpec((blk, _K_PAD), lambda i, j: (i, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(sp // blk, num_items // tile),
+            in_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, tile), lambda i, j, *_: (0, j)),
+                pl.BlockSpec((blk, 1), lambda i, j, *_: (i, 0)),
+                pl.BlockSpec((1, 1), lambda i, j, *_: (0, 0)),
+            ],
+            out_specs=(
+                pl.BlockSpec((blk, _K_PAD), lambda i, j, *_: (i, 0)),
+                pl.BlockSpec((blk, _K_PAD), lambda i, j, *_: (i, 0)),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((2, blk, _GROUP, tile), C.dtype),
+                pltpu.SemaphoreType.DMA((2, blk)),
+                pltpu.VMEM((blk, tile), jnp.float32),
+                pltpu.VMEM((blk, _K_PAD), jnp.float32),
+                pltpu.VMEM((blk, _K_PAD), jnp.float32),
+            ],
         ),
-        scratch_shapes=[
-            pltpu.VMEM((blk, _K_PAD), jnp.float32),
-            pltpu.VMEM((blk, _K_PAD), jnp.float32),
-        ],
         out_shape=(
             jax.ShapeDtypeStruct((sp, _K_PAD), jnp.float32),
             jax.ShapeDtypeStruct((sp, _K_PAD), jnp.float32),
         ),
+        # Sequential grid: a step waits on DMAs the step before started.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(gathered, rs2d, rsi, obs)
+    )(slots, firsts, n_groups, C, row_sums.reshape(1, num_items), rsi, obs)
 
 
 def pallas_score_topk_local(C_loc, row_sums, rows_global, lo, observed, *,
@@ -181,33 +311,14 @@ def pallas_score_topk_local(C_loc, row_sums, rows_global, lo, observed, *,
     (decode with astype — same contract as ``pallas_score_topk(packed=
     True)``). Padded rows may repeat a real row; the caller drops them.
     """
-    num_items = C_loc.shape[1]
-    if C_loc.dtype not in (jnp.int32, jnp.int16):
-        raise ValueError(
-            f"pallas scorer supports int32|int16 counts, got {C_loc.dtype}")
-    if num_items % tile != 0:
-        raise ValueError(
-            f"num_items {num_items} must be a multiple of tile {tile}")
-    if num_items > 1 << 24:
-        raise ValueError(
-            f"num_items {num_items} exceeds 2^24: column ids ride as exact "
-            f"float32; use the XLA scorer beyond that")
-    if top_k > _K_PAD:
-        raise ValueError(
-            f"top_k {top_k} exceeds the kernel's lane width {_K_PAD}")
-    blk = row_block(C_loc.dtype)
     S = rows_global.shape[0]
-    pad_s = (-S) % blk
+    pad_s = (-S) % BLOCK_ROWS
     if pad_s:
         rows_global = jnp.concatenate(
             [rows_global, jnp.full(pad_s, lo, dtype=rows_global.dtype)])
-    sp = S + pad_s
-    gathered = C_loc[rows_global - lo]                   # [Sp, I]
-    rsi = row_sums[rows_global].reshape(sp, 1)
-    rs2d = row_sums.reshape(1, num_items)
-    vals, idxf = _pallas_topk_gathered(gathered, rs2d, rsi, observed,
-                                       top_k=top_k, tile=tile, blk=blk,
-                                       interpret=interpret)
+    vals, idxf = dense_topk(C_loc, rows_global, row_sums, observed,
+                            top_k=top_k, tile=tile, interpret=interpret,
+                            lo=lo)
     return jnp.stack([vals[:S, :top_k], idxf[:S, :top_k]])
 
 
@@ -363,8 +474,8 @@ def pallas_score_rect(cnt, dst, row_sums, meta, observed, *, top_k: int,
     Drop-in replacement for ``state/sparse_scorer._score_rect`` (same
     arguments, same packed ``[2, S_pad, K]`` float32 output with ids as
     an int32 *bitcast*, same tie semantics), for use inside a jit — the
-    slab/row-sum gathers stay in XLA exactly like the dense kernel's
-    ``C[rows]`` gather; the kernel fuses away the ``[S, R]`` float32
+    slab/row-sum gathers stay in XLA (a rectangle's cells lie at
+    arbitrary slab offsets); the kernel fuses away the ``[S, R]`` float32
     score materialization and ``top_k``'s second full pass over it.
 
     cnt/dst   [cap]  int32 — slab cells (counts / partner ids)
@@ -533,7 +644,8 @@ def pallas_expand_baskets(basket, new, lens, skips, signs, *,
 def pallas_score_topk(C, row_sums, rows, observed, *, top_k: int,
                       tile: int = 512, interpret: bool = False,
                       packed: bool = False):
-    """Fused LLR + top-K over gathered rows. Mirrors ``device_scorer._score``.
+    """Fused LLR + top-K over rows of ``C``, which the kernel fetches
+    from HBM itself (``dense_topk``). Mirrors ``device_scorer._score``.
 
     C        [I, I] int32|int16 — dense co-occurrence counts (I % tile == 0)
     row_sums [I]    int32
@@ -544,38 +656,12 @@ def pallas_score_topk(C, row_sums, rows, observed, *, top_k: int,
     float *values* (decode with ``astype``, not a bitcast view) — so the
     caller fetches one buffer.
     """
-    num_items = C.shape[0]
-    if C.dtype not in (jnp.int32, jnp.int16):
-        raise ValueError(
-            f"pallas scorer supports int32|int16 counts, got {C.dtype}")
-    blk = row_block(C.dtype)
-    if num_items % tile != 0:
-        raise ValueError(f"num_items {num_items} must be a multiple of tile {tile}")
-    if num_items > 1 << 24:
-        raise ValueError(
-            f"num_items {num_items} exceeds 2^24: column ids are tracked as "
-            f"exact float32 inside the kernel (int32 scratch miscompiles on "
-            f"Mosaic); use the XLA scorer (pallas='off') beyond that")
-    if top_k > _K_PAD:
-        raise ValueError(
-            f"top_k {top_k} exceeds the kernel's lane width {_K_PAD}; "
-            f"use the XLA scorer (pallas='off') for larger K")
     S = rows.shape[0]
-    pad_s = (-S) % blk
+    pad_s = (-S) % BLOCK_ROWS
     if pad_s:
         rows = jnp.concatenate([rows, jnp.zeros(pad_s, dtype=rows.dtype)])
-    sp = S + pad_s
-    # Device-side stage name of the row gather (op metadata in a
-    # profiler trace). The kernel stays outside any scope: a Pallas
-    # custom call takes the innermost scope's name, and the trace's
-    # readers match it as ``pallas_score_topk``.
-    with jax.named_scope("gather"):
-        gathered = C[rows]                               # [Sp, I] count dtype
-        rsi = row_sums[rows].reshape(sp, 1)
-    rs2d = row_sums.reshape(1, num_items)
-    vals, idx = _pallas_topk_gathered(gathered, rs2d, rsi, observed,
-                                      top_k=top_k, tile=tile, blk=blk,
-                                      interpret=interpret)
+    vals, idx = dense_topk(C, rows, row_sums, observed, top_k=top_k,
+                           tile=tile, interpret=interpret)
     vals = vals[:S, :top_k]
     if packed:
         # Value-space packing: ids stay exact float32 (wrapper guard caps
