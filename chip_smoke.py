@@ -41,9 +41,13 @@ PHASES = ("dense", "dense-fused", "sparse", "sparse-fused")
 SHARDED = "sharded"
 
 #: Events (baskets for config 5) per workload. Config 5 is cut from
-#: 20,000 baskets: the float64 oracle's cost grows with the square of
-#: the basket count (133 s at 4,000 on one host core), so 20,000 would
-#: not fit the run's time limit.
+#: 20,000 baskets: the parity reference here is the program's own
+#: ``--backend oracle`` run (``OracleJob``), which rescores every row
+#: of the catalog it touches each window, so its cost grows with the
+#: square of the basket count (133 s at 4,000 on one host core) and
+#: 20,000 would not fit the run's time limit. (The benchmark's
+#: reference, ``benchmark/reference/oracle.py``, scores a sample of rows
+#: and is linear in the baskets.)
 SIZES = {"config3": 500_000, "config4": 200_000, "config5": 6_000}
 
 #: Which workload each phase streams, and its device-side settings.

@@ -25,7 +25,6 @@ from tpu_cooccurrence.config import Backend, Config
 from tpu_cooccurrence.job import CooccurrenceJob
 from tpu_cooccurrence.observability.registry import REGISTRY
 from tpu_cooccurrence.ops.aggregate import aggregate_window_coo
-from tpu_cooccurrence.ops.pallas_score import pallas_expand_baskets
 from tpu_cooccurrence.sampling.reservoir import (BasketBatch,
                                                  PairDeltaBatch,
                                                  UserReservoirSampler)
@@ -93,49 +92,68 @@ def _ladder_edge_stream():
     return users, np.asarray(items), np.asarray(ts, dtype=np.int64)
 
 
-# -- kernel-level parity (the registered parity test for
-#    pallas_expand_baskets, pinned by cooclint pallas-kernel-registry) --
+# -- the device-side expansion ------------------------------------------
 
 
-def test_pallas_expand_baskets_matches_host_expansion():
-    """The expansion kernel's folded COO output equals the host
-    expansion (BasketBatch.to_pairs) fold, across append ops (skip=-1),
-    replacement op pairs (skip=slot, ±1), zero-length ops, and pad
-    rows; pad/invalid lanes carry the (0, 0, 0) scatter no-op."""
+def _expand(b, step=0, pairs=None):
+    """One scatter step's lanes of ``b`` (pairs past ``pairs`` dropped)."""
+    n_cap, l_cap = ds.pad_pow2(b.n_ops, minimum=8), 128
+    blk = np.zeros((n_cap, l_cap + 4), np.int32)
+    blk[:b.n_ops, :b.baskets.shape[1]] = b.baskets
+    blk[:, l_cap + 2] = -1
+    blk[:b.n_ops, l_cap:] = np.stack([b.new_items, b.lens, b.skips,
+                                      b.signs], axis=1)
+    n = len(b) // 2 if pairs is None else pairs
+    lanes = ds._basket_lanes(blk, np.int32(step), np.int32(n),
+                             num_items=1000, basket_width=l_cap)
+    src, dst, delta = (np.asarray(x) for x in lanes)
+    keep = src < 1000
+    return src[keep], dst[keep], delta[keep]
+
+
+def test_basket_lanes_match_host_expansion():
+    """The device expansion's lanes fold to the host expansion's
+    (BasketBatch.to_pairs) across append ops (skip=-1), replacement op
+    pairs (skip=slot, +-1), zero-length ops and a skip past the length;
+    exactly the window's pairs are live, each once per direction."""
     rng = np.random.default_rng(42)
-    n_ops, w = 16, 128
+    n_ops, w = 16, 100
     baskets = rng.integers(1, 50, size=(n_ops, w)).astype(np.int32)
-    lens = np.array([0, 1, 5, 7] * 4, dtype=np.int32)
+    lens = np.array([0, 1, 5, 7, 100, 0, 3, 64] * 2, dtype=np.int32)
     skips = np.full(n_ops, -1, dtype=np.int32)
     skips[2::4] = 3                       # replacement-style exclusions
+    skips[6] = 9                          # past the op's length
     signs = np.ones(n_ops, dtype=np.int32)
     signs[3::4] = -1
     new = rng.integers(50, 60, size=n_ops).astype(np.int32)
     b = BasketBatch(new, baskets, lens, skips, signs)
-
-    src, dst, delta = pallas_expand_baskets(
-        baskets, new.reshape(-1, 1), lens.reshape(-1, 1),
-        skips.reshape(-1, 1), signs.reshape(-1, 1), interpret=True)
-    src, dst, delta = (np.asarray(src).ravel(), np.asarray(dst).ravel(),
-                      np.asarray(delta).ravel())
-    lanes_used = (delta != 0).sum()
-    assert lanes_used == len(b) == len(b.to_pairs())
-    # Every zero-delta lane is the full no-op triple.
-    idle = delta == 0
-    assert not src[idle].any() and not dst[idle].any()
+    src, dst, delta = _expand(b)
+    assert len(src) == len(b)
     p = b.to_pairs()
     assert _fold(src, dst, delta) == _fold(p.src, p.dst, p.delta)
+    # A window of more pairs than one step is the union of its steps.
+    steps = [_expand(b, t, pairs=len(b) // 2) for t in range(2)]
+    assert sum(len(x[0]) for x in steps) == len(b)
 
 
-def test_pallas_expand_baskets_rejects_bad_shapes():
-    ok = np.zeros((8, 128), np.int32)
-    meta = np.zeros((8, 1), np.int32)
-    with pytest.raises(ValueError, match="multiple of 8"):
-        pallas_expand_baskets(ok[:6], meta[:6], meta[:6], meta[:6],
-                              meta[:6], interpret=True)
-    with pytest.raises(ValueError, match="multiple of 128"):
-        pallas_expand_baskets(np.zeros((8, 64), np.int32), meta, meta,
-                              meta, meta, interpret=True)
+def test_basket_lanes_split_across_steps(monkeypatch):
+    """Pairs past one scatter step continue in the next, op boundaries
+    and skips included, and the lanes past the window's pairs drop."""
+    monkeypatch.setattr(ds, "SCATTER_STEP", 16)
+    rng = np.random.default_rng(7)
+    n_ops, w = 9, 40
+    baskets = rng.integers(1, 90, size=(n_ops, w)).astype(np.int32)
+    lens = np.array([11, 0, 30, 1, 17, 0, 40, 5, 2], dtype=np.int32)
+    skips = np.array([-1, -1, 12, -1, 0, -1, 39, 4, -1], dtype=np.int32)
+    signs = np.array([1, 1, 1, -1, 1, 1, -1, 1, 1], dtype=np.int32)
+    new = rng.integers(90, 99, size=n_ops).astype(np.int32)
+    b = BasketBatch(new, baskets, lens, skips, signs)
+    n = len(b) // 2
+    parts = [_expand(b, t, pairs=n) for t in range(-(-n // 16))]
+    src, dst, delta = (np.concatenate(x) for x in zip(*parts))
+    assert [len(x[0]) for x in parts[:-1]] == [32] * (len(parts) - 1)
+    p = b.to_pairs()
+    assert _fold(src, dst, delta) == _fold(p.src, p.dst, p.delta)
 
 
 # -- sampler encoding ---------------------------------------------------
@@ -200,6 +218,163 @@ def test_fused_bit_identical_with_pallas_score_and_int16():
         chained = _run_job(users, items, ts, fused_window="off", **kw)
         fused = _run_job(users, items, ts, fused_window="on", **kw)
         assert _table(chained) == _table(fused), extra
+
+
+def _basket_stream(seed, windows=8, users=12, items=120, per_window=6,
+                   window=10):
+    """Seeded baskets, as an order history streams: each basket is one
+    user's products under one timestamp, several baskets of a window
+    share a timestamp, and each user orders often enough that a small
+    ``user_cut`` fills the reservoir (replacements, -1 deltas)."""
+    rng = np.random.default_rng(seed)
+    users_out, items_out, ts_out = [], [], []
+    for w in range(windows):
+        stamps = np.sort(rng.integers(0, 3, per_window)) + w * window
+        for t in stamps:
+            u = int(rng.integers(users))
+            for _ in range(int(rng.integers(1, 15))):
+                users_out.append(u)
+                items_out.append(int(rng.integers(items)))
+                ts_out.append(int(t))
+    users_out.append(0)                   # flush the last window
+    items_out.append(0)
+    ts_out.append(windows * window + window)
+    return (relabel_first_appearance(np.asarray(users_out)),
+            relabel_first_appearance(np.asarray(items_out)),
+            np.asarray(ts_out, dtype=np.int64))
+
+
+def _spy_windows(job):
+    """Record what reaches the scorer each window: the logical pairs,
+    whether any carries a -1 delta, the lanes of its basket rectangle
+    and of its scatter steps."""
+    seen = []
+    real = job.scorer.process_window
+
+    def spy(ts, pairs):
+        rec = {"pairs": len(pairs), "minus": bool(len(pairs))
+               and bool((np.asarray(pairs.delta) < 0).any())}
+        if isinstance(pairs, BasketBatch) and pairs.n_ops:
+            rec["rect"] = (ds.pad_pow2(pairs.n_ops, minimum=64) * 2
+                           * ds.pad_pow2(pairs.baskets.shape[1],
+                                         minimum=128))
+            step = ds.SCATTER_STEP
+            rec["lanes"] = 2 * step * -(-(len(pairs) // 2) // step)
+        seen.append(rec)
+        return real(ts, pairs)
+
+    job.scorer.process_window = spy
+    return seen
+
+
+def _shrink_score_chunk(job):
+    job.scorer.max_score_rows = 64
+
+
+#: Basket windows the fused path must serve bitwise like the chained
+#: one: reservoir evictions, a rescoring set over one chained score
+#: chunk (the Pallas kernel scores it in one program, skipping the
+#: padding blocks), and an expansion over the old lane budget
+#: (``max_pairs_per_step``, now only the chained COO chunk).
+BASKET_CASES = {
+    "evictions": (dict(), None),
+    "evictions-pallas-int16": (dict(pallas="on", count_dtype="int16"),
+                               None),
+    "score-chunk-pallas": (dict(pallas="on"), _shrink_score_chunk),
+    "lane-budget": (dict(max_pairs_per_step=1 << 13), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BASKET_CASES))
+def test_fused_basket_windows_bit_identical_to_chained(case):
+    extra, tweak = BASKET_CASES[case]
+    users, items, ts = _basket_stream(5, items=400, per_window=12)
+    kw = dict(user_cut=10, item_cut=40, **extra)
+    jobs, seen = {}, {}
+    for mode in ("off", "on"):
+        job = CooccurrenceJob(Config(window_size=10, seed=0xBEEF,
+                                     backend=Backend.DEVICE,
+                                     development_mode=True,
+                                     fused_window=mode, **kw))
+        if tweak is not None:
+            tweak(job)
+        seen[mode] = _spy_windows(job)
+        for lo in range(0, len(users), 97):
+            job.add_batch(users[lo:lo + 97], items[lo:lo + 97],
+                          ts[lo:lo + 97])
+        job.finish()
+        jobs[mode] = job
+    chained, fused = jobs["off"], jobs["on"]
+    assert _table(chained) == _table(fused)
+    assert chained.counters.as_dict() == fused.counters.as_dict()
+    np.testing.assert_array_equal(np.asarray(chained.scorer.C),
+                                  np.asarray(fused.scorer.C))
+    np.testing.assert_array_equal(np.asarray(chained.scorer.row_sums),
+                                  np.asarray(fused.scorer.row_sums))
+    # And both as the host oracle, to the f32/f64 tolerance.
+    oracle = _run_job(users, items, ts, backend=Backend.ORACLE,
+                      user_cut=10, item_cut=40)
+    assert_latest_close(_table(oracle), _table(fused))
+    assert any(w["minus"] for w in seen["off"]), "no reservoir eviction"
+    # Every window with pairs went fused, and its counts say what the
+    # expansion was shaped for and what of it was live.
+    records = list(fused.step_timer.windows)
+    carrying = [w for w in seen["on"] if w["pairs"]]
+    counts = [r.counts for r in records if r.counts.get("fused_windows")]
+    assert len(counts) == len(carrying)
+    assert sum(c["expand_live"] for c in counts) == \
+        sum(w["pairs"] for w in carrying)
+    assert [c["expand_lanes"] for c in counts] == \
+        [w["lanes"] for w in carrying]
+    if case == "lane-budget":
+        assert max(w["rect"] for w in carrying) > 1 << 13
+    if case == "score-chunk-pallas":
+        # Rescoring sets over one chained chunk, scored in one program
+        # whose last pow4 blocks are padding the kernel skips.
+        width = fused.scorer.num_items
+        rows = [c["live_cells"] // width for c in counts]
+        assert max(rows) > 64
+        assert any(ds.pad_pow4(r, minimum=64) - r >= 64 for r in rows)
+        assert [c["score_cells"] // width for c in counts] == \
+            [-(-r // 64) * 64 for r in rows]
+
+
+def test_deferred_fused_windows_in_flight_are_bounded(monkeypatch):
+    """Once ``FUSED_IN_FLIGHT`` deferred fused windows are on the device,
+    the next waits for the oldest before it uplinks its block, so the
+    blocks the device holds stay bounded however far the host runs
+    ahead; results are unchanged."""
+    users, items, ts = _basket_stream(5, items=400, per_window=12)
+    kw = dict(user_cut=10, item_cut=40)
+    chained = _run_job(users, items, ts, fused_window="off", **kw)
+    waited, queued = [], []
+    real = ds._fused_window_defer
+
+    class Done:
+        def __init__(self, window):
+            self.window = window
+
+        def block_until_ready(self):
+            waited.append(self.window)
+            return self
+
+    def spy(*args, **kwargs):
+        queued.append(len(job.scorer._fused_queue))
+        *out, _done = real(*args, **kwargs)
+        return (*out, Done(len(queued)))
+
+    monkeypatch.setattr(ds, "_fused_window_defer", spy)
+    job = CooccurrenceJob(Config(window_size=10, seed=0xBEEF,
+                                 backend=Backend.DEVICE,
+                                 development_mode=True, fused_window="on",
+                                 **kw))
+    for lo in range(0, len(users), 97):
+        job.add_batch(users[lo:lo + 97], items[lo:lo + 97], ts[lo:lo + 97])
+    job.finish()
+    assert len(queued) > ds.FUSED_IN_FLIGHT
+    assert max(queued) == ds.FUSED_IN_FLIGHT - 1
+    assert waited == list(range(1, len(queued) - ds.FUSED_IN_FLIGHT + 1))
+    assert _table(chained) == _table(job)
 
 
 def test_fused_emit_updates_mode_bit_identical():
@@ -272,12 +447,13 @@ def test_chained_dispatch_path_unchanged_with_fused_off(monkeypatch):
 
 
 def test_fused_oversize_window_falls_back_chained(monkeypatch):
-    """A window whose padded expansion lanes exceed max_pairs_per_step
-    routes chained (per-window, results identical); the chunk budget is
+    """A window whose padded expansion lanes exceed FUSED_MAX_LANES
+    routes chained (per-window, results identical); the lane bound is
     honored rather than silently inflated."""
     users, items, ts = _ladder_edge_stream()
-    kw = dict(user_cut=4, item_cut=500, max_pairs_per_step=1 << 14)
+    kw = dict(user_cut=4, item_cut=500)
     chained = _run_job(users, items, ts, fused_window="off", **kw)
+    monkeypatch.setattr(ds, "FUSED_MAX_LANES", 1 << 14)
     counter = _FusedCounter(monkeypatch)
     fused = _run_job(users, items, ts, fused_window="on", **kw)
     # 2 * n_cap * l_cap = 16384 lanes at the minimum buckets fits the

@@ -275,6 +275,38 @@ def test_dense_kernel_local_block_offset(dtype):
     np.testing.assert_array_equal(np.asarray(packed), np.asarray(whole))
 
 
+@pytest.mark.parametrize("dtype", [np.int16, np.int32])
+def test_dense_kernel_skips_blocks_past_live(dtype):
+    """Rows past ``live`` are padding: their whole blocks fetch and score
+    nothing and return (-inf, 0), and every row of a live block scores
+    bitwise as without the bound."""
+    import jax
+
+    from tpu_cooccurrence.ops.pallas_score import dense_topk
+
+    num_items, live = 512, 100
+    C, row_sums = _counts(np.random.default_rng(14), num_items, dtype)
+    rows = np.zeros(256, dtype=np.int32)
+    rows[:live] = np.random.default_rng(15).integers(0, num_items, live)
+    args = (jnp.asarray(C), jnp.asarray(rows), jnp.asarray(row_sums),
+            np.float32(row_sums.sum()))
+
+    def run(bound):
+        return [np.asarray(a) for a in jax.jit(
+            lambda c, r, rs, o, n: dense_topk(c, r, rs, o, top_k=8,
+                                              tile=128, interpret=True,
+                                              live=n))(*args, bound)]
+
+    got_vals, got_idx = run(np.int32(live))
+    all_vals, all_idx = run(np.int32(len(rows)))
+    scored = 128  # the two blocks that hold live rows
+    np.testing.assert_array_equal(got_vals[:scored], all_vals[:scored])
+    np.testing.assert_array_equal(got_idx[:scored], all_idx[:scored])
+    assert np.isneginf(got_vals[scored:]).all()
+    assert not got_idx[scored:].any()
+    assert np.isfinite(all_vals[scored:, 0]).any()
+
+
 @pytest.mark.parametrize("dtype", ["int16", "int32"])
 def test_dense_scorer_counts_fetch_cells(dtype):
     """``fetch_cells`` is the groups the kernel DMAs x 8 x the width: in

@@ -230,8 +230,7 @@ class Config:
     # device backend (tumbling mode): the sampler uplinks baskets (star
     # ops) and expansion + count scatter + row sums + LLR + top-K run
     # as ONE program per shape bucket
-    # (ops/pallas_score.pallas_expand_baskets +
-    # ops/device_scorer._fused_window_*). sparse backend
+    # (ops/device_scorer._fused_window_*). sparse backend
     # (single-process, deferred results): packed-wire decode + slab
     # update scatter + device registry sync + rescore + results-table
     # scatter run as ONE program per shape bucket

@@ -190,7 +190,9 @@ SCHEMA = {
                                  # seconds] tuples (SPAN_STAGES)
     "counts": (False, dict),     # the scorer's per-window counts
                                  # (StageClock.counts: launches,
-                                 # score_cells, live_cells)
+                                 # score_cells, live_cells; dense
+                                 # fused_windows, expand_lanes,
+                                 # expand_live)
 }
 
 

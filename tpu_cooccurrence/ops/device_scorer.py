@@ -32,6 +32,7 @@ scalar.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import functools
 import logging
@@ -80,6 +81,23 @@ def pad_pow4(n: int, minimum: int = _POW2_PAD_MIN) -> int:
     while size < n:
         size *= 4
     return size
+
+
+#: Most lanes one fused window's basket rectangle (ops bucket x basket
+#: bucket, two lanes a cell) may expand to: an 8 MB int32 uplink at this
+#: bound; a larger window takes the chained path.
+FUSED_MAX_LANES = 1 << 22
+#: Most rows one fused window rescores with the Pallas kernel: a pow4
+#: bucket whose padding blocks the kernel skips (``dense_topk``'s
+#: ``live``), so no ``[S, I]`` working set bounds it.
+FUSED_MAX_ROWS = 1 << 16
+#: Deferred fused windows one scorer lets wait on the device. A window's
+#: uplinked basket block stays in HBM until its program runs, and the
+#: fused path leaves the host so little to do that it runs ahead to the
+#: runtime's own limit of queued programs: 0.1 GB of blocks at the
+#: Instacart cell's shapes (v5e). Two keep the device fed, one running
+#: while the next waits.
+FUSED_IN_FLIGHT = 2
 
 
 def pallas_auto(count_dtype: np.dtype, backend: str, top_k: int = 1) -> bool:
@@ -131,11 +149,14 @@ def resolve_fused_flag(fused_window: str) -> bool:
 
     ``auto`` is the on-chip gate: the fused one-dispatch window only
     engages on a real TPU, where per-window dispatch count and uplink
-    bytes are wall-clock. Off-TPU the expansion kernel would run
+    bytes are wall-clock. Off-TPU the score kernel would run
     interpreted — a debug path, not a fast path — so a CPU run stays on
     the chained scatter+score pipeline ('on' still forces it for parity
-    tests). Default 'off' until a benchmark cell measures a win on the
-    chip.
+    tests). The benchmark's basket cell (``instacart-baskets.replay``,
+    tumbling reservoir windows on the dense backend) runs it 'on': 15%
+    more pairs per second than the chained path on a v5e. The default
+    stays 'off' because the flag also selects the sparse backend's fused
+    window, which no cell has measured a win for.
     """
     if fused_window not in ("auto", "on", "off"):
         raise ValueError(
@@ -146,12 +167,14 @@ def resolve_fused_flag(fused_window: str) -> bool:
 
 
 def score_row_budget(num_items: int, cap: int) -> int:
-    """Rows per score call keeping the [S, I] working set ≲ 1 GB int32.
+    """Rows per chained score call: ``[S, I]`` int32 ≲ 1 GB.
 
-    Larger chunks amortize per-dispatch overhead (each call re-reads
-    ``row_sums`` and re-launches gather+LLR+top_k); the transient
-    [S, I] int32 gather plus [S, I] float32 scores stay well under the
-    16 GB HBM of one chip even at the 1 GB budget.
+    The XLA scorer gathers ``C[rows]`` into an ``[S, I]`` int32 block and
+    scores it into ``[S, I]`` float32, so the budget bounds that working
+    set well under one chip's 16 GB. The Pallas kernel holds no such
+    block (it DMAs each row's 8-row group of ``C`` itself): there the
+    budget only sets the chained path's rows per call, and the fused
+    window scores up to ``FUSED_MAX_ROWS`` in one program.
     """
     budget_rows = max(64, (1 << 28) // max(num_items, 1))
     return min(cap, 1 << (budget_rows.bit_length() - 1))
@@ -365,48 +388,82 @@ _score = functools.partial(jax.jit, static_argnames=("top_k", "packed"))(
     _score_body)
 
 
-def _fused_apply_baskets(C, row_sums, block, num_items: int,
-                         basket_width: int, interpret: bool):
-    """Expansion + scatter half of the fused window program.
+#: Pairs (forward lanes) one step of a fused window's scatter takes. A
+#: padding lane costs the scatter as much as a live one (about 113 ns
+#: each on a v5e at the Instacart catalog), so the window's live pairs
+#: are packed and scattered in as many steps as they fill: at most one
+#: step's worth of padding a window, and a small scatter working set.
+SCATTER_STEP = 1 << 14
 
-    ``block`` is the single packed ``[N, W + 4]`` int32 uplink: the
-    basket rectangle plus the (new, len, skip, sign) meta columns. The
-    expansion runs in the Pallas kernel
-    (``pallas_score.pallas_expand_baskets``); the scatter-add stays an
-    XLA op inside the same program — Mosaic cannot scatter-add to
-    arbitrary HBM rows (the score kernel only reads them, a DMA of each
-    row's 8-row group). Invalid/padded lanes carry (0, 0, 0): the
-    scatter no-op triple, so no masking is needed here.
+
+def _basket_lanes(block, step, n_pairs, num_items: int, basket_width: int):
+    """Step ``step``'s ``SCATTER_STEP`` pairs of a window's basket
+    uplink, as COO lanes, forward then mirrored.
+
+    ``block`` is the packed ``[N, W + 4]`` int32 uplink: the basket
+    rectangle plus the (new, len, skip, sign) meta columns; op ``r``
+    emits the pairs ``new[r] <-> basket[r, c]`` for ``c < len[r]``,
+    ``c != skip[r]``, each with delta ``sign[r]``. Pair ``k`` of the
+    window is the ``k``-th such cell in op order. Lanes past the
+    window's ``n_pairs`` are sent past the last row, where the scatter
+    drops them.
     """
-    from .pallas_score import pallas_expand_baskets
-
     w = basket_width
-    basket = block[:, :w]
-    new = block[:, w:w + 1]
-    lens = block[:, w + 1:w + 2]
-    skips = block[:, w + 2:w + 3]
-    signs = block[:, w + 3:w + 4]
-    src, dst, delta = pallas_expand_baskets(basket, new, lens, skips, signs,
-                                            interpret=interpret)
-    return _apply_coo(C, row_sums, src.reshape(-1), dst.reshape(-1),
-                      delta.reshape(-1), num_items)
+    lens, skips = block[:, w + 1], block[:, w + 2]
+    per_op = lens - ((skips >= 0) & (skips < lens)).astype(jnp.int32)
+    starts = jnp.cumsum(per_op) - per_op
+    meta = jnp.stack([starts, skips, block[:, w + 3], block[:, w]], axis=1)
+    base = step * SCATTER_STEP
+    k = base + jnp.arange(SCATTER_STEP, dtype=jnp.int32)
+    # Each pair's op: the ops that start before the step, plus one mark
+    # at every op's first pair inside it, summed up.
+    at = starts - base
+    marks = jnp.zeros((SCATTER_STEP,), jnp.int32).at[
+        jnp.where(at >= 0, at, SCATTER_STEP)].add(1, mode="drop")
+    op = jnp.sum(at < 0, dtype=jnp.int32) + jnp.cumsum(marks) - 1
+    first, skip, sign, new = meta[op].T
+    col = k - first
+    col = col + ((skip >= 0) & (col >= skip)).astype(jnp.int32)
+    partner = block[op, jnp.minimum(col, w - 1)]
+    live = k < n_pairs
+    src = jnp.concatenate([jnp.where(live, new, num_items),
+                           jnp.where(live, partner, num_items)])
+    return src, jnp.concatenate([partner, new]), jnp.concatenate([sign,
+                                                                  sign])
 
 
-def _fused_score_packed(C, row_sums, rows, observed, top_k: int,
+def _fused_apply_baskets(C, row_sums, block, n_pairs, num_items: int,
+                         basket_width: int):
+    """Expansion + scatter half of the fused window program: the
+    window's ``n_pairs`` pairs, expanded from the basket uplink on the
+    device (``_basket_lanes``) and scatter-added into ``C`` and the row
+    sums ``SCATTER_STEP`` pairs at a time, for as many steps as they
+    fill (a loop with a traced trip count: one program serves every pair
+    count the rectangle can hold)."""
+
+    def step(t, state):
+        src, dst, delta = _basket_lanes(block, t, n_pairs, num_items,
+                                        basket_width)
+        return _apply_coo(*state, src, dst, delta, num_items)
+
+    steps = (n_pairs + SCATTER_STEP - 1) // SCATTER_STEP
+    return jax.lax.fori_loop(0, steps, step, (C, row_sums))
+
+
+def _fused_score_packed(C, row_sums, rows, live, observed, top_k: int,
                         use_pallas: bool, tile: int, interpret: bool):
     """Score half of the fused program: the SAME math as the chained
     path — ``_score_body`` when the Pallas score kernel is off, the
     shared ``pallas_score.dense_topk`` core when it is on — so fused and
-    chained results are bitwise equal, not just close."""
+    chained results are bitwise equal, not just close. With the kernel,
+    the blocks of rows past the first ``live`` are skipped."""
     if not use_pallas:
         return _score_body(C, row_sums, rows, observed, top_k, packed=True)
     from .pallas_score import dense_topk
 
-    # The caller pads rows to a pow4 bucket (a row-block multiple). The
-    # kernel's custom call is unscoped: it keeps the enclosing program's
-    # name (see pallas_score_topk).
+    # The caller pads rows to a pow4 bucket (a row-block multiple).
     vals, idx = dense_topk(C, rows, row_sums, observed, top_k=top_k,
-                           tile=tile, interpret=interpret)
+                           tile=tile, interpret=interpret, live=live)
     # Value-space id packing, exactly like pallas_score_topk(packed=True).
     return jnp.stack([vals[:, :top_k], idx[:, :top_k]])
 
@@ -414,17 +471,17 @@ def _fused_score_packed(C, row_sums, rows, observed, top_k: int,
 @functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1),
                    static_argnames=("num_items", "basket_width", "top_k",
                                     "use_pallas", "tile", "interpret"))
-def _fused_window_emit(C, row_sums, block, rows, observed, *, num_items: int,
-                       basket_width: int, top_k: int, use_pallas: bool,
-                       tile: int, interpret: bool):
+def _fused_window_emit(C, row_sums, block, n_pairs, rows, live, observed, *,
+                       num_items: int, basket_width: int, top_k: int,
+                       use_pallas: bool, tile: int, interpret: bool):
     """ONE-dispatch fused window (streaming-results form): on-chip
     basket expansion + count scatter + row-sum maintenance + LLR rescore
     + per-row top-K, one XLA program per (ops-bucket, basket-bucket,
     rows-bucket) shape triple. Replaces the chained path's separate
     update and score dispatches and its 3x-wider COO uplink."""
-    C, row_sums = _fused_apply_baskets(C, row_sums, block, num_items,
-                                       basket_width, interpret)
-    packed = _fused_score_packed(C, row_sums, rows, observed, top_k,
+    C, row_sums = _fused_apply_baskets(C, row_sums, block, n_pairs,
+                                       num_items, basket_width)
+    packed = _fused_score_packed(C, row_sums, rows, live, observed, top_k,
                                  use_pallas, tile, interpret)
     return C, row_sums, packed
 
@@ -432,20 +489,23 @@ def _fused_window_emit(C, row_sums, block, rows, observed, *, num_items: int,
 @functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1, 2),
                    static_argnames=("num_items", "basket_width", "top_k",
                                     "use_pallas", "tile", "interpret"))
-def _fused_window_defer(C, row_sums, tbl, block, rows, scatter_rows,
-                        observed, *, num_items: int, basket_width: int,
-                        top_k: int, use_pallas: bool, tile: int,
-                        interpret: bool):
+def _fused_window_defer(C, row_sums, tbl, block, n_pairs, rows, live,
+                        scatter_rows, observed, *, num_items: int,
+                        basket_width: int, top_k: int, use_pallas: bool,
+                        tile: int, interpret: bool):
     """Deferred-results form of :func:`_fused_window_emit`: the packed
     top-K scatters into the device-resident results table inside the
     same program — a steady-state window is literally one dispatch and
     zero result downlink. Padded score rows carry the ``_SENT_ROW``
-    sentinel and drop out of the scatter."""
-    C, row_sums = _fused_apply_baskets(C, row_sums, block, num_items,
-                                       basket_width, interpret)
-    packed = _fused_score_packed(C, row_sums, rows, observed, top_k,
+    sentinel and drop out of the scatter. The last output is a scalar
+    the host can wait on for the window (every other output is donated
+    to the next dispatch)."""
+    C, row_sums = _fused_apply_baskets(C, row_sums, block, n_pairs,
+                                       num_items, basket_width)
+    packed = _fused_score_packed(C, row_sums, rows, live, observed, top_k,
                                  use_pallas, tile, interpret)
-    return C, row_sums, tbl.at[:, scatter_rows].set(packed, mode="drop")
+    return (C, row_sums, tbl.at[:, scatter_rows].set(packed, mode="drop"),
+            row_sums[0])
 
 
 def check_coo_chunk(coo: np.ndarray, n: int) -> None:
@@ -616,9 +676,12 @@ class DeviceScorer:
         # The job enables basket emission iff this resolved True.
         self.use_fused = resolve_fused_flag(fused_window)
         # Basket uplinks are the DENSE fused path's wire format (the
-        # kernel expands them on chip); the sparse fused path consumes
-        # aggregated deltas instead and leaves this False.
+        # device expands them, _basket_lanes); the sparse fused path
+        # consumes aggregated deltas instead and leaves this False.
         self.wants_baskets = self.use_fused
+        # One scalar per deferred fused window still on the device, oldest
+        # first (FUSED_IN_FLIGHT bounds them).
+        self._fused_queue: collections.deque = collections.deque()
         # Which path the LAST process_window dispatch took — the
         # journal's ``fused`` field and /healthz read it.
         self.last_dispatch_fused = False
@@ -712,6 +775,7 @@ class DeviceScorer:
                 routed = self._try_fused(ts, pairs)
                 if routed is not None:
                     return routed
+                clk.add("fused_windows", 0)
             # Not fused-routable (oversized window / kernel limit) or
             # fused resolved off: expand host-side and run the chained
             # path — the same pair multiset, so results are identical.
@@ -847,11 +911,12 @@ class DeviceScorer:
         caller then expands host-side and takes the chained path, which
         produces identical results (same pair multiset, same score
         math). Not routable: zero-pair windows (the chained empty-window
-        contract applies), windows whose padded expansion lanes exceed
-        the ``max_pairs_per_step`` chunk budget, rescore sets beyond one
-        score chunk, and configurations the Pallas score kernel itself
-        rejects on the chained path (vocab > 2^24, K > lane width) —
-        the chained path raises the canonical error for those.
+        contract applies), windows whose basket rectangle's lanes exceed
+        ``FUSED_MAX_LANES``, rescore sets beyond ``FUSED_MAX_ROWS`` (one
+        score chunk without the Pallas kernel, whose ``[S, I]`` gather
+        the chunk bounds), and configurations the Pallas score kernel
+        itself rejects on the chained path (vocab > 2^24, K > lane
+        width) — the chained path raises the canonical error for those.
         """
         per_op = b.pairs_per_op()
         n_pairs = int(per_op.sum())
@@ -871,10 +936,9 @@ class DeviceScorer:
             n_ops = b.n_ops
             n_cap = pad_pow2(n_ops, minimum=64)
             l_cap = pad_pow2(max(int(b.baskets.shape[1]), 1), minimum=128)
-            if 2 * n_cap * l_cap > self.max_pairs_per_step:
-                # The expanded lanes would exceed the chained path's COO
-                # chunk budget (HBM working-set bound): oversized windows
-                # stay chained, where chunking already handles them.
+            if 2 * n_cap * l_cap > FUSED_MAX_LANES:
+                # The uplinked rectangle would pass its bound: oversized
+                # windows stay chained, where the COO upload is chunked.
                 return None
             # Rescore set: every item touched by an emitted pair — the
             # union of active star items and valid basket cells, exactly
@@ -882,14 +946,16 @@ class DeviceScorer:
             rows = np.unique(np.concatenate([
                 b.new_items[active].astype(np.int64),
                 b.baskets[valid].astype(np.int64)])).astype(np.int32)
-            if len(rows) > self.max_score_rows:
+            if len(rows) > (FUSED_MAX_ROWS if self.use_pallas
+                            else self.max_score_rows):
                 return None
 
         with clk.stage("uplink-encode"):
             # Single packed uplink: basket rectangle + 4 meta columns. Pad
             # ops carry (len 0, sign 0) — zero expanded lanes. Basket cells
-            # beyond each op's len ride up unspecified and are masked
-            # in-kernel, same contract as the sampler's storage.
+            # beyond each op's len ride up unspecified and are never read
+            # (the expansion reads each op's first len cells), same
+            # contract as the sampler's storage.
             blockbuf = np.zeros((n_cap, l_cap + 4), dtype=np.int32)
             w = b.baskets.shape[1]
             if w:
@@ -911,11 +977,25 @@ class DeviceScorer:
         self._fused_dispatches.add(1)
 
         s = len(rows)
-        pad_s = min(pad_pow4(s, minimum=64), self.max_score_rows)
+        # One pow4 bucket of rows per program. The Pallas kernel skips
+        # the blocks past the live rows, so it scores (and counts) only
+        # those; the XLA scorer scores every padded row.
+        pad_s = pad_pow4(s, minimum=64)
+        if not self.use_pallas:
+            pad_s = min(pad_s, self.max_score_rows)
         rows_padded = np.zeros(pad_s, dtype=np.int32)
         rows_padded[:s] = rows
-        self._count_scored(s, rows_padded)
+        from .pallas_score import BLOCK_ROWS
+
+        scored = (-(-s // BLOCK_ROWS) * BLOCK_ROWS if self.use_pallas
+                  else pad_s)
+        self._count_scored(s, rows_padded[:scored])
+        clk.add("fused_windows")
+        clk.add("expand_lanes",
+                2 * SCATTER_STEP * -(-n_pairs // SCATTER_STEP))
+        clk.add("expand_live", 2 * n_pairs)
         observed = np.float32(self.observed)
+        live, pairs = np.int32(s), np.int32(n_pairs)
         if self.defer_results:
             self.stage_clock.add("launches", self._results.ensure())
             # Padded entries gather row 0 but must NOT scatter there.
@@ -923,17 +1003,22 @@ class DeviceScorer:
             scatter_rows[:s] = rows
             LEDGER.up_basket("fused-window", blockbuf, rows_padded,
                              scatter_rows)
-            self.C, self.row_sums, self._results.tbl = _fused_window_defer(
-                self.C, self.row_sums, self._results.tbl, blockbuf,
-                rows_padded, scatter_rows, observed,
+            while len(self._fused_queue) >= FUSED_IN_FLIGHT:
+                self._fused_queue.popleft().block_until_ready()
+            (self.C, self.row_sums, self._results.tbl,
+             done) = _fused_window_defer(
+                self.C, self.row_sums, self._results.tbl, blockbuf, pairs,
+                rows_padded, live, scatter_rows, observed,
                 num_items=self.num_items, basket_width=l_cap,
                 top_k=self.top_k, use_pallas=self.use_pallas,
                 tile=self.PALLAS_TILE, interpret=self._pallas_interpret)
+            self._fused_queue.append(done)
             self._results.mark(rows)
             return TopKBatch.empty(self.top_k)
         LEDGER.up_basket("fused-window", blockbuf, rows_padded)
         self.C, self.row_sums, packed = _fused_window_emit(
-            self.C, self.row_sums, blockbuf, rows_padded, observed,
+            self.C, self.row_sums, blockbuf, pairs, rows_padded, live,
+            observed,
             num_items=self.num_items, basket_width=l_cap,
             top_k=self.top_k, use_pallas=self.use_pallas,
             tile=self.PALLAS_TILE, interpret=self._pallas_interpret)

@@ -28,7 +28,8 @@ and of issuing the fetches (measured on a v5e against 16, 32 and 128).
 The running top-K lives in VMEM scratch that persists across the
 column-tile dimension (sequential grid execution, innermost-last
 order), initialized at ``j == 0`` and written to the output block at
-the last tile.
+the last tile. A block the caller marks as padding (``live``) plans no
+groups: it fetches and scores nothing.
 
 Tie-breaking matches ``lax.top_k`` (lowest column index among equal scores):
 within a tile the extraction picks the minimum position, and the running
@@ -127,6 +128,26 @@ def _score_topk_kernel(slots_ref, firsts_ref, n_ref, c_hbm, rsj_ref,
     def _(g):
         copy(i, j, buf, g).wait()
 
+    # A block with no groups is padding past the caller's live rows: it
+    # fetched nothing and scores nothing (its output stays -inf).
+    @pl.when(n_ref[i] > 0)
+    def _score():
+        _score_block(slots_ref, rsj_ref, rsi_ref, obs_ref, groups, buf,
+                     counts_ref, run_vals, run_idx, i=i, j=j,
+                     top_k=top_k, tile=tile)
+
+    @pl.when(j == n_j - 1)
+    def _emit():
+        vals_ref[...] = run_vals[...]
+        idx_ref[...] = run_idx[...]
+
+
+def _score_block(slots_ref, rsj_ref, rsi_ref, obs_ref, groups, buf,
+                 counts_ref, run_vals, run_idx, *, i, j, top_k, tile):
+    """Row block ``i``'s column tile ``j``: load its rows from the
+    groups fetched into buffer ``buf``, score them and fold the tile
+    into the running top-K."""
+    R = BLOCK_ROWS
     # Unrolled, so the scalar work uses lax ops: each jnp operator traces
     # a jitted wrapper of its own.
     base = i * R
@@ -192,11 +213,6 @@ def _score_topk_kernel(slots_ref, firsts_ref, n_ref, c_hbm, rsj_ref,
         run_vals[...] = new_vals
         run_idx[...] = new_idx
 
-    @pl.when(j == n_j - 1)
-    def _emit():
-        vals_ref[...] = run_vals[...]
-        idx_ref[...] = run_idx[...]
-
 
 def _fetch_plan(local):
     """The DMAs of each row block, from its ``[Sp]`` local row ids
@@ -219,7 +235,7 @@ def _fetch_plan(local):
 
 
 def dense_topk(C, rows, row_sums, observed, *, top_k: int, tile: int,
-               interpret: bool, lo=0):
+               interpret: bool, lo=0, live=None):
     """THE dense scoring core: fused LLR + top-K of ``C``'s rows
     ``rows - lo``, fetched by the kernel from ``C`` in HBM.
 
@@ -228,6 +244,10 @@ def dense_topk(C, rows, row_sums, observed, *, top_k: int, tile: int,
              I % tile == 0)
     row_sums [I] int32 — global row sums
     rows     [Sp] int32 — global row ids, Sp % BLOCK_ROWS == 0
+    live     int32 scalar or None — rows past the first ``live`` are
+             padding: their whole blocks fetch and score nothing and
+             return (-inf, 0), so one program serves every row count up
+             to ``Sp`` at the cost of the live blocks only
     Returns (vals [Sp, _K_PAD] f32, idx [Sp, _K_PAD] f32 — ids as exact
     float values). The row ids ride in scalar memory; each grid step
     DMAs the 8-row groups its block needs for the next column tile while
@@ -256,47 +276,53 @@ def dense_topk(C, rows, row_sums, observed, *, top_k: int, tile: int,
     blk = BLOCK_ROWS
     sp = rows.shape[0]
     # Device-side stage name of the row-sum lookup and the fetch plan (op
-    # metadata in a profiler trace). The kernel stays outside any scope:
-    # a Pallas custom call takes the innermost scope's name, and the
-    # trace's readers match it as ``pallas_score_topk``.
+    # metadata in a profiler trace).
     with jax.named_scope("gather"):
         rsi = row_sums[rows].reshape(sp, 1)
         slots, firsts, n_groups = _fetch_plan(
             jnp.clip(rows - lo, 0, n_rows - 1))
+        if live is not None:
+            n_groups = jnp.where(
+                jnp.arange(sp // blk, dtype=jnp.int32) * blk < live,
+                n_groups, 0)
     obs = jnp.full((1, 1), observed, dtype=jnp.float32)
     kernel = functools.partial(_score_topk_kernel, top_k=top_k, tile=tile)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(sp // blk, num_items // tile),
-            in_specs=[
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec((1, tile), lambda i, j, *_: (0, j)),
-                pl.BlockSpec((blk, 1), lambda i, j, *_: (i, 0)),
-                pl.BlockSpec((1, 1), lambda i, j, *_: (0, 0)),
-            ],
-            out_specs=(
-                pl.BlockSpec((blk, _K_PAD), lambda i, j, *_: (i, 0)),
-                pl.BlockSpec((blk, _K_PAD), lambda i, j, *_: (i, 0)),
+    # A Pallas custom call takes the innermost scope's name: the trace's
+    # readers match it as ``pallas_score_topk`` in every program.
+    with jax.named_scope("pallas_score_topk"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3,
+                grid=(sp // blk, num_items // tile),
+                in_specs=[
+                    pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec((1, tile), lambda i, j, *_: (0, j)),
+                    pl.BlockSpec((blk, 1), lambda i, j, *_: (i, 0)),
+                    pl.BlockSpec((1, 1), lambda i, j, *_: (0, 0)),
+                ],
+                out_specs=(
+                    pl.BlockSpec((blk, _K_PAD), lambda i, j, *_: (i, 0)),
+                    pl.BlockSpec((blk, _K_PAD), lambda i, j, *_: (i, 0)),
+                ),
+                scratch_shapes=[
+                    pltpu.VMEM((2, blk, _GROUP, tile), C.dtype),
+                    pltpu.SemaphoreType.DMA((2, blk)),
+                    pltpu.VMEM((blk, tile), jnp.float32),
+                    pltpu.VMEM((blk, _K_PAD), jnp.float32),
+                    pltpu.VMEM((blk, _K_PAD), jnp.float32),
+                ],
             ),
-            scratch_shapes=[
-                pltpu.VMEM((2, blk, _GROUP, tile), C.dtype),
-                pltpu.SemaphoreType.DMA((2, blk)),
-                pltpu.VMEM((blk, tile), jnp.float32),
-                pltpu.VMEM((blk, _K_PAD), jnp.float32),
-                pltpu.VMEM((blk, _K_PAD), jnp.float32),
-            ],
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((sp, _K_PAD), jnp.float32),
-            jax.ShapeDtypeStruct((sp, _K_PAD), jnp.float32),
-        ),
-        # Sequential grid: a step waits on DMAs the step before started.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-        interpret=interpret,
-    )(slots, firsts, n_groups, C, row_sums.reshape(1, num_items), rsi, obs)
+            out_shape=(
+                jax.ShapeDtypeStruct((sp, _K_PAD), jnp.float32),
+                jax.ShapeDtypeStruct((sp, _K_PAD), jnp.float32),
+            ),
+            # Sequential grid: a step waits on DMAs the step before started.
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+        )(slots, firsts, n_groups, C, row_sums.reshape(1, num_items), rsi,
+          obs)
 
 
 def pallas_score_topk_local(C_loc, row_sums, rows_global, lo, observed, *,
@@ -549,94 +575,6 @@ def pallas_score_rect(cnt, dst, row_sums, meta, observed, *, top_k: int,
 
     ids = idxf[:S, :top_k].astype(jnp.int32)
     return jnp.stack([vals[:S, :top_k], pack_ids(ids)])
-
-
-def _expand_kernel(basket_ref, new_ref, len_ref, skip_ref, sign_ref,
-                   src_ref, dst_ref, delta_ref, *, width, block):
-    """On-chip basket expansion: one star op per row.
-
-    Row ``r`` expands op ``(new, basket[:len], skip, sign)`` into the
-    ``2 * width`` COO lanes ``[new -> basket[j] | j] ++ [basket[j] ->
-    new | j]`` with ``delta = sign`` on the valid lanes (``j < len``,
-    ``j != skip``) and the padded ``(0, 0, 0)`` no-op triple everywhere
-    else — the same pad-slot invariant the chained COO upload carries
-    (``device_scorer.process_window``), so the scatter that consumes
-    these lanes needs no masking. Pure VPU selects over a column iota;
-    no cross-lane traffic.
-    """
-    R = block
-    basket = basket_ref[...]                            # [R, W] int32
-    new = new_ref[...]                                  # [R, 1] int32
-    lens = len_ref[...]                                 # [R, 1] int32
-    skip = skip_ref[...]                                # [R, 1] int32
-    sign = sign_ref[...]                                # [R, 1] int32
-    j = jax.lax.broadcasted_iota(jnp.int32, (R, width), dimension=1)
-    valid = (j < lens) & (j != skip)
-    zero = jnp.zeros((R, width), dtype=jnp.int32)
-    fwd_src = jnp.where(valid, new + zero, zero)
-    fwd_dst = jnp.where(valid, basket, zero)
-    d = jnp.where(valid, sign + zero, zero)
-    src_ref[...] = jnp.concatenate([fwd_src, fwd_dst], axis=1)
-    dst_ref[...] = jnp.concatenate([fwd_dst, fwd_src], axis=1)
-    delta_ref[...] = jnp.concatenate([d, d], axis=1)
-
-
-#: Ops-axis block of the expansion kernel (int32 sublane tile).
-_EXPAND_BLOCK = 8
-
-
-def pallas_expand_baskets(basket, new, lens, skips, signs, *,
-                          interpret: bool = False):
-    """Expand a padded basket tensor into COO pair-delta lanes on chip.
-
-    The device half of the fused window dispatch
-    (``device_scorer._fused_window_emit``/``_defer``): takes the padded
-    per-op basket rectangle the host uplinked and produces the
-    ``(src, dst, delta)`` lanes the count scatter consumes, replacing
-    the host-side ``native/reservoir_expand.cpp`` expansion plus the
-    3x-wider COO uplink.
-
-    basket [N, W] int32 — partner rows (cells at ``j >= len`` are
-                          UNSPECIFIED, masked in-kernel; ``W % 128 == 0``)
-    new/lens/skips/signs [N, 1] int32 — star item, valid-cell count,
-                          excluded column (-1 = none), delta sign
-                          (padded ops: len 0, sign 0)
-    Returns ``(src, dst, delta)`` each [N, 2W] int32; invalid lanes
-    carry the (0, 0, 0) scatter no-op triple.
-    """
-    n, width = basket.shape
-    if n % _EXPAND_BLOCK:
-        raise ValueError(
-            f"op count {n} must be a multiple of {_EXPAND_BLOCK} "
-            f"(pad the ops axis)")
-    if width % 128:
-        raise ValueError(
-            f"basket width {width} must be a multiple of 128 lanes")
-    kernel = functools.partial(_expand_kernel, width=width,
-                               block=_EXPAND_BLOCK)
-    blk = _EXPAND_BLOCK
-    return pl.pallas_call(
-        kernel,
-        grid=(n // blk,),
-        in_specs=[
-            pl.BlockSpec((blk, width), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((blk, 2 * width), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 2 * width), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 2 * width), lambda i: (i, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((n, 2 * width), jnp.int32),
-            jax.ShapeDtypeStruct((n, 2 * width), jnp.int32),
-            jax.ShapeDtypeStruct((n, 2 * width), jnp.int32),
-        ),
-        interpret=interpret,
-    )(basket, new, lens, skips, signs)
 
 
 @functools.partial(jax.jit,

@@ -71,7 +71,7 @@ class BasketBatch:
     ``ops/device_scorer``): each row is one expansion op — a new/star
     item against a basket of partner items — and the device performs
     the expansion into COO deltas on chip
-    (``ops/pallas_score.pallas_expand_baskets``). One append event is
+    (``ops/device_scorer._basket_lanes``). One append event is
     one op (basket = the user's history prefix, ``skip = -1``); one
     replacement is two ops over the same pre-write reservoir row
     (``(+1, new item)`` and ``(-1, previous item)``, both with
