@@ -113,7 +113,10 @@ def log1p_f32(x):
     chip. Here: ``|x| < 0.25`` uses ``2 atanh(x / (2 + x))`` directly on
     ``x`` (no rounding of ``1 + x``); otherwise ``1 + x = m * 2^e`` with
     ``m`` in ``[sqrt(1/2), sqrt(2))`` and ``log = e ln2 + 2 atanh((m-1)/
-    (m+1))``. Same code on every backend and inside Pallas kernels.
+    (m+1))``. The branch selects the quotient's numerator and denominator,
+    so each element runs one division and one series (the LLR calls this
+    four times a cell; see PERF.md §5). Same code on every backend and
+    inside Pallas kernels.
     """
     from jax import lax
 
@@ -125,10 +128,11 @@ def log1p_f32(x):
     high = m > 1.4142135
     m = jnp.where(high, m * 0.5, m)
     ef = jnp.where(high, e + 1, e).astype(jnp.float32)
-    log_u = ef * _LN2_HI + (_two_atanh((m - 1.0) / (m + 1.0))
-                            + ef * _LN2_LO)
-    log_u = jnp.where(u > 0, log_u, -jnp.inf)
-    return jnp.where(jnp.abs(x) < 0.25, _two_atanh(x / (2.0 + x)), log_u)
+    small = jnp.abs(x) < 0.25
+    s = _two_atanh(jnp.where(small, x, m - 1.0)
+                   / jnp.where(small, 2.0 + x, m + 1.0))
+    log_u = jnp.where(u > 0, ef * _LN2_HI + (s + ef * _LN2_LO), -jnp.inf)
+    return jnp.where(small, s, log_u)
 
 
 def _split(a):

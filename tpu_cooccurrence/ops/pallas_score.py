@@ -168,11 +168,13 @@ def _score_block(slots_ref, rsj_ref, rsi_ref, obs_ref, groups, buf,
     scores = llr_stable(k11, k12, k21, k22)
     scores = jnp.where(k11 != 0, scores, -jnp.inf)          # [R, TILE]
 
-    # Threshold skip: the merge below costs more VPU work than the LLR
-    # itself (top_k sequential extractions over the candidate width). A
-    # tile only needs it if some row's tile-max beats that row's running
-    # K-th best; after the first few column tiles most tiles lose and the
-    # whole merge is skipped, leaving the kernel LLR-bound.
+    # Threshold skip. The LLR above is the larger per-cell cost: about
+    # 326 traced ops and 8 divisions a cell, against about 80 for the
+    # merge below (top_k extractions of about 8 ops over the
+    # _K_PAD + tile candidates) and about 5 to load a row (PERF.md §5).
+    # A tile needs the merge only if some row's tile-max beats that row's
+    # running K-th best, but `need_merge` is an `any` over the block's 64
+    # rows, so the skip rarely fires.
     thresh = run_vals[:, top_k - 1:top_k]                   # [R, 1]
     tile_max = jnp.max(scores, axis=1, keepdims=True)       # [R, 1]
     need_merge = jnp.any(tile_max > thresh)
