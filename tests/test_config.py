@@ -76,9 +76,9 @@ def test_score_ladder_and_fixed_score_flags():
                             "--score-ladder", "16", "--fixed-score", "on"])
     assert cfg.score_ladder == 16
     assert cfg.fixed_score == "on"
-    # Defaults: ladder deferred to the scorer (env or 4), fixed-score auto.
+    # Defaults: the registry's ladder (4), fixed-score auto.
     cfg2 = Config.from_args(["-i", "x.csv", "-ws", "100"])
-    assert cfg2.score_ladder is None
+    assert cfg2.score_ladder == 4
     assert cfg2.fixed_score == "auto"
 
 

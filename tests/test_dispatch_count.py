@@ -22,7 +22,6 @@ class DispatchCounter:
     """Counting shims around the sparse scorer's jitted entry points."""
 
     TRACKED = ("_apply_update", "_apply_moves_update",
-               "_apply_update_chunked", "_apply_moves_update_chunked",
                "_apply_update_packed", "_apply_moves_update_packed",
                "_score_slab", "_score_into_table",
                "_score_window_into_table", "_grow", "_compact_gather",
@@ -50,8 +49,6 @@ class DispatchCounter:
     def updates(self):
         return (self.counts["_apply_update"]
                 + self.counts["_apply_moves_update"]
-                + self.counts["_apply_update_chunked"]
-                + self.counts["_apply_moves_update_chunked"]
                 + self.counts["_apply_update_packed"]
                 + self.counts["_apply_moves_update_packed"])
 
@@ -85,7 +82,7 @@ def test_fixed_shape_window_is_two_dispatches(monkeypatch):
     # stream (<= 20k distinct cells): steady state means NO growth or
     # compaction dispatches, only the two hot ones.
     scorer = ss.SparseDeviceScorer(
-        top_k=5, defer_results=True, fixed_shapes=True,
+        top_k=5, defer_results=True, fixed_score="on",
         capacity=1 << 18, items_capacity=1 << 10)
     rng = np.random.default_rng(42)
     vocab = 300
@@ -119,7 +116,7 @@ def test_fixed_shape_plan_is_monotone_and_bounded(monkeypatch):
     count — at most one program per (bucket, chunk-rank) ever occupied."""
     counter = DispatchCounter(monkeypatch)
     scorer = ss.SparseDeviceScorer(
-        top_k=5, defer_results=True, fixed_shapes=True,
+        top_k=5, defer_results=True, fixed_score="on",
         capacity=1 << 15, items_capacity=1 << 10)
     rng = np.random.default_rng(7)
 
@@ -155,7 +152,7 @@ def test_variable_mode_defer_still_one_update(monkeypatch):
     per window; scoring is one fused dispatch per occupied (bucket, chunk)."""
     counter = DispatchCounter(monkeypatch)
     scorer = ss.SparseDeviceScorer(
-        top_k=5, defer_results=True, fixed_shapes=False,
+        top_k=5, defer_results=True, fixed_score="off",
         capacity=1 << 15, items_capacity=1 << 10)
     rng = np.random.default_rng(3)
     for w in range(5):
@@ -372,7 +369,7 @@ def test_sharded_sparse_program_cache_is_monotone():
 
     mesh = make_mesh(8, devices=jax.devices()[:8])
     scorer = ShardedSparseScorer(5, mesh=mesh, defer_results=True,
-                                 fixed_shapes=True)
+                                 fixed_score="on")
     rng = np.random.default_rng(11)
     sizes = []
     for w in range(12):

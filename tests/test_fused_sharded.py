@@ -11,7 +11,7 @@ resync), and the 2→4 autoscale seam (plans rebuild cold, the first
 post-seam window routes chained, the second re-enters fused with one new
 bucket compilation). Non-routable windows fall back chained per window
 under the reason taxonomy the cooclint ``fused-fallback-registry`` rule
-pins: ``plan-rebuild``, ``relocation``, ``upload-split``, ``promotion``.
+pins: ``plan-rebuild``, ``relocation``, ``promotion``.
 """
 
 import json
@@ -209,7 +209,7 @@ def test_fused_sharded_rescale_seam_rebuilds_plans():
     assert np.array_equal(b_fixed.idx[sel], b_fused.idx)
 
 
-# -- fallback taxonomy: relocation, promotion, upload-split -------------
+# -- fallback taxonomy: relocation, promotion ---------------------------
 
 
 def test_fused_sharded_relocation_falls_back_and_recovers():
@@ -256,18 +256,6 @@ def test_fused_sharded_promotion_falls_back_chained():
     assert "promotion" in reasons, trace
     # Once wide, the hub row keeps the window chained.
     assert trace[3] == (False, "promotion"), trace
-
-
-def test_fused_sharded_upload_split_pins_chained(monkeypatch):
-    """An explicit TPU_COOC_UPLOAD_CHUNKS request is a measurement
-    lever: the chunking A/B must not silently measure the fused program,
-    so every window routes chained (reason ``upload-split``)."""
-    monkeypatch.setenv("TPU_COOC_UPLOAD_CHUNKS", "2")
-    wins = _steady_windows(n_win=3)
-    s = _mk(2, "on")
-    trace = _drive(s, wins)
-    assert trace[0] == (False, "plan-rebuild"), trace
-    assert all(t == (False, "upload-split") for t in trace[1:]), trace
 
 
 # -- observability: gauges, journal, packed-uplink ledger ---------------

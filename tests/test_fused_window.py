@@ -392,8 +392,7 @@ class _FusedCounter:
     """Counting shims around the device scorer's jitted entry points."""
 
     TRACKED = ("_fused_window_emit", "_fused_window_defer", "_update_coo",
-               "_update_coo_u16", "_update_coo_chunked",
-               "_update_coo_u16_chunked", "_score")
+               "_update_coo_u16", "_score")
 
     def __init__(self, monkeypatch):
         self.counts = {name: 0 for name in self.TRACKED}

@@ -185,7 +185,7 @@ def test_sparse_scorer_pallas_end_to_end(mode):
     pairs = _dense_stream()
     out = {}
     for pl in ("on", "off"):
-        kw = (dict(defer_results=True, fixed_shapes=True)
+        kw = (dict(defer_results=True, fixed_score="on")
               if mode == "deferred-fixed" else dict(defer_results=False))
         sc = SparseDeviceScorer(10, use_pallas=pl, **kw)
         sc.process_window(0, pairs)
@@ -211,7 +211,7 @@ def test_sharded_sparse_pallas_matches_xla():
     out = {}
     for pl in ("on", "off"):
         sc = ShardedSparseScorer(10, num_shards=8, defer_results=True,
-                                 fixed_shapes=True, use_pallas=pl)
+                                 fixed_score="on", use_pallas=pl)
         # Small fixed rectangles: interpret-mode pallas across 8 shards
         # is minutes at the default budget, seconds at this one.
         sc.FIXED_BUDGET = 1 << 13

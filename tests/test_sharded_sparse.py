@@ -195,7 +195,7 @@ def test_sharded_sparse_fixed_shapes_matches_variable():
         scorer = ShardedSparseScorer(cfg.top_k, num_shards=8,
                                      development_mode=True,
                                      defer_results=True,
-                                     fixed_shapes=fixed)
+                                     fixed_score="on" if fixed else "off")
         if fixed:
             scorer.FIXED_BUDGET = 1 << 12
             scorer.FIXED_ROW_CAP = 64

@@ -195,11 +195,11 @@ def test_allocate_heap_overflow_guard():
         idx.apply(np.asarray([(3 << 32) | 1], np.int64))
 
 
-def test_make_row_registry_env(monkeypatch):
-    monkeypatch.setenv("TPU_COOC_ROW_INDEX", "dense")
-    assert make_row_registry(64).kind == "dense"
-    monkeypatch.setenv("TPU_COOC_ROW_INDEX", "bitmap")
+def test_make_row_registry_env():
+    """The layout comes from the ``kind`` argument alone (bitmap by
+    default); the environment has no say."""
     assert make_row_registry(64).kind == "bitmap"
-    monkeypatch.setenv("TPU_COOC_ROW_INDEX", "nope")
-    with pytest.raises(ValueError, match="TPU_COOC_ROW_INDEX"):
-        make_row_registry(64)
+    assert make_row_registry(64, "dense").kind == "dense"
+    assert make_row_registry(64, "bitmap").kind == "bitmap"
+    with pytest.raises(ValueError, match="bitmap or dense"):
+        make_row_registry(64, "nope")

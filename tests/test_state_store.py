@@ -16,8 +16,8 @@ The contracts under test:
   reference: any restore rebuilds rows in key order, so THAT is the
   canonical post-restore state).
 * **Pre-codec compatibility** — a PR-7 ``ckpt_codec``-less checkpoint
-  (``--wire-format raw``) restores bit-identically under
-  ``TPU_COOC_ROW_INDEX=bitmap`` and under the tiered store.
+  (``--wire-format raw``) restores bit-identically under the bitmap
+  row index and under the tiered store.
 """
 
 import os
@@ -457,12 +457,10 @@ def test_sharded_rescale_restore_bit_identical(tmp_path, n_from, n_to):
     {},                 # bitmap row index (the default), direct store
     SPILL,              # TieredSlabStore
 ], ids=["bitmap", "tiered"])
-def test_precodec_checkpoint_restores_bit_identical(tmp_path, monkeypatch,
-                                                    resume_kw):
+def test_precodec_checkpoint_restores_bit_identical(tmp_path, resume_kw):
     """A PR-7 pre-codec checkpoint (--wire-format raw writes the
-    ckpt_codec-less layout) restores under TPU_COOC_ROW_INDEX=bitmap and
+    ckpt_codec-less layout) restores under the bitmap row index and
     under the TieredSlabStore, resuming bit-identically either way."""
-    monkeypatch.setenv("TPU_COOC_ROW_INDEX", "bitmap")
     users, items, ts = random_stream(84, n=700, n_items=60, n_users=25)
     half = 330
 

@@ -69,7 +69,7 @@ def test_deferred_sparse_steady_window_uplink_only():
     """Steady state (no new cells, no moves, no plan growth): exactly one
     update upload + one meta upload, ZERO downlink."""
     pairs = _pairs()
-    sc = SparseDeviceScorer(5, defer_results=True, fixed_shapes=True)
+    sc = SparseDeviceScorer(5, defer_results=True, fixed_score="on")
     sc.process_window(0, pairs)  # warmup: allocs, moves, plan discovery
 
     LEDGER.reset()
@@ -84,7 +84,7 @@ def test_deferred_sparse_steady_window_uplink_only():
 
 def test_deferred_flush_fetches_dirty_rows_only():
     pairs = _pairs()
-    sc = SparseDeviceScorer(5, defer_results=True, fixed_shapes=True)
+    sc = SparseDeviceScorer(5, defer_results=True, fixed_score="on")
     sc.process_window(0, pairs)
     n_dirty = int(sc._results.dirty.sum())
     assert n_dirty > 0
@@ -106,7 +106,7 @@ def test_deferred_flush_fetches_dirty_rows_only():
 
 
 def test_deferred_idle_window_moves_nothing():
-    sc = SparseDeviceScorer(5, defer_results=True, fixed_shapes=True)
+    sc = SparseDeviceScorer(5, defer_results=True, fixed_score="on")
     sc.process_window(0, _pairs())
     LEDGER.reset()
     sc.process_window(10, PairDeltaBatch(np.zeros(0, np.int64),

@@ -136,10 +136,6 @@ def sparse_device_mocked():
           lambda cnt, dst, rs, upd, bounds: (cnt, dst, rs))
     patch("_apply_moves_update",
           lambda cnt, dst, rs, mv, upd, bounds, L: (cnt, dst, rs))
-    patch("_apply_update_chunked",
-          lambda cnt, dst, rs, parts, bounds: (cnt, dst, rs))
-    patch("_apply_moves_update_chunked",
-          lambda cnt, dst, rs, mv, parts, bounds, L: (cnt, dst, rs))
     patch("_score_into_table", lambda tbl, *a, **k: tbl)
     patch("_score_window_into_table", lambda tbl, *a, **k: tbl)
     patch("_compact_gather", lambda cnt, dst, gmap, cap: (cnt, dst))

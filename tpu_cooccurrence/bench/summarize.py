@@ -150,9 +150,8 @@ def render() -> str:
                     f"both at full size before deciding")
             else:
                 winner = (
-                    "chunked upload WINS — default "
-                    "TPU_COOC_UPLOAD_CHUNK_KB=256 on TPU "
-                    "(ops/device_scorer.upload_chunk_kb)"
+                    "chunked upload WINS — the chunked path is "
+                    "retired; re-measure it as a benchmark cell"
                     if c > h * 1.05 else
                     "monolithic upload holds (keep default)")
                 lines.append(

@@ -5,15 +5,11 @@ JSON lines to ``TPU_ROUND2.jsonl`` at the repo root (one object per
 measurement, with failures recorded rather than aborting the pass). The
 first ``benchmark`` PR replaces it with the cell table (ROADMAP A0/D1):
 
-1. config4-headline — the 1M-item Zipfian north star in ONE number
-                      (single L16/fixed run; target: >=458k pairs/s =
-                      20x the measured 22.9k host-oracle baseline,
-                      BASELINE.md). config4-sparse is the 4-mode sweep.
-2. ml25m-sparse / ml25m-full — the two config-3 carrier candidates,
+1. ml25m-sparse / ml25m-full — the two config-3 carrier candidates,
                       25M events + v5e-8 projection (bench/ml25m.py).
-3. sparse-pallas / sharded-pallas-1chip / pallas-bench — kernel-vs-XLA
+2. sparse-pallas / sharded-pallas-1chip / pallas-bench — kernel-vs-XLA
                       A/Bs with on-hardware parity checks.
-4. configs          — the five BASELINE.md benchmark configs.
+3. configs          — the five BASELINE.md benchmark configs.
 
 Each measurement can run alone via ``--only NAME``.
 
@@ -27,14 +23,11 @@ Usage (on a TPU-attached interpreter — no JAX_PLATFORMS override):
 from __future__ import annotations
 
 import argparse
-import contextlib
 import json
 import os
 import sys
 import time
 import traceback
-
-from tpu_cooccurrence import tuning
 
 #: TPU_ROUND2_OUT overrides the artifact path — for CPU smoke tests of
 #: the measurement machinery (which must not pollute the tracked JSONL
@@ -119,122 +112,10 @@ def config5_sparse(quick: bool) -> dict:
         # Quick mode exists to sanity-check the chip cheaply; the
         # Instacart shape takes minutes (same rule as all_configs).
         return {"skipped": "config 5 takes minutes; run without --quick"}
-    # Single measured run (chip time is the scarce resource): unlike
-    # config4's per-ladder warmups this shape runs minutes, so the
-    # one-time jit compile it absorbs is noise, not signal.
+    # Single measured run (chip time is the scarce resource): this
+    # shape runs minutes, so the one-time jit compile it absorbs is
+    # noise, not signal.
     return config5_instacart(backend=Backend.SPARSE).as_dict()
-
-
-@guard("config4-sparse")
-def config4_sparse(quick: bool) -> dict:
-    from .configs import config4_zipfian_1m
-
-    n = _config4_events(quick)
-    # Two-axis sweep: score ladder x fixed-shape scoring. With fixed
-    # shapes ON (the TPU default) every bucket pads to its constant
-    # rectangle, so the ladder only decides the bucket set; the
-    # "L16/var" point re-measures the round-2 variable-padding mode
-    # (whose prior numbers were 71.9k @16 / 65.5k @4 before results
-    # were deferred). Warmup populates the jit caches; measure the
-    # second run of each.
-    by_mode = {}
-    best = None
-    with _env_overrides(TPU_COOC_SCORE_LADDER="4",
-                        TPU_COOC_FIXED_SCORE="1"):
-        for ladder, fixed in (("4", "1"), ("16", "1"), ("64", "1"),
-                              ("16", "0")):
-            os.environ["TPU_COOC_SCORE_LADDER"] = ladder
-            os.environ["TPU_COOC_FIXED_SCORE"] = fixed
-            config4_zipfian_1m(n_events=n)
-            r = config4_zipfian_1m(n_events=n)
-            key = f"L{ladder}/{'fixed' if fixed == '1' else 'var'}"
-            by_mode[key] = round(r.pairs_per_sec, 1)
-            if best is None or r.pairs_per_sec > best.pairs_per_sec:
-                best = r
-    d = best.as_dict()
-    d["pairs_per_sec_by_mode"] = by_mode
-    d["vs_host_baseline_22.9k"] = round(best.pairs_per_sec / 22_900, 2)
-    return d
-
-
-@contextlib.contextmanager
-def _env_overrides(**overrides: str):
-    """Set env vars for the duration, restoring the operator's values
-    (shared by the config4 passes; the remaining passes read the
-    ambient settings on purpose)."""
-    prior = {k: os.environ.get(k) for k in overrides}
-    os.environ.update(overrides)
-    try:
-        yield
-    finally:
-        for k, v in prior.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-
-
-def _config4_events(quick: bool) -> int:
-    """Event count for the config-4 passes. TPU_COOC_SMOKE_EVENTS
-    shrinks it for CPU smoke tests of the measurement machinery. On an
-    accelerator backend the knob is IGNORED with a warning: a stale
-    export must not shrink a chip capture into garbage rows. Every row
-    records its ``events`` regardless."""
-    smoke = tuning.env_read("TPU_COOC_SMOKE_EVENTS")
-    if smoke:
-        import jax
-
-        if jax.default_backend() == "cpu":
-            return max(1_000, int(smoke))
-        print(f"tpu_round2: ignoring TPU_COOC_SMOKE_EVENTS={smoke} on "
-              f"backend {jax.default_backend()!r} — smoke sizes would "
-              "corrupt a chip capture", file=sys.stderr)
-    return 200_000 if quick else 1_000_000
-
-
-def _config4_single(quick: bool, mode_label: str, **extra_env: str) -> dict:
-    """One warmup + one measured run of config 4 in L16/fixed mode.
-
-    Pins every knob the A/B rows vary — including UPLOAD_CHUNKS, so an
-    ambient operator setting can't contaminate the monolithic arm of
-    the upload comparison."""
-    from .configs import config4_zipfian_1m
-
-    n = _config4_events(quick)
-    env = dict(TPU_COOC_SCORE_LADDER="16", TPU_COOC_FIXED_SCORE="1",
-               TPU_COOC_UPLOAD_CHUNKS="1", TPU_COOC_UPLOAD_CHUNK_KB="0")
-    env.update(extra_env)
-    with _env_overrides(**env):
-        config4_zipfian_1m(n_events=n)  # warmup: populate jit caches
-        r = config4_zipfian_1m(n_events=n)
-    d = r.as_dict()
-    d["mode"] = mode_label
-    d["vs_host_baseline_22.9k"] = round(r.pairs_per_sec / 22_900, 2)
-    return d
-
-
-@guard("config4-headline")
-def config4_headline(quick: bool) -> dict:
-    """North star #1 in ONE number, fast: a single run of the
-    best-known mode (L16/fixed — the TPU default) instead of the 4-mode
-    sweep, so a short chip session still settles the headline before
-    anything long runs. The full sweep remains as config4-sparse."""
-    return _config4_single(quick, "L16/fixed")
-
-
-@guard("config4-chunked")
-def config4_chunked(quick: bool) -> dict:
-    """config4-headline with the update upload split into 4 transfers
-    (TPU_COOC_UPLOAD_CHUNKS=4): a per-transfer cost cliff between
-    256 KB and 1 MB was measured before this round (on a link the chip
-    tool's machine does not have; it awaits a cell), and config-4's
-    ~0.8 MB/window update sits above it. Compare against the
-    config4-headline row — if this wins on-chip, default
-    TPU_COOC_UPLOAD_CHUNK_KB=256 on TPU (the adaptive policy,
-    ops/device_scorer.upload_chunk_kb — fixed K leaves outsized
-    windows above the cliff)."""
-    return _config4_single(quick, "L16/fixed/chunks4",
-                           TPU_COOC_UPLOAD_CHUNKS="4")
 
 
 @guard("ml25m-full")
@@ -426,15 +307,15 @@ def sharded_pallas_1chip(quick: bool) -> dict:
         return (time.monotonic() - start) / n_w
 
     t_plain = step_time(SparseDeviceScorer(10, defer_results=True,
-                                           fixed_shapes=True))
+                                           fixed_score="on"))
     t_sharded = step_time(ShardedSparseScorer(10, mesh=mesh,
                                               defer_results=True,
-                                              fixed_shapes=True))
+                                              fixed_score="on"))
     return {
         "sharded_dense_int16": compare(lambda pl: ShardedScorer(
             items, 10, mesh=mesh, count_dtype="int16", use_pallas=pl)),
         "sharded_sparse": compare(lambda pl: ShardedSparseScorer(
-            10, mesh=mesh, defer_results=True, fixed_shapes=True,
+            10, mesh=mesh, defer_results=True, fixed_score="on",
             use_pallas=pl)),
         "step_ms_per_window_unsharded": round(t_plain * 1e3, 2),
         "step_ms_per_window_sharded_1dev": round(t_sharded * 1e3, 2),
@@ -518,18 +399,13 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated subset of measurement names")
     args = ap.parse_args()
-    # ONE number per north star runs before anything long
-    # (config4-headline is a single-mode run; the 4-mode sweep is
-    # config4-sparse, after the carrier rows); sparse-pallas decides the
-    # config-4 carrier kernel in the same sitting.
+    # The carrier rows run before anything long; sparse-pallas decides
+    # the config-4 carrier kernel in the same sitting.
     passes = {
-        "config4-headline": config4_headline,
-        "config4-chunked": config4_chunked,
         "ml25m-sparse": ml25m_sparse,
         "sparse-pallas": sparse_pallas,
         "ml25m-full": ml25m_full,
         "sharded-pallas-1chip": sharded_pallas_1chip,
-        "config4-sparse": config4_sparse,
         "config5-sparse": config5_sparse,
         "pallas-bench": pallas_bench,
         "configs": all_configs,

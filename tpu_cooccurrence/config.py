@@ -217,9 +217,9 @@ class Config:
     # slower than this raises the degradation plane's QUERY_PRESSURE
     # signal, shedding INGEST (tighter cuts, pause) before query latency
     # degrades — never the reverse; 0 = signal off
-    score_ladder: Optional[int] = None  # sparse score-bucket ladder base
-    # (power of two >= 2); None = env TPU_COOC_SCORE_LADDER or 4. Coarser
-    # = fewer dispatches, more padding — the high-latency-link lever.
+    score_ladder: int = tuning.default("score_ladder")  # sparse score-bucket
+    # ladder base (power of two >= 2). Coarser = fewer dispatches, more
+    # padding — the high-latency-link lever.
     fixed_score: str = tuning.default("fixed_score")  # sparse fixed-shape scoring: auto|on|off
     # (auto = on for real TPUs when results are deferred; constant
     # per-bucket rectangles -> one compile + one dispatch per bucket)
@@ -262,9 +262,8 @@ class Config:
     wire_format: str = tuning.default("wire_format")  # sparse per-window uplink encoding:
     # auto|raw|packed. packed = per-section sorted delta + zigzag +
     # bit-pack of the update buffer, decoded on device by a jit prologue
-    # (state/wire.py) — fewer uplink bytes at bit-identical results; an
-    # explicit TPU_COOC_UPLOAD_CHUNKS/_CHUNK_KB split request pins the
-    # raw chunked path. Also selects the checkpoint blob codec
+    # (state/wire.py) — fewer uplink bytes at bit-identical results.
+    # Also selects the checkpoint blob codec
     # (raw = pre-codec layout, else delta+varint). auto = packed on the
     # single-process sparse backend, raw elsewhere.
     pipeline_depth: int = tuning.default("pipeline_depth")  # pipelined execution: the caller thread
@@ -922,12 +921,12 @@ class Config:
                             "bit-pack, decoded on device, bit-identical "
                             "results (auto: packed on the single-process "
                             "sparse backend)")
-        p.add_argument("--score-ladder", type=int, default=None,
+        p.add_argument("--score-ladder", type=int,
+                       default=tuning.default("score_ladder"),
                        dest="score_ladder",
                        help="Sparse-backend score-bucket ladder base "
-                            "(power of two >= 2; default 4 or env "
-                            "TPU_COOC_SCORE_LADDER). Coarser = fewer "
-                            "dispatches, more padding")
+                            "(power of two >= 2; default 4). Coarser = "
+                            "fewer dispatches, more padding")
         p.add_argument("--fixed-score",
                        choices=list(tuning.get("fixed_score").choices),
                        default=tuning.default("fixed_score"),

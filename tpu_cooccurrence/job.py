@@ -332,15 +332,6 @@ class CooccurrenceJob:
             threshold=self.config.scorer_breaker_threshold,
             probe_after_windows=self.config.scorer_breaker_probe_windows)
 
-    def _parse_fixed_score(self):
-        fixed = {"auto": None, "on": True,
-                 "off": False}.get(self.config.fixed_score, KeyError)
-        if fixed is KeyError:
-            raise ValueError(
-                f"fixed_score must be auto|on|off, got "
-                f"{self.config.fixed_score!r}")
-        return fixed
-
     def _make_scorer(self):
         backend = self.config.backend
         if backend == Backend.HYBRID:
@@ -356,10 +347,14 @@ class CooccurrenceJob:
             LOG.warning("--backend hybrid is retired; running the sparse "
                         "backend (checkpoints are interchangeable)")
             backend = Backend.SPARSE
-        if backend != Backend.SPARSE and self._parse_fixed_score() is not None:
+        if backend != Backend.SPARSE and self.config.fixed_score != "auto":
             # An explicit setting the backend cannot honor must not be
             # silently ignored (same rule as the sparse branch's
-            # emit-updates conflict).
+            # emit-updates conflict). A bogus value is refused first,
+            # by the same parse the sparse scorers use.
+            from .state.sparse_scorer import resolve_fixed_shapes
+
+            resolve_fixed_shapes(self.config.fixed_score, True)
             raise ValueError(
                 f"--fixed-score {self.config.fixed_score} only applies to "
                 f"--backend sparse (got {backend.value})")
@@ -382,7 +377,6 @@ class CooccurrenceJob:
                 defer_results=not self.config.emit_updates,
                 fused_window=self.config.fused_window))
         if backend == Backend.SPARSE:
-            fixed = self._parse_fixed_score()
             if self.config.num_shards > 1:
                 from .parallel.distributed import maybe_multihost_mesh
 
@@ -402,7 +396,7 @@ class CooccurrenceJob:
                     development_mode=self.config.development_mode,
                     score_ladder=self.config.score_ladder,
                     defer_results=not self.config.emit_updates,
-                    fixed_shapes=fixed,
+                    fixed_score=self.config.fixed_score,
                     use_pallas=self.config.pallas,
                     cell_dtype=resolve_cell_dtype(
                         self.config.cell_dtype, sparse_single_device=False),
@@ -430,7 +424,7 @@ class CooccurrenceJob:
                 self.config.development_mode,
                 score_ladder=self.config.score_ladder,
                 defer_results=not self.config.emit_updates,
-                fixed_shapes=fixed,
+                fixed_score=self.config.fixed_score,
                 use_pallas=self.config.pallas,
                 cell_dtype=resolve_cell_dtype(
                     self.config.cell_dtype, sparse_single_device=True),

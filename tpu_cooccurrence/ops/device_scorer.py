@@ -35,8 +35,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import functools
-import logging
-import os
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -237,97 +235,6 @@ def _update_coo_u16(C, row_sums, coo, num_items: int):
     also falls back to the int32 block when a window's aggregated cell
     delta leaves int16 range.
     """
-    src = coo[0].astype(jnp.int32)
-    dst = coo[1].astype(jnp.int32)
-    delta = coo[2].astype(jnp.int16).astype(jnp.int32)  # sign-extend
-    return _apply_coo(C, row_sums, src, dst, delta, num_items)
-
-
-def upload_chunks() -> int:
-    """How many pieces to split per-window packed uploads into.
-
-    Host->device transfer cost was measured non-linear in size before
-    this round (256 KB = 0.3 ms, 1 MB = 11.6 ms, on a link the chip
-    tool's machine does not have); K separate smaller arguments of one
-    jitted call may ride under such a cliff. Default 1 (monolithic)
-    until a benchmark cell measures the split (ROADMAP A2). Shared by
-    the sparse update and dense COO paths."""
-    try:
-        return max(1, int(tuning.env_read("TPU_COOC_UPLOAD_CHUNKS", "1")))
-    except ValueError:
-        return 1
-
-
-_split_declined_warned = False
-
-
-def split_upload(arr: np.ndarray, k: int) -> Optional[Tuple]:
-    """``arr`` ([rows, N]) as k contiguous column-range pieces, or None
-    when splitting is off / not worthwhile (tiny windows) / uneven.
-
-    A requested-but-declined split warns once: an operator A/B-testing
-    chunking on scarce chip time must not silently measure the
-    monolithic path (padded widths are pow2/pow4, so e.g. K=3 never
-    divides and would never engage)."""
-    if k <= 1 or arr.shape[1] % k or arr.shape[1] // k < 1024:
-        global _split_declined_warned
-        if k > 1 and not _split_declined_warned:
-            _split_declined_warned = True
-            logging.getLogger("tpu_cooccurrence").warning(
-                "TPU_COOC_UPLOAD_CHUNKS=%d requested but a width-%d "
-                "upload cannot split evenly into >=1024-column chunks; "
-                "monolithic upload used for such windows (use a power "
-                "of two that divides the padded width)", k, arr.shape[1])
-        return None
-    return tuple(np.ascontiguousarray(p) for p in np.split(arr, k, axis=1))
-
-
-def upload_chunk_kb() -> float:
-    """Byte target per upload piece (0 = off). The adaptive form of the
-    chunk policy: where TPU_COOC_UPLOAD_CHUNKS fixes K for every
-    window, TPU_COOC_UPLOAD_CHUNK_KB picks the smallest power-of-two K
-    per upload that brings each piece under the target — window sizes
-    are data-dependent (pow2/pow4 ladders), so a fixed K leaves big
-    windows above the measured per-transfer cliff (e.g. 3 MB / 4 =
-    750 KB pieces). This is the shape the TPU default takes if the
-    on-chip A/B proves chunking."""
-    try:
-        return float(tuning.env_read("TPU_COOC_UPLOAD_CHUNK_KB", "0"))
-    except ValueError:
-        return 0.0
-
-
-def split_upload_auto(arr: np.ndarray) -> Optional[Tuple]:
-    """Pieces for this upload per the env policy, or None (monolithic).
-
-    A SET TPU_COOC_UPLOAD_CHUNKS wins outright — including =1, which
-    pins the monolithic arm of an A/B against an ambient CHUNK_KB (the
-    same silent-contamination hazard _config4_single pins against).
-    Otherwise TPU_COOC_UPLOAD_CHUNK_KB adapts K to the buffer size."""
-    if tuning.env_read("TPU_COOC_UPLOAD_CHUNKS"):
-        return split_upload(arr, upload_chunks())
-    kb = upload_chunk_kb()
-    if kb <= 0:
-        return None
-    cols = arr.shape[1]
-    k = 1
-    while (arr.nbytes / k > kb * 1024 and cols % (2 * k) == 0
-           and cols // (2 * k) >= 1024):
-        k *= 2
-    return split_upload(arr, k) if k > 1 else None
-
-
-@functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1), static_argnames=("num_items",))
-def _update_coo_chunked(C, row_sums, coo_parts, num_items: int):
-    """_update_coo with the block arriving as K separate transfers;
-    the concatenate is device-side and fuses away."""
-    coo = jnp.concatenate(coo_parts, axis=1)
-    return _apply_coo(C, row_sums, coo[0], coo[1], coo[2], num_items)
-
-
-@functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1), static_argnames=("num_items",))
-def _update_coo_u16_chunked(C, row_sums, coo_parts, num_items: int):
-    coo = jnp.concatenate(coo_parts, axis=1)
     src = coo[0].astype(jnp.int32)
     dst = coo[1].astype(jnp.int32)
     delta = coo[2].astype(jnp.int16).astype(jnp.int32)  # sign-extend
@@ -826,20 +733,10 @@ class DeviceScorer:
                 coo[0, :n] = src[lo: lo + n]
                 coo[1, :n] = dst[lo: lo + n]
                 check_coo_chunk(coo, n)
-                parts = split_upload_auto(coo)
                 clk.add("launches")
-                if parts is not None:
-                    for p in parts:
-                        LEDGER.up("coo-chunk", p)
-                    update_chunked = (_update_coo_u16_chunked if use_u16
-                                      else _update_coo_chunked)
-                    self.C, self.row_sums = update_chunked(
-                        self.C, self.row_sums, parts,
-                        num_items=self.num_items)
-                else:
-                    LEDGER.up("coo", coo)
-                    self.C, self.row_sums = update(
-                        self.C, self.row_sums, coo, num_items=self.num_items)
+                LEDGER.up("coo", coo)
+                self.C, self.row_sums = update(
+                    self.C, self.row_sums, coo, num_items=self.num_items)
 
         window_sum = int(pairs.delta.sum())
         self.observed += window_sum

@@ -19,18 +19,12 @@ matrix. The copy this costs on CPU is host-memory bandwidth — real but
 bounded — where the race is a crash; accelerator backends keep full
 donation (their PJRT clients sequence donation against pending reads
 correctly).
-
-``TPU_COOC_DONATE=0|1`` overrides for A/B measurement; unset = the
-backend rule above.
 """
 
 from __future__ import annotations
 
-import os
 from typing import Tuple
 
-
-from .. import tuning
 
 def donate_argnums(*argnums: int) -> Tuple[int, ...]:
     """``argnums`` on accelerator backends, ``()`` on CPU (see module doc).
@@ -43,11 +37,6 @@ def donate_argnums(*argnums: int) -> Tuple[int, ...]:
     *before* importing any scorer module — a scorer import that
     initialized the backend first would make distributed init raise.
     """
-    env = tuning.env_read("TPU_COOC_DONATE", "").strip()
-    if env in ("0", "off", "false", "no"):
-        return ()
-    if env in ("1", "on", "true", "yes"):
-        return tuple(argnums)
     import jax
 
     return tuple(argnums) if jax.default_backend() != "cpu" else ()

@@ -40,7 +40,7 @@ indexed by shard-local row id) sync from each shard's
 on its ownership-partitioned word streams, and the update scatter + psum
 + mirror sync + rescore + results scatter compile into ONE ``shard_map``
 program — a steady-state window is exactly one launch per worker.
-Relocation / promotion / upload-split windows and the first window after
+Relocation / promotion windows and the first window after
 construction or restore (the rescale seam: every bucket plan is invalid
 until rebuilt from post-restore registry state) route down the chained
 path per window, bit-identically — the fused body is built from the same
@@ -96,9 +96,9 @@ class ShardedSparseScorer:
                  capacity: int = 1 << 14,
                  items_capacity: int = 1 << 10,
                  compact_min_heap: int = 1 << 16,
-                 score_ladder: Optional[int] = None,
+                 score_ladder: int = tuning.default("score_ladder"),
                  defer_results: bool = False,
-                 fixed_shapes: Optional[bool] = None,
+                 fixed_score: str = tuning.default("fixed_score"),
                  use_pallas: str = "auto",
                  cell_dtype: str = "int32",
                  wire_format: str = "raw",
@@ -118,9 +118,7 @@ class ShardedSparseScorer:
         self.wire_format = wire_format
         self.wire_packed = wire_format == "packed"
         self.top_k = top_k
-        self.score_ladder = int(score_ladder if score_ladder is not None
-                                else tuning.env_read(
-                                    "TPU_COOC_SCORE_LADDER", 4))
+        self.score_ladder = int(score_ladder)
         ladder_bits(self.score_ladder)  # validate at construction
         self.counters = counters if counters is not None else Counters()
         self.development_mode = development_mode
@@ -157,10 +155,10 @@ class ShardedSparseScorer:
         self._score_into_fns: Dict[tuple, object] = {}  # (R, pallas-routed)
         self._score_window_fns: Dict[tuple, object] = {}  # (plan, routed)
         self._tbl_gather_fns: Dict[int, object] = {}
-        # Fixed-shape scoring (same contract and env override as the
-        # single-device sparse scorer — constant per-bucket rectangles,
-        # one fused window dispatch over a monotone high-water plan).
-        self.fixed_shapes = resolve_fixed_shapes(fixed_shapes,
+        # Fixed-shape scoring (same contract as the single-device
+        # sparse scorer — constant per-bucket rectangles, one fused
+        # window dispatch over a monotone high-water plan).
+        self.fixed_shapes = resolve_fixed_shapes(fixed_score,
                                                  self.defer_results)
         self._plan_buckets = {}  # bucket -> high-water chunk count
         # Fused-kernel routing, same contract as the single-device sparse
@@ -208,7 +206,7 @@ class ShardedSparseScorer:
         self._plan_buckets_w = {}  # wide rows' own monotone plan
         # Fused one-dispatch window on the mesh (--fused-window on the
         # sharded sparse backend): deferred results only; promotion /
-        # relocation / upload-split windows and the first window after
+        # relocation windows and the first window after
         # construction or restore route chained per window (see
         # _fallback_chained). Same contract as the single-device scorer.
         from ..observability.registry import REGISTRY
@@ -951,13 +949,9 @@ class ShardedSparseScorer:
 
         Not fused-routable (decided here, after allocation): relocation
         windows (``plan.mv`` on any shard — the fused program carries no
-        move kernel) and windows under an explicit upload-split request
-        (TPU_COOC_UPLOAD_CHUNKS/_CHUNK_KB pins the raw chunked path).
-        The caller gates promotion windows and the post-restore plan
-        rebuild before allocation.
+        move kernel). The caller gates promotion windows and the
+        post-restore plan rebuild before allocation.
         """
-        from ..ops.device_scorer import split_upload_auto
-
         D = self.n_shards
         clk = self.stage_clock
         with clk.stage("index"):
@@ -994,9 +988,6 @@ class ShardedSparseScorer:
                 upd[d, 0, b1: b1 + k] = rows[sel]
                 upd[d, 1, b1: b1 + k] = rs_delta[sel].astype(np.int32)
                 bounds[d] = (b0, b1)
-            if split_upload_auto(upd[0]) is not None:
-                self._fallback_chained("upload-split")
-                return False, prealloc
 
             # Registry mirror delta sync, per shard in LOCAL row ids:
             # rows whose host (start, len) changed since the mirror last

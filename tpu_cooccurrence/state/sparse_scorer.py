@@ -64,8 +64,7 @@ from ..robustness import faults
 from ..ops.aggregate import (AggregatedPairs, aggregate_window_coo,
                              distinct_sorted, merge_sorted_insert,
                              narrow_deltas_int32)
-from ..ops.device_scorer import (DeferredResultsTable, pad_pow2, pad_pow4,
-                                 split_upload_auto)
+from ..ops.device_scorer import DeferredResultsTable, pad_pow2, pad_pow4
 from ..ops.donation import donate_argnums
 from ..ops.llr import llr_stable
 from ..sampling.reservoir import PairDeltaBatch, _ragged_arange
@@ -122,22 +121,6 @@ def _update_body(cnt, dst, row_sums, upd, bounds):
 
 _apply_update = functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1, 2))(
     _update_body)
-
-
-@functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1, 2))
-def _apply_update_chunked(cnt, dst, row_sums, upd_parts, bounds):
-    """_apply_update with the update buffer arriving as K separate
-    transfers; the concatenate is device-side and fuses away."""
-    return _update_body(cnt, dst, row_sums,
-                        jnp.concatenate(upd_parts, axis=1), bounds)
-
-
-@functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1, 2), static_argnames=("L",))
-def _apply_moves_update_chunked(cnt, dst, row_sums, mv, upd_parts, bounds,
-                                L: int):
-    cnt, dst = _moves_body(cnt, dst, mv, L)
-    return _update_body(cnt, dst, row_sums,
-                        jnp.concatenate(upd_parts, axis=1), bounds)
 
 
 @functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1, 2),
@@ -458,28 +441,20 @@ def _pad_words(words: np.ndarray) -> np.ndarray:
     return out
 
 
-def resolve_fixed_shapes(fixed_shapes, defer_results: bool) -> bool:
-    """Resolve a fixed-shape request (None = env TPU_COOC_FIXED_SCORE or
-    auto) and enforce the defer-only contract — shared by the
-    single-device and sharded sparse scorers."""
-    if fixed_shapes is None:
-        env = tuning.env_read("TPU_COOC_FIXED_SCORE", "auto")
-        env = env.strip().lower()
-        if env in ("1", "on", "true", "yes"):
-            fixed_shapes = True
-        elif env in ("0", "off", "false", "no"):
-            fixed_shapes = False
-        elif env in ("auto", ""):
-            # Fixed rectangles only make sense when results stay on
-            # device: the pipelined path fetches each packed block, and
-            # a full [2, s_block, K] fetch per bucket would ship
-            # megabytes of padding over the very link this mode exists
-            # to spare.
-            fixed_shapes = (jax.default_backend() == "tpu"
-                            and defer_results)
-        else:
-            raise ValueError(
-                f"TPU_COOC_FIXED_SCORE must be 0/1/auto, got {env!r}")
+def resolve_fixed_shapes(fixed_score: str, defer_results: bool) -> bool:
+    """Resolve an ``auto|on|off`` --fixed-score request and enforce the
+    defer-only contract — shared by the single-device and sharded
+    sparse scorers, and by the job's non-sparse-backend check."""
+    if fixed_score not in ("auto", "on", "off"):
+        raise ValueError(
+            f"fixed_score must be auto|on|off, got {fixed_score!r}")
+    if fixed_score == "auto":
+        # Fixed rectangles only make sense when results stay on device:
+        # the pipelined path fetches each packed block, and a full
+        # [2, s_block, K] fetch per bucket would ship megabytes of
+        # padding over the very link this mode exists to spare.
+        return jax.default_backend() == "tpu" and defer_results
+    fixed_shapes = fixed_score == "on"
     if fixed_shapes and not defer_results:
         # An explicit request that cannot take effect must not be
         # silently downgraded — a fixed-vs-variable A/B would then
@@ -506,7 +481,7 @@ def ladder_bits(ladder: int) -> int:
     if k < 1 or ladder != (1 << k):
         raise ValueError(
             f"score ladder must be a power of two >= 2, got {ladder} "
-            f"(TPU_COOC_SCORE_LADDER)")
+            f"(--score-ladder)")
     return k
 
 
@@ -552,7 +527,8 @@ def score_buckets(lens: np.ndarray, min_r: int, ladder: int = 4):
 #             vocabulary this is an order of magnitude under dense
 #             (pinned by tests/test_slab_registry.py).
 #
-# Default: bitmap (env TPU_COOC_ROW_INDEX=dense opts out for A/B).
+# Default: bitmap; dense stays as the reference layout the bitmap is
+# tested against.
 
 
 if hasattr(np, "bitwise_count"):  # numpy >= 2.0
@@ -825,18 +801,15 @@ class BitmapRowRegistry(_RegistryDirtyLog):
         self.cap = np.zeros(0, dtype=np.int32)
 
 
-def make_row_registry(rows_capacity: int, kind: Optional[str] = None):
-    """Row-registry factory: ``kind`` or env ``TPU_COOC_ROW_INDEX``
-    (default bitmap — the compressed index is the production layout;
-    dense remains for A/B and as the reference implementation)."""
-    if kind is None:
-        kind = tuning.env_read("TPU_COOC_ROW_INDEX", "bitmap").strip().lower()
+def make_row_registry(rows_capacity: int, kind: str = "bitmap"):
+    """Row-registry factory (bitmap — the compressed index is the
+    production layout; dense remains as the reference implementation)."""
     if kind == "dense":
         return DenseRowRegistry(rows_capacity)
     if kind == "bitmap":
         return BitmapRowRegistry(rows_capacity)
-    raise ValueError(
-        f"TPU_COOC_ROW_INDEX must be bitmap or dense, got {kind!r}")
+    raise ValueError(f"row registry kind must be bitmap or dense, got "
+                     f"{kind!r}")
 
 
 class _RowField:
@@ -890,7 +863,7 @@ class SlabIndex:
     """
 
     def __init__(self, rows_capacity: int = 1 << 10,
-                 row_index: Optional[str] = None) -> None:
+                 row_index: str = "bitmap") -> None:
         self.g_key = np.zeros(0, dtype=np.int64)
         self.g_slot = np.zeros(0, dtype=np.int32)
         self.rows = make_row_registry(rows_capacity, row_index)
@@ -1459,7 +1432,7 @@ class SparseDeviceScorer:
     # Per-score-chunk padded-cell budget. Padding is device compute only —
     # it never crosses the wire in this backend — so the budget is sized
     # for HBM transients ([S, R] gather + scores), not transfer, and the
-    # length ladder is coarse (default pow-4; TPU_COOC_SCORE_LADDER):
+    # length ladder is coarse (default pow-4; --score-ladder):
     # fewer dispatches beats tighter padding when every dispatch pays
     # link round-trip latency.
     SCORE_BUDGET = 1 << 24
@@ -1475,9 +1448,9 @@ class SparseDeviceScorer:
                  capacity: int = 1 << 16,
                  items_capacity: int = 1 << 10,
                  compact_min_heap: int = 1 << 16,
-                 score_ladder: Optional[int] = None,
+                 score_ladder: int = tuning.default("score_ladder"),
                  defer_results: bool = False,
-                 fixed_shapes: Optional[bool] = None,
+                 fixed_score: str = tuning.default("fixed_score"),
                  use_pallas: str = "auto",
                  cell_dtype: str = "int32",
                  wire_format: str = "raw",
@@ -1503,11 +1476,7 @@ class SparseDeviceScorer:
         self.wire_packed = wire_format == "packed"
         self.top_k = top_k
         # Bucket-ladder base for the scoring dispatches (see score_buckets).
-        # Env-tunable so high-latency links can trade padding for fewer
-        # round trips without a config/API change.
-        self.score_ladder = int(score_ladder if score_ladder is not None
-                                else tuning.env_read(
-                                    "TPU_COOC_SCORE_LADDER", 4))
+        self.score_ladder = int(score_ladder)
         ladder_bits(self.score_ladder)  # validate at construction
         self.counters = counters if counters is not None else Counters()
         self.development_mode = development_mode
@@ -1561,9 +1530,9 @@ class SparseDeviceScorer:
         # bounded meta upload; the win is dispatch/compile-count, which
         # is what a high-latency link and a freshly-started process
         # actually pay for. Default: on for real TPUs, off elsewhere
-        # (CPU tests would crawl through the padding); env
-        # TPU_COOC_FIXED_SCORE=0/1 overrides.
-        self.fixed_shapes = resolve_fixed_shapes(fixed_shapes,
+        # (CPU tests would crawl through the padding); --fixed-score
+        # on/off overrides.
+        self.fixed_shapes = resolve_fixed_shapes(fixed_score,
                                                  self.defer_results)
         # bucket -> high-water chunk count (monotone plan: the fused
         # program's static plan only ever grows, so compile count stays
@@ -1804,8 +1773,8 @@ class SparseDeviceScorer:
         # Fused routing gate: steady-state all-narrow windows with no
         # spill re-promotion take the one-dispatch program; promotion /
         # wide-touching / re-promotion windows (and, inside
-        # _fused_window, relocation windows and explicit upload-split
-        # requests) route chained — per window, bit-identically.
+        # _fused_window, relocation windows) route chained — per
+        # window, bit-identically.
         plan = None
         fused_done = False
         if (self.use_fused and promo_n is None and promo_w is None
@@ -1828,8 +1797,8 @@ class SparseDeviceScorer:
         split = cell_wide is not None and (cell_wide.any()
                                            or promo_w is not None)
         # Slot allocation is index work; a plan from a fused attempt
-        # that bailed AFTER allocation (relocation window / explicit
-        # upload-split request) must not be applied twice.
+        # that bailed AFTER allocation (a relocation window) must not
+        # be applied twice.
         with clk.stage("index"):
             if split:
                 key_n, key_w = d_key[~cell_wide], d_key[cell_wide]
@@ -1930,12 +1899,7 @@ class SparseDeviceScorer:
         lbl = "update-wide" if wide else "update"
         self.stage_clock.add("launches")
 
-        # An explicit upload-split request (TPU_COOC_UPLOAD_CHUNKS /
-        # _CHUNK_KB) pins the raw chunked path — the two wire levers are
-        # alternatives, and an operator A/B-ing chunk sizes must not
-        # silently measure the packed encoder instead.
-        parts = split_upload_auto(upd) if not wide else None
-        if parts is None and self.wire_packed:
+        if self.wire_packed:
             from .wire import encode_update
 
             words_i, words_v, header = encode_update(upd, bounds, n)
@@ -1953,33 +1917,15 @@ class SparseDeviceScorer:
                                   upd.nbytes + bounds.nbytes, wi, wv, header)
                 cnt_t, dst_t, self.row_sums = _apply_update_packed(
                     cnt_t, dst_t, self.row_sums, wi, wv, header, n_pad=n_pad)
+        elif plan.mv is not None:
+            LEDGER.up(lbl, upd, bounds, plan.mv)
+            cnt_t, dst_t, self.row_sums = _apply_moves_update(
+                cnt_t, dst_t, self.row_sums, plan.mv, upd,
+                bounds, L=plan.mv_len)
         else:
-            if parts is not None:
-                # Ledger mirrors the actual transfer pattern: one event
-                # per chunk plus the small metadata buffers (same byte
-                # total as the monolithic event).
-                for p in parts:
-                    LEDGER.up("update-chunk", p)
-            if plan.mv is not None:
-                if parts is not None:
-                    LEDGER.up("update-meta", bounds, plan.mv)
-                    cnt_t, dst_t, self.row_sums = _apply_moves_update_chunked(
-                        cnt_t, dst_t, self.row_sums, plan.mv,
-                        parts, bounds, L=plan.mv_len)
-                else:
-                    LEDGER.up(lbl, upd, bounds, plan.mv)
-                    cnt_t, dst_t, self.row_sums = _apply_moves_update(
-                        cnt_t, dst_t, self.row_sums, plan.mv, upd,
-                        bounds, L=plan.mv_len)
-            else:
-                if parts is not None:
-                    LEDGER.up("update-meta", bounds)
-                    cnt_t, dst_t, self.row_sums = _apply_update_chunked(
-                        cnt_t, dst_t, self.row_sums, parts, bounds)
-                else:
-                    LEDGER.up(lbl, upd, bounds)
-                    cnt_t, dst_t, self.row_sums = _apply_update(
-                        cnt_t, dst_t, self.row_sums, upd, bounds)
+            LEDGER.up(lbl, upd, bounds)
+            cnt_t, dst_t, self.row_sums = _apply_update(
+                cnt_t, dst_t, self.row_sums, upd, bounds)
         if wide:
             self.cnt_w, self.dst_w = cnt_t, dst_t
         else:
@@ -2061,12 +2007,9 @@ class SparseDeviceScorer:
 
         Not fused-routable (decided here, after allocation): relocation
         windows (``plan.mv`` — the fused program carries no move
-        kernel; moves stay fused with the CHAINED update instead) and
-        windows under an explicit upload-split request
-        (TPU_COOC_UPLOAD_CHUNKS/_CHUNK_KB pins the raw chunked path —
-        an operator A/B-ing chunk sizes must not silently measure the
-        fused program). The caller gates promotion / wide-row / spill
-        re-promotion windows before allocation.
+        kernel; moves stay fused with the CHAINED update instead). The
+        caller gates promotion / wide-row / spill re-promotion windows
+        before allocation.
         """
         clk = self.stage_clock
         with clk.stage("index"):
@@ -2082,8 +2025,6 @@ class SparseDeviceScorer:
             upd, bounds, n = self._pack_update(self.index, plan, d_key,
                                                d_val32, rows, rs_delta, None)
             n_pad = upd.shape[1]
-            if split_upload_auto(upd) is not None:
-                return False, plan
             # Registry delta sync: rows whose host (start, len) changed
             # since the device mirror last synced — this window's
             # new-cell rows plus anything a chained window / compaction

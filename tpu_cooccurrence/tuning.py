@@ -183,38 +183,14 @@ _register(TuningParameter(
 _register(TuningParameter(
     name="score_ladder", type="int", default=4, bounds=(2, None),
     unit="x per bucket", flag="--score-ladder",
-    env="TPU_COOC_SCORE_LADDER",
     doc="Sparse score-bucket ladder base (power of two >= 2): coarser "
         "ladders mean fewer compiled rectangle shapes but more "
         "padding per dispatch."))
 _register(TuningParameter(
     name="fixed_score", type="choice", default="auto",
     choices=("auto", "on", "off"), flag="--fixed-score",
-    env="TPU_COOC_FIXED_SCORE",
     doc="Sparse fixed-shape scoring (constant per-bucket rectangles); "
         "auto = on for real TPUs when results are deferred."))
-_register(TuningParameter(
-    name="upload_chunks", type="int", default=1, bounds=(1, None),
-    unit="chunks", env="TPU_COOC_UPLOAD_CHUNKS",
-    doc="Fixed K-way split of per-window device uploads (transfer-"
-        "cliff lever); 1 = monolithic until the on-chip A/B proves the "
-        "split."))
-_register(TuningParameter(
-    name="upload_chunk_kb", type="float", default=0.0, bounds=(0.0, None),
-    unit="KiB", env="TPU_COOC_UPLOAD_CHUNK_KB",
-    doc="Adaptive upload chunking: smallest pow2 K bringing each piece "
-        "under this size; 0 = off. A set upload_chunks pins K first."))
-_register(TuningParameter(
-    name="row_index", type="choice", default="bitmap",
-    choices=("bitmap", "dense"), env="TPU_COOC_ROW_INDEX",
-    doc="Sparse row-registry layout: bitmap+rank directory "
-        "(production) or dense reference arrays (A/B baseline)."))
-_register(TuningParameter(
-    name="donate", type="choice", default="auto",
-    choices=("auto", "on", "off"), env="TPU_COOC_DONATE",
-    doc="Donate state buffers to the jitted window dispatch (halves "
-        "peak HBM); auto = on for non-CPU backends (TFRT CPU "
-        "use-after-donate gating)."))
 _register(TuningParameter(
     name="pow2_pad_min", type="int", default=256, bounds=(1, None),
     unit="rows",
@@ -272,11 +248,6 @@ _register(TuningParameter(
     env="TPU_COOC_SUPERVISOR_STATE",
     doc="Path of the supervisor's crash-loop state file (restart "
         "budget accounting across respawns)."))
-_register(TuningParameter(
-    name="smoke_events", type="int", default=None, kind="infra",
-    env="TPU_COOC_SMOKE_EVENTS",
-    doc="CPU-only bench shrink: cap measured events for smoke runs "
-        "(ignored with a warning on accelerator backends)."))
 
 
 def markdown_table(kind: str = "perf") -> str:
