@@ -116,16 +116,18 @@ def _redonate(jitted, donate, static):
                    static_argnames=static)
 
 
-def _dense_update(one_chip):
+def _dense_update(one_chip, items=61_440, wire=jnp.int32):
     """Config 3's chained dense window, update half: the [3, N] COO
-    scatter into the resident int16 C, donated as on the chip."""
+    scatter into the resident int16 C, stepped over the chunk's live
+    lanes and donated as on the chip (uint16 COO: the ML-25M cell's)."""
     from tpu_cooccurrence.ops import device_scorer as ds
 
-    items = 61_440
-    fn = _redonate(ds._update_coo, (0, 1), ("num_items",))
+    fn = _redonate(ds._update_coo if wire == jnp.int32
+                   else ds._update_coo_u16, (0, 1), ("num_items",))
     return fn.lower(_sds((items, items), jnp.int16, one_chip),
                     _sds((items,), jnp.int32, one_chip),
-                    _sds((3, 1 << 20), jnp.int32, one_chip),
+                    _sds((3, 1 << 20), wire, one_chip),
+                    _sds((), jnp.int32, one_chip),
                     num_items=items).compile()
 
 
@@ -219,16 +221,21 @@ def _assert_reads_c_in_place(compiled):
 
 
 @pytest.mark.parametrize("program", [
-    "dense-update-61440-int16", "dense-score-61440-int16",
+    "dense-update-61440-int16", "dense-update-59392-int16-u16-ml25m",
+    "dense-score-61440-int16",
     "dense-score-59392-int16-ml25m", "dense-fused-config5",
     "dense-fused-config5-1024ops", "sparse-fused-config4"])
 def test_main_path_program_compiles(one_chip, monkeypatch, program):
-    if program == "dense-update-61440-int16":
-        compiled = _dense_update(one_chip)
+    if program.startswith("dense-update"):
+        items = int(program.split("-")[2])
+        compiled = _dense_update(
+            one_chip, items, jnp.uint16 if "u16" in program else jnp.int32)
         m = compiled.memory_analysis()
-        # Donation keeps ONE 7.55 GB C resident: without the alias the
-        # output C alone would double it past the chip's HBM.
-        assert m.alias_size_in_bytes >= 61_440 ** 2 * 2
+        # Donation keeps ONE 7.55 GB C resident through the scatter's
+        # steps: without the alias the output C alone would double it
+        # past the chip's HBM.
+        assert m.alias_size_in_bytes >= items ** 2 * 2
+        assert m.temp_size_in_bytes < 64 * 2**20
     elif program.startswith("dense-score"):
         # 59,392: the ML-25M benchmark cell's catalog, padded to the tile
         # (4,096 rows a call, tile 2,048).

@@ -192,7 +192,8 @@ SCHEMA = {
                                  # (StageClock.counts: launches,
                                  # score_cells, live_cells; dense
                                  # fused_windows, expand_lanes,
-                                 # expand_live)
+                                 # expand_live; dense chained
+                                 # scatter_lanes, scatter_live)
 }
 
 

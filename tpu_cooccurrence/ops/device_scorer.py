@@ -214,31 +214,69 @@ def _update(C, row_sums, src, dst, delta, num_items: int):
     return _apply_coo(C, row_sums, src, dst, delta, num_items)
 
 
+#: Entries one step of a stepped scatter takes (``_stepped_apply``):
+#: COO lanes on the chained path, pairs (two lanes each) in the fused
+#: window. A padding lane costs the scatter as much as a live one (about
+#: 113 ns each on a v5e at the Instacart catalog), so both dense update
+#: paths scatter only the steps their live entries fill: at most one
+#: step's worth of padding a COO chunk or window, and a small scatter
+#: working set. The chained path's COO buckets are powers of two of at
+#: least this many lanes, so its steps never run past the buffer.
+SCATTER_STEP = 1 << 14
+
+
+def _stepped_apply(C, row_sums, n, lanes, num_items: int):
+    """Scatter-add ``n`` entries into ``C`` and the row sums
+    ``SCATTER_STEP`` at a time, for as many steps as they fill: a loop
+    with a traced trip count, so one program serves every ``n`` its
+    buffer can hold, and ``C`` stays the loop's carry (updated in place
+    under donation). ``lanes(t)`` gives step ``t``'s lanes as ``(src,
+    dst, delta)``; those of the last step past ``n`` must be no-ops."""
+
+    def step(t, state):
+        return _apply_coo(*state, *lanes(t), num_items)
+
+    steps = (n + SCATTER_STEP - 1) // SCATTER_STEP
+    return jax.lax.fori_loop(0, steps, step, (C, row_sums))
+
+
+def _coo_step(coo, t):
+    """Step ``t``'s ``SCATTER_STEP`` lanes of a packed ``[3, N]`` COO."""
+    return jax.lax.dynamic_slice_in_dim(coo, t * SCATTER_STEP, SCATTER_STEP,
+                                        axis=1)
+
+
 @functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1), static_argnames=("num_items",))
-def _update_coo(C, row_sums, coo, num_items: int):
-    """Scatter-apply a packed ``[3, N]`` (src, dst, delta) COO block.
+def _update_coo(C, row_sums, coo, n, num_items: int):
+    """Scatter-apply the first ``n`` lanes of a packed ``[3, N]`` (src,
+    dst, delta) COO block, in steps (``_stepped_apply``).
 
     Packing the three arrays into one host buffer costs one host->device
     transfer instead of three — on a latency-bound link transfer count
     matters as much as bytes.
     """
-    return _apply_coo(C, row_sums, coo[0], coo[1], coo[2], num_items)
+    return _stepped_apply(C, row_sums, n,
+                          lambda t: _coo_step(coo, t), num_items)
 
 
 @functools.partial(jax.jit, donate_argnums=donate_argnums(0, 1), static_argnames=("num_items",))
-def _update_coo_u16(C, row_sums, coo, num_items: int):
-    """Scatter-apply a packed ``[3, N]`` uint16 COO block (half the bytes).
+def _update_coo_u16(C, row_sums, coo, n, num_items: int):
+    """Scatter-apply the first ``n`` lanes of a packed ``[3, N]`` uint16
+    COO block (half the bytes), in steps.
 
     Used only when the vocab fits 2^16 (the caller checks ``num_items`` —
     int16-count runs can exceed that, and then ship int32 blocks); deltas
-    ride as uint16 two's complement and are sign-extended here. The caller
-    also falls back to the int32 block when a window's aggregated cell
-    delta leaves int16 range.
+    ride as uint16 two's complement and are sign-extended step by step.
+    The caller also falls back to the int32 block when a window's
+    aggregated cell delta leaves int16 range.
     """
-    src = coo[0].astype(jnp.int32)
-    dst = coo[1].astype(jnp.int32)
-    delta = coo[2].astype(jnp.int16).astype(jnp.int32)  # sign-extend
-    return _apply_coo(C, row_sums, src, dst, delta, num_items)
+
+    def lanes(t):
+        src, dst, delta = _coo_step(coo, t)
+        return (src.astype(jnp.int32), dst.astype(jnp.int32),
+                delta.astype(jnp.int16).astype(jnp.int32))  # sign-extend
+
+    return _stepped_apply(C, row_sums, n, lanes, num_items)
 
 
 @functools.partial(jax.jit, static_argnames=("n",))
@@ -295,14 +333,6 @@ _score = functools.partial(jax.jit, static_argnames=("top_k", "packed"))(
     _score_body)
 
 
-#: Pairs (forward lanes) one step of a fused window's scatter takes. A
-#: padding lane costs the scatter as much as a live one (about 113 ns
-#: each on a v5e at the Instacart catalog), so the window's live pairs
-#: are packed and scattered in as many steps as they fill: at most one
-#: step's worth of padding a window, and a small scatter working set.
-SCATTER_STEP = 1 << 14
-
-
 def _basket_lanes(block, step, n_pairs, num_items: int, basket_width: int):
     """Step ``step``'s ``SCATTER_STEP`` pairs of a window's basket
     uplink, as COO lanes, forward then mirrored.
@@ -347,14 +377,10 @@ def _fused_apply_baskets(C, row_sums, block, n_pairs, num_items: int,
     sums ``SCATTER_STEP`` pairs at a time, for as many steps as they
     fill (a loop with a traced trip count: one program serves every pair
     count the rectangle can hold)."""
-
-    def step(t, state):
-        src, dst, delta = _basket_lanes(block, t, n_pairs, num_items,
-                                        basket_width)
-        return _apply_coo(*state, src, dst, delta, num_items)
-
-    steps = (n_pairs + SCATTER_STEP - 1) // SCATTER_STEP
-    return jax.lax.fori_loop(0, steps, step, (C, row_sums))
+    return _stepped_apply(
+        C, row_sums, n_pairs,
+        lambda t: _basket_lanes(block, t, n_pairs, num_items, basket_width),
+        num_items)
 
 
 def _fused_score_packed(C, row_sums, rows, live, observed, top_k: int,
@@ -594,8 +620,9 @@ class DeviceScorer:
         self.last_dispatch_fused = False
         # Tracing plane: per-window stage seconds (index / uplink-encode
         # / rescore; the job carves the rest of score_seconds into
-        # dispatch) and counts (launches, score_cells, live_cells, and
-        # with the Pallas kernel fetch_cells).
+        # dispatch) and counts (launches, score_cells, live_cells; with
+        # the Pallas kernel fetch_cells; on the chained update
+        # scatter_lanes and scatter_live).
         self.stage_clock = StageClock()
         self._fused_dispatches = REGISTRY.gauge(
             "cooc_fused_dispatches_total",
@@ -703,12 +730,15 @@ class DeviceScorer:
             rows = distinct_sorted(src)
 
         # Bounded COO buckets: chunk to max_pairs_per_step, pad each chunk to
-        # a power of two (recompile guard, SURVEY §7 "dynamic shapes").
-        # pow-2 (not the score path's pow-4): post-aggregation sizes sit in a
-        # narrow steady-state band, so the finer ladder costs few extra
-        # compiles (amortized by the on-disk XLA cache) and halves the
-        # worst-case transfer+scatter padding. Padding slots scatter delta 0
-        # at (0, 0) — a no-op. The chunk ships as one packed [3, N] buffer
+        # a power of two of at least SCATTER_STEP lanes (recompile guard,
+        # SURVEY §7 "dynamic shapes"). pow-2 (not the score path's pow-4):
+        # post-aggregation sizes sit in a narrow steady-state band, so the
+        # finer ladder costs few extra compiles (amortized by the on-disk
+        # XLA cache) and halves the worst-case transfer padding. The device
+        # scatters only the SCATTER_STEP steps the chunk's n live lanes
+        # fill (n rides as a traced scalar, so the bucket alone keys the
+        # program); the rest of the last step is the (0, 0) delta 0
+        # padding, a no-op. The chunk ships as one packed [3, N] buffer
         # (one transfer, not three).
         # uint16 wire format halves transfer bytes whenever the vocab and
         # the window's cell deltas allow it (bytes on the link are
@@ -720,7 +750,7 @@ class DeviceScorer:
                        and int(agg_delta.max()) < (1 << 15))
             for lo in range(0, len(src), self.max_pairs_per_step):
                 n = min(len(src) - lo, self.max_pairs_per_step)
-                pad = pad_pow2(n, minimum=1 << 14)
+                pad = pad_pow2(n, minimum=SCATTER_STEP)
                 if use_u16:
                     coo = np.zeros((3, pad), dtype=np.uint16)
                     coo[2, :n] = agg_delta[lo: lo + n].astype(
@@ -734,9 +764,12 @@ class DeviceScorer:
                 coo[1, :n] = dst[lo: lo + n]
                 check_coo_chunk(coo, n)
                 clk.add("launches")
+                clk.add("scatter_lanes", SCATTER_STEP * -(-n // SCATTER_STEP))
+                clk.add("scatter_live", n)
                 LEDGER.up("coo", coo)
                 self.C, self.row_sums = update(
-                    self.C, self.row_sums, coo, num_items=self.num_items)
+                    self.C, self.row_sums, coo, np.int32(n),
+                    num_items=self.num_items)
 
         window_sum = int(pairs.delta.sum())
         self.observed += window_sum
