@@ -259,16 +259,18 @@ def test_dense_kernel_fetch_matches_xla(dtype, case):
 
 @pytest.mark.parametrize("dtype", [np.int16, np.int32])
 def test_dense_kernel_local_block_offset(dtype):
-    """The sharded form reads a shard's row block of C (global rows
-    [lo, lo + 128)) and scores exactly what the whole-C form scores."""
-    num_items, lo, per_shard = 512, 256, 128
+    """The sharded form reads a shard's row block of C (shard 1 of 4
+    holds the global rows r with r % 4 == 1, at its rows r // 4) and
+    scores exactly what the whole-C form scores."""
+    num_items, shards, shard = 512, 4, 1
     C, row_sums = _counts(np.random.default_rng(13), num_items, dtype)
     observed = np.float32(row_sums.sum())
-    rows = np.asarray([383, 256, 300, 301, 302, 256, 311, 376, 377, 290,
-                       264, 265, 333], dtype=np.int32)
+    rows = np.asarray([381, 257, 301, 305, 309, 257, 313, 377, 385, 289,
+                       265, 269, 333], dtype=np.int32)
     packed = pallas_score_topk_local(
-        jnp.asarray(C[lo:lo + per_shard]), jnp.asarray(row_sums),
-        jnp.asarray(rows), lo, observed, top_k=6, tile=128, interpret=True)
+        jnp.asarray(C[shard::shards]), jnp.asarray(row_sums),
+        jnp.asarray(rows), jnp.asarray(rows // shards), observed, top_k=6,
+        tile=128, interpret=True)
     whole = pallas_score_topk(jnp.asarray(C), jnp.asarray(row_sums),
                               jnp.asarray(rows), observed, top_k=6,
                               tile=128, interpret=True, packed=True)

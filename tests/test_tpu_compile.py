@@ -307,3 +307,46 @@ def test_sharded_sparse_window_compiles_on_2x2(topo, monkeypatch):
         *structs(calls["score"])).compile()
     for compiled in (update, score):
         assert _hbm_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_sharded_dense_window_compiles_on_2x2(topo, monkeypatch):
+    """The Yelp cell's --backend sharded window over a mesh of the four
+    described chips, at its shapes: 150,346 businesses padded to the
+    kernel tile (151,552), a 37,888-row int16 block a chip; the COO
+    update's 2^17-lane chunk, stepped and donated as on the chip, with
+    its row-sum psum, and one 32,768-row rescore a shard."""
+    from tpu_cooccurrence.parallel import sharded as sh
+    from tpu_cooccurrence.parallel.mesh import ITEM_AXIS
+
+    monkeypatch.setattr(sh, "donate_argnums", lambda *a: a)
+    monkeypatch.setattr(sh.ShardedScorer, "_new_state",
+                        lambda self: (None, None))
+    mesh = Mesh(np.asarray(topo.devices[:4]), (ITEM_AXIS,))
+    scorer = sh.ShardedScorer(150_346, 10, mesh=mesh, count_dtype="int16",
+                              use_pallas="on")
+    scorer._pallas_interpret = False
+    scorer._build(150_346)
+    n, rows = scorer.num_items, scorer.rows_per_shard
+    assert (n, rows) == (151_552, 37_888)
+
+    def sds(shape, dtype, spec):
+        return _sds(shape, dtype, NamedSharding(mesh, spec))
+
+    C = sds((n, n), jnp.int16, P(ITEM_AXIS, None))
+    row_sums = sds((n,), jnp.int32, P())
+    per_shard = sds((4,), jnp.int32, P(ITEM_AXIS))
+    update = scorer._update.lower(
+        C, row_sums, sds((4, 3, sh.SHARD_CHUNK), jnp.int32, P(ITEM_AXIS)),
+        per_shard).compile()
+    assert "all-reduce" in update.as_text()  # the row-sum psum on ICI
+    m = update.memory_analysis()
+    # One 11.48 GB block a chip, updated in place through the steps.
+    assert m.alias_size_in_bytes >= rows * n * 2
+    assert m.temp_size_in_bytes < 64 * 2**20
+    score = scorer._score.lower(
+        C, row_sums, sds((4, 32_768), jnp.int32, P(ITEM_AXIS)), per_shard,
+        sds((), jnp.float32, P())).compile()
+    _assert_kernel(score)
+    _assert_reads_c_in_place(score)
+    for compiled in (update, score):
+        assert _hbm_bytes(compiled) < V5E_HBM_BYTES

@@ -237,15 +237,17 @@ def _fetch_plan(local):
 
 
 def dense_topk(C, rows, row_sums, observed, *, top_k: int, tile: int,
-               interpret: bool, lo=0, live=None):
-    """THE dense scoring core: fused LLR + top-K of ``C``'s rows
-    ``rows - lo``, fetched by the kernel from ``C`` in HBM.
+               interpret: bool, local=None, live=None):
+    """THE dense scoring core: fused LLR + top-K of the global rows
+    ``rows``, fetched by the kernel from ``C`` in HBM.
 
     C        [N, I] int32|int16 — dense counts, or a shard's row block
-             of them starting at global row ``lo`` (N % 8 == 0,
-             I % tile == 0)
+             of them (N % 8 == 0, I % tile == 0)
     row_sums [I] int32 — global row sums
     rows     [Sp] int32 — global row ids, Sp % BLOCK_ROWS == 0
+    local    [Sp] int32 or None — the row of ``C`` that holds each of
+             ``rows`` when ``C`` is a shard's row block; None when ``C``
+             is the whole matrix
     live     int32 scalar or None — rows past the first ``live`` are
              padding: their whole blocks fetch and score nothing and
              return (-inf, 0), so one program serves every row count up
@@ -282,7 +284,7 @@ def dense_topk(C, rows, row_sums, observed, *, top_k: int, tile: int,
     with jax.named_scope("gather"):
         rsi = row_sums[rows].reshape(sp, 1)
         slots, firsts, n_groups = _fetch_plan(
-            jnp.clip(rows - lo, 0, n_rows - 1))
+            jnp.clip(rows if local is None else local, 0, n_rows - 1))
         if live is not None:
             n_groups = jnp.where(
                 jnp.arange(sp // blk, dtype=jnp.int32) * blk < live,
@@ -327,26 +329,27 @@ def dense_topk(C, rows, row_sums, observed, *, top_k: int, tile: int,
           obs)
 
 
-def pallas_score_topk_local(C_loc, row_sums, rows_global, lo, observed, *,
+def pallas_score_topk_local(C_loc, row_sums, rows, local, observed, *,
                             top_k: int, tile: int = 512,
-                            interpret: bool = False):
-    """Sharded-dense form: score global ``rows_global`` out of a LOCAL row
-    block ``C_loc`` (`[rows_per_shard, I]`, rows ``[lo, lo+rows_per_shard)``)
-    against the replicated global ``row_sums``. For use inside a
-    ``shard_map`` body (pallas_call is an ordinary per-device op there).
+                            interpret: bool = False, live=None):
+    """Sharded-dense form: score the global ``rows`` out of a shard's
+    row block ``C_loc``, which holds them at its rows ``local``, against
+    the replicated global ``row_sums``. For use inside a ``shard_map``
+    body (pallas_call is an ordinary per-device op there); ``live`` as
+    :func:`dense_topk`'s.
 
     Returns packed [2, S, top_k] float32 with ids as float *values*
     (decode with astype — same contract as ``pallas_score_topk(packed=
     True)``). Padded rows may repeat a real row; the caller drops them.
     """
-    S = rows_global.shape[0]
+    S = rows.shape[0]
     pad_s = (-S) % BLOCK_ROWS
     if pad_s:
-        rows_global = jnp.concatenate(
-            [rows_global, jnp.full(pad_s, lo, dtype=rows_global.dtype)])
-    vals, idxf = dense_topk(C_loc, rows_global, row_sums, observed,
-                            top_k=top_k, tile=tile, interpret=interpret,
-                            lo=lo)
+        rows = jnp.concatenate([rows, jnp.zeros(pad_s, dtype=rows.dtype)])
+        local = jnp.concatenate([local, jnp.zeros(pad_s, dtype=local.dtype)])
+    vals, idxf = dense_topk(C_loc, rows, row_sums, observed, top_k=top_k,
+                            tile=tile, interpret=interpret, local=local,
+                            live=live)
     return jnp.stack([vals[:S, :top_k], idxf[:S, :top_k]])
 
 
